@@ -533,6 +533,8 @@ def _write_step_summary(summary, regressed) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     argv = sys.argv[1:]
     smoke = "--smoke" in argv
     csv = "--csv" in argv

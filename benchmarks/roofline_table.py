@@ -84,6 +84,8 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     args = ap.parse_args()
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     archs = [args.arch] if args.arch else list(ARCH_IDS)
     rows = []
     for arch in archs:

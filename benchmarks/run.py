@@ -25,6 +25,8 @@ def emit(name: str, us: float, derived: str):
 
 
 def main() -> None:
+    from repro.launch.compile_cache import setup_compile_cache
+    setup_compile_cache()
     print("name,us_per_call,derived")
 
     rows, s = figs.fig8_9_speedup_energy()

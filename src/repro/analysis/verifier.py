@@ -21,7 +21,8 @@ plans:
      the whole per-step block footprint is sized against the physical
      VMEM capacity, using the EXACT block extents the kernels pick
      (the block-selection helpers are imported from the kernels, not
-     re-implemented).
+     re-implemented); and every block meets the TPU tiling rule
+     (``repro.kernels.tiling``) the Mosaic compiler enforces.
   4. **well-formedness** — no FAR_PRIMS inside near segments, spans
      consistent, the ``decisions`` table in agreement with the emitted
      segments, persisted-plan fingerprints re-verifiable.
@@ -46,27 +47,30 @@ from repro.core.offload import (
     _jaxpr_fingerprint,
 )
 from repro.kernels.fused_elementwise import (
+    REP_SPAN,
     _bcast_row_index,
-    _largest_divisor_leq,
+    row_view,
     segment_row_block,
 )
 from repro.kernels.fused_matmul import (
     _ACC_VMEM_BYTES,
-    _block_budget,
     _row_block,
+    contraction_block,
 )
-from repro.kernels.fused_matmul_bwd import drhs_blocks
+from repro.kernels.fused_matmul_bwd import drhs_blocks, drhs_m_block
+from repro.kernels.tiling import VMEM_LIMIT_BYTES
 
 SEVERITIES = ("info", "warning", "error")
 
-# Physical per-core VMEM ceiling the whole per-step footprint (operand
-# blocks + accumulator scratch + output blocks) is sized against.  The
-# policy's ``vmem_budget`` only clamps the ACCUMULATOR (an error to
-# exceed — the kernel's row-block floor of 8 can genuinely overflow a
-# small budget); the footprint rule is advisory (warning) because the
-# elementwise grid intentionally does not lane-block wide operands
-# (e.g. a [rows, vocab] softmax segment keeps whole rows resident).
-VMEM_CAPACITY_BYTES = 32 * 1024 * 1024
+# The scoped VMEM the kernels ask Mosaic for, which the whole per-step
+# footprint (operand blocks + accumulator scratch + output blocks) is
+# sized against.  The policy's ``vmem_budget`` only clamps the
+# ACCUMULATOR (an error to exceed — the kernel's row-block floor of 8
+# can genuinely overflow a small budget); the footprint rule is
+# advisory (warning) because the elementwise grid intentionally does not
+# lane-block wide operands (e.g. a [rows, vocab] softmax segment keeps
+# whole rows resident).
+VMEM_CAPACITY_BYTES = VMEM_LIMIT_BYTES
 
 # full grid enumeration cap; larger grids are edge-sampled
 _ENUM_CAP = 1 << 15
@@ -196,8 +200,7 @@ def _stream_race(seg: Segment, sp: OperandSpec, oi: int) -> str | None:
     if mm.form in ("fwd", "dlhs"):
         rb = _row_block(rows, epi_meta, 512, n, vmem, batch)
         kd = mm.k
-        kb = _largest_divisor_leq(
-            kd, max(min(_block_budget(512, n, vmem), kd), 1))
+        kb = contraction_block(kd, n, vmem_bytes=vmem)
         if rows % rb or kd % kb:
             return None      # geometry broken: bounds rules report it
         R, K = rows // rb, kd // kb
@@ -219,7 +222,7 @@ def _stream_race(seg: Segment, sp: OperandSpec, oi: int) -> str | None:
                                                       kd)))
     elif mm.form == "drhs":
         pb, nb = drhs_blocks(rows, n, vmem_bytes=vmem, batch=batch)
-        mb = _largest_divisor_leq(mm.k, max(min(512, mm.k), 1))
+        mb = drhs_m_block(mm.k, batch)
         if rows % pb or n % nb or mm.k % mb:
             return None
         R, NB, NM = rows // pb, n // nb, mm.k // mb
@@ -385,14 +388,14 @@ def _check_epi_spec(sp: OperandSpec, si: int, rows: int, rb: int,
                 f"rep operand rows {sp.rows} do not divide segment "
                 f"rows {rows}"))
             return
-        q = (rows // sp.rows) // rb
-        if q < 1:
+        q = rows // sp.rows
+        if q % rb and (rb % q or rb // q > REP_SPAN):
             findings.append(Finding(
                 "index-bounds", "error", si,
-                f"rep repeat factor {rows // sp.rows} smaller than the "
-                f"row block {rb}"))
+                f"row block {rb} neither divides the rep repeat factor "
+                f"{q} nor spans at most {REP_SPAN} whole repeats"))
             return
-        top = (n_row_blocks - 1) // q
+        top = ((n_row_blocks - 1) * rb) // q + max(rb // q, 1) - 1
         if top >= sp.rows:
             findings.append(Finding(
                 "index-bounds", "error", si,
@@ -562,14 +565,10 @@ def _check_matmul_streams(seg: Segment, si: int,
 # VMEM legality
 # ---------------------------------------------------------------------------
 
-def _epi_block_bytes(sp: OperandSpec, rb: int) -> int:
-    per_row = sp.cols * _itemsize(sp.var)
-    if sp.role in ("param", "rep"):
-        return per_row
-    if sp.role == "bcast":
-        lead = tuple(sp.lead) or (1,)
-        return per_row * (rb if lead[-1] != 1 else 1)
-    return per_row * rb          # bulk / tile
+def _epi_block_bytes(sp: OperandSpec, rows: int, rb: int) -> int:
+    """Bytes of the block the kernel fetches for one epilogue operand,
+    from the kernel's own ``row_view``."""
+    return row_view(sp.meta, rows, rb)[1][0] * sp.cols * _itemsize(sp.var)
 
 
 def _check_vmem(seg: Segment, si: int, findings: list[Finding]) -> None:
@@ -583,7 +582,8 @@ def _check_vmem(seg: Segment, si: int, findings: list[Finding]) -> None:
     if mm is None:
         rb, _, _ = segment_row_block(rows, epi_meta, 512,
                                      donate=bool(seg.donations))
-        blocks += sum(_epi_block_bytes(s, rb) for s in seg.operand_specs)
+        blocks += sum(_epi_block_bytes(s, rows, rb)
+                      for s in seg.operand_specs)
         blocks += sum(rb * c * _itemsize(v)
                       for v, c in zip(seg.outputs, seg.out_cols))
     elif mm.flash is not None:
@@ -596,19 +596,19 @@ def _check_vmem(seg: Segment, si: int, findings: list[Finding]) -> None:
     elif mm.form == "drhs":
         pb, nb = drhs_blocks(rows, mm.n, vmem_bytes=seg.vmem_bytes,
                              batch=mm.batch)
-        mb = _largest_divisor_leq(mm.k, max(min(512, mm.k), 1))
+        mb = drhs_m_block(mm.k, mm.batch)
         acc = pb * nb * 4
         if mm.lhs_specs:
             blocks += mb * pb * _itemsize(mm.lhs_specs[0].var)
         blocks += mb * nb * _itemsize(mm.rhs)
-        blocks += sum(_epi_block_bytes(s, pb) for s in seg.operand_specs)
+        blocks += sum(_epi_block_bytes(s, rows, pb)
+                      for s in seg.operand_specs)
         blocks += sum(pb * nb * _itemsize(v) for v in seg.outputs)
     else:
         rb = _row_block(rows, epi_meta, 512, mm.n, seg.vmem_bytes,
                         mm.batch)
         kd = mm.k
-        kb = _largest_divisor_leq(
-            kd, max(min(_block_budget(512, mm.n, seg.vmem_bytes), kd), 1))
+        kb = contraction_block(kd, mm.n, vmem_bytes=seg.vmem_bytes)
         acc = rb * mm.n * 4
         for s in mm.lhs_specs:
             blocks += (kb if s.cols == kd else s.cols) * _itemsize(s.var) \
@@ -619,7 +619,8 @@ def _check_vmem(seg: Segment, si: int, findings: list[Finding]) -> None:
                            else s.cols) * _itemsize(s.var)
         else:
             blocks += mm.n * kb * _itemsize(mm.rhs)
-        blocks += sum(_epi_block_bytes(s, rb) for s in seg.operand_specs)
+        blocks += sum(_epi_block_bytes(s, rows, rb)
+                      for s in seg.operand_specs)
         blocks += sum(rb * c * _itemsize(v)
                       for v, c in zip(seg.outputs, seg.out_cols))
     if acc > VMEM_CAPACITY_BYTES:
@@ -800,6 +801,8 @@ def _verify_segment(seg: Segment, si: int, jaxpr, consumers, invar_set,
         else:
             _check_outputs(seg, si, findings)
     _check_vmem(seg, si, findings)
+    for why in seg.tiling_violations():
+        findings.append(Finding("tpu-tiling", "error", si, why))
 
 
 def verify_plan(plan: OffloadPlan, closed=None) -> list[Finding]:

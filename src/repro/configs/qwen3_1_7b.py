@@ -1,6 +1,7 @@
-"""qwen3-1.7b — dense GQA with qk_norm.
+"""qwen3-1.7b — dense GQA with qk_norm and tied word embeddings.
 
-28L d_model=2048 16H (GQA kv=8) d_ff=6144 vocab=151936 [hf:Qwen/Qwen3-8B; hf].
+28L d_model=2048 16H (GQA kv=8) d_ff=6144 vocab=151936
+[hf:Qwen/Qwen3-1.7B config.json].
 """
 from repro.configs.base import ModelConfig
 
@@ -16,5 +17,7 @@ CONFIG = ModelConfig(
     qk_norm=True,
     head_dim=128,
     rope_theta=1e6,
-    source="hf:Qwen/Qwen3-8B; hf",
+    norm_eps=1e-6,
+    tie_embeddings=True,
+    source="hf:Qwen/Qwen3-1.7B",
 )

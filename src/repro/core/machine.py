@@ -112,6 +112,8 @@ class GPUMachine:
 class TPUv5e:
     """Roofline constants (assignment-provided)."""
 
+    # ``jax.Device.device_kind`` of the chips these constants describe
+    device_kinds: tuple[str, ...] = ("TPU v5 lite", "TPU v5e")
     peak_bf16_flops: float = 197e12      # per chip
     hbm_gbps: float = 819.0              # GB/s per chip
     ici_link_gbps: float = 50.0          # GB/s per link per direction

@@ -6,7 +6,7 @@ location annotator (Algorithm 1, repro.core.locator) marks each
 instruction near/far, and the backend emits offload descriptors into the
 compiled program.  This module mirrors that architecture for JAX:
 
-  flatten once  trivially-inlinable call eqns (``pjit``-wrapped
+  flatten once  trivially-inlinable call eqns (``jit``-wrapped
                 elementwise helpers like ``jax.nn.silu``, and
                 ``custom_jvp`` bodies, whose forward rule is what the
                 post-grad trace wants anyway) are spliced into the
@@ -65,7 +65,7 @@ compiled program.  This module mirrors that architecture for JAX:
                 ``scan``/``closed_call`` bodies are rewritten
                 recursively *at rewrite time* (scan CARRIES that die at
                 a body segment are donated into the body's kernel
-                aliases), and non-trivial ``pjit`` eqns are re-emitted
+                aliases), and non-trivial ``jit`` eqns are re-emitted
                 as ``jax.jit`` calls so their fully-specified
                 ``in_shardings``/``out_shardings`` and
                 ``donated_invars`` survive the rewrite (partially
@@ -369,6 +369,46 @@ class Segment:
                     mm.n, batch=mm.batch, vmem_bytes=self.vmem_bytes)
         return total
 
+    def tiling_violations(self) -> list[str]:
+        """Breaches of the TPU block tiling rule (``repro.kernels.tiling``)
+        by the blocks this segment's kernel would launch, computed with
+        the kernel's own layout helpers; empty when every block lowers.
+        Flash segments run the flash kernel's fixed blocks."""
+        from repro.kernels.fused_elementwise import segment_grid_layout
+        from repro.kernels.fused_matmul import matmul_layout
+        from repro.kernels.fused_matmul_bwd import dlhs_layout, drhs_layout
+        from repro.kernels.tiling import violations
+
+        epi = [sp.meta for sp in self.operand_specs]
+        arrays = [sp.var for sp in self.operand_specs] + list(self.outputs)
+        mm = self.matmul
+        if mm is None:
+            *_, ins, outs = segment_grid_layout(
+                self.rows, epi, self.out_cols, donate=bool(self.donations))
+        elif mm.flash is not None:
+            return []
+        else:
+            geo = dict(batch=mm.batch, vmem_bytes=self.vmem_bytes)
+            lhs = [sp.meta for sp in mm.lhs_specs]
+            if mm.form == "drhs":
+                *_, ins, outs = drhs_layout(mm.k, self.rows, mm.n, epi,
+                                            self.out_cols, **geo)
+                arrays = [mm.lhs_specs[0].var, mm.rhs_specs[0].var] + arrays
+            elif mm.form == "dlhs":
+                *_, ins, outs = dlhs_layout(self.rows, mm.k, mm.n, lhs, epi,
+                                            self.out_cols, **geo)
+                arrays = [sp.var for sp in mm.lhs_specs] + \
+                    [mm.rhs_specs[0].var] + arrays
+            else:
+                *_, ins, outs = matmul_layout(
+                    self.rows, mm.k, mm.n, lhs,
+                    [sp.meta for sp in mm.rhs_specs], epi, self.out_cols,
+                    **geo)
+                arrays = [sp.var for sp in (*mm.lhs_specs, *mm.rhs_specs)] \
+                    + arrays
+        return violations((*ins, *outs), [v.aval.dtype.itemsize
+                                          for v in arrays])
+
 
 @dataclass
 class OffloadPlan:
@@ -386,7 +426,7 @@ class OffloadPlan:
 
     def report(self) -> DecisionReport:
         """The per-segment decision report (see ``DecisionReport``),
-        nested reports covering scan/pjit bodies.  Every fused decision
+        nested reports covering scan/jit bodies.  Every fused decision
         row is cross-checked against its emitted segment and rendered
         with a ``verified`` status ("ok" / "MISMATCH(...)" /
         "MISSING-SEGMENT") so decision/plan drift is visible instead of
@@ -424,7 +464,7 @@ class OffloadPlan:
 
     @property
     def total_segments(self) -> int:
-        """Segments including those planned inside scan/pjit bodies."""
+        """Segments including those planned inside scan/jit bodies."""
         return len(self.segments) + sum(p.total_segments
                                         for p in self.inner_plans)
 
@@ -530,17 +570,15 @@ def _far_decision_bytes(eqns: Sequence, idxs: Sequence[int]) -> int:
 
 # ---------------------------------------------------------------------------
 # Call flattening: splice trivially-inlinable call bodies into the caller
-# so near chains are not cut at pjit boundaries (jax.nn.silu & friends).
+# so near chains are not cut at jit boundaries (jax.nn.silu & friends).
 # ---------------------------------------------------------------------------
 
-# NOTE: no custom_vjp entry.  Inlining a ``custom_vjp`` body would
+# NOTE: no custom_vjp entry.  Inlining a ``custom_vjp_call`` body would
 # silently discard the user's backward rule (the inlined forward would
-# differentiate by autodiff instead); those eqns re-bind unchanged so
-# the rule rides through the rewrite intact.  (On current jax the
-# traced primitive is ``custom_vjp_call_jaxpr``; ``primitive.bind`` with
-# the eqn's own params preserves the rule.)
+# differentiate by autodiff instead); those eqns re-bind unchanged (via
+# ``_bind_eqn``) so the rule rides through the rewrite intact.
 _CALL_BODY_PARAM = {
-    "pjit": "jaxpr",
+    "jit": "jaxpr",
     "closed_call": "call_jaxpr",
     "custom_jvp_call": "call_jaxpr",
 }
@@ -557,10 +595,10 @@ def _inline_body(eqn) -> Any | None:
     offload trace is post-grad, so the jvp body's forward rule is
     exactly what the trace wants.  ``custom_vjp`` eqns are NEVER inlined
     — their backward rules are numerically load-bearing and inlining
-    would drop them — they re-bind unchanged instead.  A ``pjit`` is
+    would drop them — they re-bind unchanged instead.  A ``jit`` is
     inlined only when it carries no shardings or donation AND its body
     is purely elementwise/layout eqns — anything else keeps its call
-    boundary (pjit fidelity is preserved separately by the runner's
+    boundary (jit fidelity is preserved separately by the runner's
     re-emitted ``jax.jit``)."""
     name = eqn.primitive.name
     if name not in _CALL_BODY_PARAM:
@@ -570,7 +608,7 @@ def _inline_body(eqn) -> Any | None:
         return None
     if name in ("custom_jvp_call", "closed_call"):
         return body
-    if name == "pjit":
+    if name == "jit":
         if any(not _unspecified(s) for s in eqn.params.get("in_shardings", ())):
             return None
         if any(not _unspecified(s)
@@ -586,6 +624,15 @@ def _inline_body(eqn) -> Any | None:
             continue
         return None
     return body
+
+
+def _bind_eqn(eqn, invals) -> tuple:
+    """Re-bind ``eqn``'s primitive on ``invals``; always a tuple of outs.
+    ``get_bind_params`` turns jaxpr-valued params (a ``custom_vjp_call``'s
+    rules, a ``jit``'s body) back into what ``bind`` expects."""
+    subfuns, params = eqn.primitive.get_bind_params(eqn.params)
+    out = eqn.primitive.bind(*subfuns, *invals, **params)
+    return tuple(out) if eqn.primitive.multiple_results else (out,)
 
 
 def _flatten_calls(closed: jcore.ClosedJaxpr) -> jcore.ClosedJaxpr:
@@ -612,9 +659,7 @@ def _flatten_calls(closed: jcore.ClosedJaxpr) -> jcore.ClosedJaxpr:
             if body is not None:
                 outs = ev(body, [read(v) for v in eqn.invars])
             else:
-                out = eqn.primitive.bind(*(read(v) for v in eqn.invars),
-                                         **eqn.params)
-                outs = out if eqn.primitive.multiple_results else (out,)
+                outs = _bind_eqn(eqn, [read(v) for v in eqn.invars])
             for var, val in zip(eqn.outvars, outs):
                 env[var] = val
         return tuple(read(v) for v in c.jaxpr.outvars)
@@ -691,6 +736,8 @@ def plan_offload(closed: jcore.ClosedJaxpr, *,
     donation candidates."""
     policy = resolve_policy(policy, bulk_threshold=bulk_threshold,
                             min_segment=min_segment)
+    if policy.mode == "cost":
+        policy.check_cost_target(jax.devices()[0])
     bulk_threshold = policy.bulk_threshold
     min_segment = policy.min_segment
     ann = annotate_jaxpr(closed, bulk_threshold=bulk_threshold)
@@ -1724,10 +1771,11 @@ def plan_offload(closed: jcore.ClosedJaxpr, *,
             roles = [f"{sp.role}[{sp.rows}x{sp.cols}]"
                      for sp in (*anchor_spec.lhs_specs,
                                 *anchor_spec.rhs_specs)] + roles
+        tiling = seg.tiling_violations()
         decision = policy.decide(
             tier="anchor" if anchor_spec is not None else "elementwise",
             n_compute=n_compute, near_bytes=seg.io_bytes(),
-            far_bytes=far_b)
+            far_bytes=far_b, tiling=tiling[0] if tiling else None)
         form = None
         if anchor_spec is not None:
             form = "flash" if anchor_spec.flash is not None \
@@ -1781,8 +1829,9 @@ def _segment_fn(eqns: Sequence, seg: Segment) -> Callable:
     """Build the fused near-bank function for a segment.
 
     Executed inside the Pallas kernel: every value is a 2-D block —
-    bulk/tile values are [block_rows, cols] tiles, params and rep values
-    are [1, cols] — layout prims become block-local index ops, and
+    bulk/tile values are [block_rows, cols] tiles, params are [1, cols],
+    rep values [1, cols] or already repeated to [block_rows, cols]
+    (``read_block``) — layout prims become block-local index ops, and
     lane-axis reductions collapse the block to a [block_rows, 1] row
     statistic (the whole lane extent is resident, so the reduce and its
     re-broadcast are two passes over the row inside VMEM).
@@ -1832,13 +1881,28 @@ def _segment_fn(eqns: Sequence, seg: Segment) -> Callable:
             elif name == "reduce_max":
                 out = jnp.asarray(ins[0]).max(axis=-1, keepdims=True)
             else:
-                out = eqn.primitive.bind(*ins, **eqn.params)
-                if eqn.primitive.multiple_results:
-                    out = out[0]
+                out = _bind_f32(eqn, ins)
             env[eqn.outvars[0]] = out
         return tuple(env[v] for v in seg.outputs)
 
     return fn
+
+
+def _bind_f32(eqn, ins):
+    """Bind one elementwise eqn inside a kernel body.  An op on floats
+    narrower than 32 bits computes in f32 and rounds its result back to
+    the eqn's dtype: v5e's vector units have no bf16 arithmetic, and
+    Mosaic cannot lower every bf16 op (``logistic`` among them)."""
+    narrow = [hasattr(x, "dtype") and jnp.issubdtype(x.dtype, jnp.floating)
+              and x.dtype.itemsize < 4 for x in ins]
+    out_dtype = eqn.outvars[0].aval.dtype
+    if eqn.primitive.name == "convert_element_type" or not any(narrow):
+        out = eqn.primitive.bind(*ins, **eqn.params)
+        return out[0] if eqn.primitive.multiple_results else out
+    ins = [x.astype(jnp.float32) if n else x for x, n in zip(ins, narrow)]
+    out = eqn.primitive.bind(*ins, **eqn.params)
+    out = out[0] if eqn.primitive.multiple_results else out
+    return out.astype(out_dtype)
 
 
 def _prologue_fn(eqns: Sequence, mm: MatmulAnchor) -> Callable:
@@ -2165,7 +2229,7 @@ def _fp_val(h, val) -> None:
         h.update(b")")
         return
     if callable(val):
-        # function params (custom_vjp rules, pjit names): identity by
+        # function params (custom_vjp rules, jit names): identity by
         # name only — reprs embed process-local addresses
         h.update(f"fn:{getattr(val, '__name__', type(val).__name__)}"
                  .encode())
@@ -2364,7 +2428,7 @@ def _plan_structure(plan: OffloadPlan) -> tuple:
 
 class _PlanLedger:
     """Ordered record/replay of every plan one ``_build_runner``
-    recursion builds: the top-level plan first, then scan/pjit body
+    recursion builds: the top-level plan first, then scan/jit body
     plans in recursion order.  Record mode captures payloads for
     persistence; replay mode feeds them back so a warm process does
     ZERO fresh planning.  A plan that cannot serialize poisons the
@@ -2413,7 +2477,7 @@ def _build_runner(closed: jcore.ClosedJaxpr, *, policy: OffloadPolicy,
     ClosedJaxpr the plan indexes into, and ``run(consts, args)`` is a
     pure, jit-traceable function: near segments dispatch to
     ``kops.fused_segment_grid`` (with donation aliases baked in), scan
-    bodies carry a pre-rewritten body runner, non-trivial pjit eqns are
+    bodies carry a pre-rewritten body runner, non-trivial jit eqns are
     re-emitted through ``jax.jit`` with their shardings/donation, and
     everything else re-binds its primitive unchanged.
 
@@ -2491,8 +2555,8 @@ def _build_runner(closed: jcore.ClosedJaxpr, *, policy: OffloadPolicy,
                 env[var] = val
         return step
 
-    def make_pjit_step(eqn) -> Callable:
-        """Re-emit non-trivial pjit eqns through ``jax.jit`` so their
+    def make_jit_step(eqn) -> Callable:
+        """Re-emit non-trivial jit eqns through ``jax.jit`` so their
         in/out shardings and donated invars survive the rewrite instead
         of being dropped on inlining."""
         inner_run, inner_consts = recurse(eqn.params["jaxpr"])
@@ -2516,10 +2580,7 @@ def _build_runner(closed: jcore.ClosedJaxpr, *, policy: OffloadPolicy,
         def call(*a):
             return inner_run(inner_consts, a)
 
-        try:
-            jitted = jax.jit(call, donate_argnums=donated, **jit_kwargs)
-        except Exception:                 # sharding repr drift: inline
-            return make_inline_call_step(eqn, inner_run, inner_consts)
+        jitted = jax.jit(call, donate_argnums=donated, **jit_kwargs)
 
         def step(env, read):
             outs = jitted(*[read(v) for v in eqn.invars])
@@ -2529,9 +2590,7 @@ def _build_runner(closed: jcore.ClosedJaxpr, *, policy: OffloadPolicy,
 
     def make_eqn_step(eqn) -> Callable:
         def step(env, read):
-            out = eqn.primitive.bind(*(read(v) for v in eqn.invars),
-                                     **eqn.params)
-            outs = out if eqn.primitive.multiple_results else (out,)
+            outs = _bind_eqn(eqn, [read(v) for v in eqn.invars])
             for var, val in zip(eqn.outvars, outs):
                 env[var] = val
         return step
@@ -2550,8 +2609,8 @@ def _build_runner(closed: jcore.ClosedJaxpr, *, policy: OffloadPolicy,
         name = eqn.primitive.name
         if name == "scan":
             steps.append(make_scan_step(eqn))
-        elif name == "pjit":
-            steps.append(make_pjit_step(eqn))
+        elif name == "jit":
+            steps.append(make_jit_step(eqn))
         else:
             # custom_jvp_call/closed_call never reach here (their bodies
             # are inlined by _flatten_calls); custom_vjp eqns DO — they
@@ -2974,7 +3033,7 @@ def offload_explain(fn: Callable, *args,
 #
 # This is what the compiled path replaced: every call re-traces fn,
 # re-plans the jaxpr, and walks it eqn-by-eqn in Python (recursing into
-# scan/pjit bodies per call).  benchmarks/offload_bench.py times it
+# scan/jit bodies per call).  benchmarks/offload_bench.py times it
 # against mpu_offload to quantify the win; nothing else should use it.
 # Donation is deliberately NOT applied here (pure baseline semantics).
 # ---------------------------------------------------------------------------
@@ -3001,9 +3060,7 @@ def execute_offloaded(closed: jcore.ClosedJaxpr, plan: OffloadPlan,
         return v.val if isinstance(v, jcore.Literal) else env[v]
 
     def bind_eqn(eqn):
-        out = eqn.primitive.bind(*(read(v) for v in eqn.invars),
-                                 **eqn.params)
-        outs = out if eqn.primitive.multiple_results else (out,)
+        outs = _bind_eqn(eqn, [read(v) for v in eqn.invars])
         for var, val in zip(eqn.outvars, outs):
             env[var] = val
 

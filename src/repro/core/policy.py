@@ -151,6 +151,21 @@ class OffloadPolicy:
         return dataclasses.replace(self, **overrides)
 
     # -- the cost model ----------------------------------------------------
+    def check_cost_target(self, device) -> None:
+        """Refuse ``mode="cost"`` on an accelerator the machine model's
+        constants do not describe (its ``device_kinds``): the decision
+        would price another chip's bandwidths.  A CPU host plans for the
+        modelled machine itself (tests, offline planning)."""
+        if device.platform == "cpu":
+            return
+        kinds = getattr(self.machine, "device_kinds", ())
+        if device.device_kind not in kinds:
+            raise ValueError(
+                f"OffloadPolicy(mode='cost') prices with "
+                f"{type(self.machine).__name__} constants, which describe "
+                f"{list(kinds) or 'no real device'}, not this "
+                f"{device.platform} device {device.device_kind!r}")
+
     @property
     def near_gbps(self) -> float:
         return float(self.machine.offload_near_gbps)
@@ -169,16 +184,22 @@ class OffloadPolicy:
                 far_bytes / (self.far_gbps * 1e3))
 
     def decide(self, *, tier: str, n_compute: int, near_bytes: int,
-               far_bytes: int) -> "SegmentDecision":
+               far_bytes: int, tiling: str | None = None
+               ) -> "SegmentDecision":
         """The §IV-B1 decision for one candidate segment.
 
         ``tier`` is "anchor" for matmul-anchored candidates, else
         "elementwise"; ``n_compute`` counts fused ALU eqns (layout prims
         excluded); ``near_bytes`` is the fused kernel's modeled HBM
         traffic (``Segment.io_bytes``), ``far_bytes`` the same eqns'
-        per-eqn round-trips on the far pipeline."""
+        per-eqn round-trips on the far pipeline.  ``tiling`` names a
+        block of the kernel that breaks the TPU tiling rule: such a
+        kernel cannot compile, so the candidate declines in every
+        mode."""
         near_us, far_us = self.modeled_us(near_bytes, far_bytes)
-        if self.mode == "all_far":
+        if tiling is not None:
+            fuse, reason = False, f"TPU block tiling: {tiling}"
+        elif self.mode == "all_far":
             fuse, reason = False, "policy all_far: far pipeline only"
         elif self.mode == "all_near":
             fuse, reason = True, "policy all_near: fuse every admissible"
@@ -310,7 +331,7 @@ class SegmentDecision:
 class DecisionReport:
     """The plan-inspection view ``wrapped.explain(*args)`` returns: one
     row per candidate segment (fused AND declined), nested reports for
-    scan/pjit bodies, and the plan's traffic accounting."""
+    scan/jit bodies, and the plan's traffic accounting."""
 
     policy: OffloadPolicy
     decisions: list[SegmentDecision]

@@ -80,8 +80,8 @@ REDUCE_LANE_PRIMS = {"reduce_sum", "reduce_max"}
 FAR_PRIMS = {
     "dot_general", "conv_general_dilated", "gather", "scatter",
     "scatter-add", "dynamic_slice", "dynamic_update_slice",
-    "sort", "top_k", "while", "cond", "scan", "pjit", "custom_jvp_call",
-    "custom_vjp_call", "custom_vjp_call_jaxpr", "remat2",
+    "sort", "top_k", "while", "cond", "scan", "jit", "custom_jvp_call",
+    "custom_vjp_call", "remat2",
     "rng_uniform", "rng_bit_generator", "random_bits", "random_seed",
     "random_wrap", "random_fold_in", "iota", "argmax", "argmin",
     "reduce_sum", "reduce_max", "reduce_min", "reduce_prod", "reduce_and",

@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
 
 NEG_INF = -1e30
 
@@ -137,7 +136,7 @@ def decode_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, nk, g, h), q.dtype),
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths.astype(jnp.int32), qr, kr, vr)
@@ -239,7 +238,7 @@ def paged_decode_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, nk, g, h), q.dtype),
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32), qr,

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
 
 NEG_INF = -1e30
 
@@ -160,7 +159,7 @@ def flash_attention(
             pltpu.VMEM((g * q_block,), jnp.float32),
             pltpu.VMEM((g * q_block,), jnp.float32),
         ],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
 
 from repro.kernels.flash_attention import flash_attention as _fwd_kernel_call
 
@@ -203,7 +202,7 @@ def flash_attention_bwd(
                    jax.ShapeDtypeStruct((b, nk, st, h), v.dtype)],
         scratch_shapes=[pltpu.VMEM((kv_block, h), jnp.float32),
                         pltpu.VMEM((kv_block, h), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -223,7 +222,7 @@ def flash_attention_bwd(
         out_specs=qspec_dq,
         out_shape=jax.ShapeDtypeStruct((b, nk, g, sq, h), q.dtype),
         scratch_shapes=[pltpu.VMEM((g * q_block, h), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -22,6 +22,9 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.tiling import VMEM_LIMIT_BYTES, tiled_divisor
 
 
 def _ew_kernel(*refs, fn: Callable, n_bulk: int, n_param: int, n_out: int):
@@ -92,22 +95,6 @@ def fused_elementwise(
     return result[0] if n_outputs == 1 else result
 
 
-def _largest_divisor_leq(n: int, limit: int) -> int:
-    """Largest divisor of ``n`` that is <= ``limit`` (n >= 1)."""
-    if n <= limit:
-        return n
-    best = 1
-    d = 1
-    while d * d <= n:
-        if n % d == 0:
-            if d <= limit:
-                best = max(best, d)
-            if n // d <= limit:
-                best = max(best, n // d)
-        d += 1
-    return best
-
-
 def _bcast_row_index(op_lead: tuple, out_lead: tuple,
                      rb: int) -> tuple[int, Callable]:
     """Block extent and row-grid index map for an interior-broadcast
@@ -152,6 +139,145 @@ def _bcast_row_index(op_lead: tuple, out_lead: tuple,
     return rb, fn
 
 
+#: most operand rows a ``rep`` operand's block may span: a row block
+#: that is a multiple of the repeat factor reads several operand rows,
+#: each re-broadcast by one select over the block (``read_block``)
+REP_SPAN = 8
+
+
+def _row_span(spec: tuple, rows: int, rb: int
+              ) -> tuple[Callable, int, int] | None:
+    """``(start, m, sb)`` for an operand whose ``rb``-row block is made
+    of ``m`` consecutive operand rows, each repeated ``rb // m`` times,
+    the first being row ``start(i)`` at grid step ``i``: ``rep`` (``rb``
+    divides the repeat factor, m = 1, or is a multiple of it) and a
+    ``bcast`` whose innermost lead dim is broadcast (m = 1).  None for
+    operands read in whole ``rb``-row blocks.
+
+    A block of a few rows of a many-row array breaks the TPU sublane
+    tile, so the kernel fetches the operand in ``sb``-row blocks — the
+    whole array when it has at most ``max(rb, 16)`` rows, else the
+    largest tile-aligned divisor within that holding whole spans — and
+    picks the rows in VMEM (``read_block``)."""
+    if spec[0] == "rep":
+        q = rows // spec[1]
+        start, m = (lambda i: (i * rb) // q), max(rb // q, 1)
+    elif spec[0] == "bcast":
+        brows, start = _bcast_row_index(spec[3], spec[4], rb)
+        if brows != 1:
+            return None
+        m = 1
+    else:
+        return None
+    sb = tiled_divisor(spec[1], max(rb, 16), full=spec[1],
+                       admit=lambda d: d % m == 0)
+    return start, m, sb
+
+
+def row_view(spec: tuple, rows: int, rb: int
+             ) -> tuple[tuple[int, int], tuple[int, int], Callable]:
+    """One operand's 2-D block view on a row grid of ``rb``-row blocks:
+    ``(view_shape, block_shape, row_fn)``, where the operand is reshaped
+    to ``view_shape`` and grid step ``i`` reads its block at block row
+    ``row_fn(i)``.  ``spec`` is a (role, op_rows, cols) triple or an
+    interior-broadcast 5-tuple (see repro.core.offload.OperandSpec).
+    Operands read a few rows at a time come in ``_row_span`` blocks;
+    ``row_picks`` says which rows of the block each step reads."""
+    role, op_rows, c = spec[0], spec[1], spec[2]
+    if role == "param":
+        return (1, c), (1, c), lambda i: 0
+    if role == "bulk":
+        return (rows, c), (rb, c), lambda i: i
+    span = _row_span(spec, rows, rb)
+    if span is not None:                  # rep, one-row bcast
+        start, _, sb = span
+        return (op_rows, c), (sb, c), lambda i: start(i) // sb
+    if role == "bcast":                   # interior broadcast
+        brows, fn = _bcast_row_index(spec[3], spec[4], rb)
+        return (op_rows, c), (brows, c), fn
+    p = op_rows // rb                     # tile: rb divides the period
+    return (op_rows, c), (rb, c), lambda i: i % p
+
+
+def row_picks(specs: Sequence[tuple], rows: int, rb: int) -> list:
+    """Per operand of a ``row_view`` layout: None when the kernel uses
+    its whole block, else ``(pick, m, rb)``: grid step ``i`` reads the
+    ``m`` rows of the fetched block from row ``pick(i)`` on, each
+    repeated ``rb // m`` times."""
+    picks = []
+    for spec in specs:
+        span = _row_span(spec, rows, rb)
+        if span is None:
+            picks.append(None)
+        else:
+            start, m, sb = span
+            picks.append((lambda i, start=start, sb=sb: start(i) % sb,
+                          m, rb))
+    return picks
+
+
+def _load_row(ref, r):
+    """Row ``r`` (traced) of a VMEM block as a [1, cols] value, in 32
+    bits for bool and packed dtypes.  A dynamic one-row load needs
+    unpacked (32-bit or bool) rows; packed rows are selected by a masked
+    sum in 32 bits, which is exact."""
+    if ref.dtype == jnp.bool_:
+        return ref[pl.ds(r, 1), :].astype(jnp.int32)
+    if ref.dtype.itemsize >= 4:
+        return ref[pl.ds(r, 1), :]
+    blk = ref[...]
+    acc = jnp.float32 if jnp.issubdtype(blk.dtype, jnp.floating) \
+        else jnp.int32
+    hit = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 0) == r
+    return jnp.sum(jnp.where(hit, blk, jnp.zeros_like(blk)).astype(acc),
+                   axis=0, keepdims=True)
+
+
+def read_block(ref, pick):
+    """An operand's value at this grid step: its whole block, or — for
+    a ``row_picks`` entry — its one picked row as a [1, cols] value, or
+    its ``m`` picked rows each repeated to fill the [rb, cols] block
+    (selected in 32 bits: Mosaic relayouts packed selects badly)."""
+    if pick is None:
+        return ref[...]
+    fn, m, rb = pick
+    r = fn(pl.program_id(0))
+    out = _load_row(ref, r)
+    if m > 1:
+        shape = (rb, out.shape[-1])
+        which = jax.lax.broadcasted_iota(jnp.int32, shape, 0) // (rb // m)
+        out = jnp.broadcast_to(out, shape)
+        for j in range(1, m):
+            out = jnp.where(which == j, _load_row(ref, r + j), out)
+    return out != 0 if ref.dtype == jnp.bool_ else out.astype(ref.dtype)
+
+
+def row_block(rows: int, specs: Sequence[tuple], limit: int,
+              period: int = 0) -> int:
+    """The row block every operand's view admits, or 0 when no operand
+    constrains it: the largest tile-aligned (``tiled_divisor``) block
+    within ``limit`` that divides every tile period, every bcast
+    operand's innermost output lead dim and ``period``, and for each
+    rep operand either divides its repeat factor or spans at most
+    ``REP_SPAN`` whole repeats of it.  Shared by the row-grid and the
+    anchored kernels, and the static verifier re-derives it."""
+    g, reps = period, []
+    for spec in specs:
+        if spec[0] == "rep":
+            reps.append(rows // spec[1])
+        elif spec[0] == "tile":
+            g = math.gcd(g, spec[1])
+        elif spec[0] == "bcast":
+            g = math.gcd(g, spec[4][-1])
+    if not (g or reps):
+        return 0
+    return tiled_divisor(
+        g or rows, limit, full=rows,
+        admit=lambda d: all(q % d == 0 or (d % q == 0
+                                           and d // q <= REP_SPAN)
+                            for q in reps))
+
+
 def segment_row_block(rows: int, specs: Sequence[tuple],
                       rows_block: int = 512,
                       donate: bool = False) -> tuple[int, int, bool]:
@@ -165,32 +291,51 @@ def segment_row_block(rows: int, specs: Sequence[tuple],
     the kernel to drop ``input_output_aliases`` unless a row-dividing
     block of acceptable size exists)."""
     limit = max(min(rows_block, rows), 1)
-    g = 0   # rb must divide every rep repeat factor and tile period
-    for spec in specs:
-        role, op_rows = spec[0], spec[1]
-        if role == "rep":
-            g = math.gcd(g, rows // op_rows)
-        elif role == "tile":
-            g = math.gcd(g, op_rows)
-        elif role == "bcast":   # must divide the innermost out lead dim
-            g = math.gcd(g, spec[4][-1])
-    # largest divisor that fits the block budget (NOT gcd with the
-    # budget, which collapses to 1 for coprime extents like 511)
-    rb = _largest_divisor_leq(g, limit) if g else limit
+    rb = row_block(rows, specs, limit) or limit
     pad = (-rows) % rb
     if pad and donate:
         # aliasing a jnp.pad temporary reuses a dead buffer, not the
         # real boundary tensor; prefer a row-dividing block (rep/tile
-        # constraints guarantee pad == 0, so g is 0 here), and only
-        # give up donation when that would tank the block size
-        alt = _largest_divisor_leq(rows, limit)
+        # constraints guarantee pad == 0, so none applies here), and
+        # only give up donation when that would tank the block size
+        alt = tiled_divisor(rows, limit, full=rows)
         if alt >= max(limit // 8, 16):
             rb, pad = alt, 0
     return rb, pad, donate and not pad
 
 
-def _seg_kernel(*refs, fn: Callable, n_in: int):
-    vals = [r[...] for r in refs[:n_in]]
+def segment_grid_layout(rows: int, specs: Sequence[tuple],
+                        out_cols: Sequence[int], rows_block: int = 512,
+                        donate: bool = False):
+    """``fused_segment_grid``'s geometry: ``(rb, pad, donate_kept,
+    in_views, out_views)`` with one ``(view, block, index_map)`` per
+    operand and per output, over the padded row extent."""
+    rb, pad, keep = segment_row_block(rows, specs, rows_block, donate)
+    padded = rows + pad
+    views = [row_view(spec, padded, rb) for spec in specs]
+    ins = [(view, block, lambda i, f=f: (f(i), 0))
+           for view, block, f in views]
+    outs = [((padded, c), (rb, c), lambda i: (i, 0)) for c in out_cols]
+    return rb, pad, keep, ins, outs
+
+
+def block_specs(operands: Sequence, in_views: Sequence,
+                out_views: Sequence, out_dtypes: Sequence):
+    """A ``(view, block, index_map)`` layout made concrete: the operands
+    reshaped to their views, their BlockSpecs, the output shapes and the
+    output BlockSpecs — the four arguments of a kernel's pallas_call."""
+    ops2 = [jnp.asarray(v).reshape(view)
+            for v, (view, _, _) in zip(operands, in_views)]
+    in_specs = [pl.BlockSpec(block, imap) for _, block, imap in in_views]
+    out_shape = [jax.ShapeDtypeStruct(view, dt)
+                 for (view, _, _), dt in zip(out_views, out_dtypes)]
+    out_specs = [pl.BlockSpec(block, imap) for _, block, imap in out_views]
+    return ops2, in_specs, out_shape, out_specs
+
+
+def _seg_kernel(*refs, fn: Callable, picks: Sequence):
+    n_in = len(picks)
+    vals = [read_block(r, p) for r, p in zip(refs[:n_in], picks)]
     outs = fn(*vals)
     for o_ref, o in zip(refs[n_in:], outs):
         o_ref[...] = o.astype(o_ref.dtype)
@@ -236,54 +381,30 @@ def fused_segment_grid(
     no extra HBM traffic (rmsnorm/softmax row stats; see
     ``repro.core.offload`` REDUCE_LANE_PRIMS admission).
     """
-    rb, pad, keep = segment_row_block(rows, specs, rows_block,
-                                      donate=bool(donate))
+    rb, pad, keep, in_views, out_views = segment_grid_layout(
+        rows, specs, out_cols, rows_block, donate=bool(donate))
     if not keep:
         donate = ()
     grid = ((rows + pad) // rb,)
 
-    ops2, in_specs = [], []
-    for spec, v in zip(specs, operands):
-        role, op_rows, c = spec[0], spec[1], spec[2]
-        v = jnp.asarray(v)
-        if role == "param":
-            ops2.append(v.reshape(1, c))
-            in_specs.append(pl.BlockSpec((1, c), lambda i: (0, 0)))
-        elif role == "bulk":
-            v2 = v.reshape(rows, c)
-            if pad:
-                v2 = jnp.pad(v2, ((0, pad), (0, 0)))
-            ops2.append(v2)
-            in_specs.append(pl.BlockSpec((rb, c), lambda i: (i, 0)))
-        elif role == "rep":
-            q = (rows // op_rows) // rb   # rb divides the repeat factor
-            ops2.append(v.reshape(op_rows, c))
-            in_specs.append(
-                pl.BlockSpec((1, c), lambda i, q=q: (i // q, 0)))
-        elif role == "bcast":             # interior broadcast
-            brows, idx_fn = _bcast_row_index(spec[3], spec[4], rb)
-            ops2.append(v.reshape(op_rows, c))
-            in_specs.append(
-                pl.BlockSpec((brows, c), lambda i, f=idx_fn: (f(i), 0)))
-        else:                             # tile: rb divides the period
-            p = op_rows // rb
-            ops2.append(v.reshape(op_rows, c))
-            in_specs.append(
-                pl.BlockSpec((rb, c), lambda i, p=p: (i % p, 0)))
-
-    out_shape = [jax.ShapeDtypeStruct((rows + pad, c), dt)
-                 for c, dt in zip(out_cols, out_dtypes)]
-    out_specs = [pl.BlockSpec((rb, c), lambda i: (i, 0)) for c in out_cols]
+    if pad:
+        operands = [jnp.pad(jnp.asarray(v).reshape(rows, spec[2]),
+                            ((0, pad), (0, 0))) if spec[0] == "bulk" else v
+                    for spec, v in zip(specs, operands)]
+    ops2, in_specs, out_shape, out_specs = block_specs(
+        operands, in_views, out_views, out_dtypes)
 
     outs = pl.pallas_call(
         functools.partial(_seg_kernel,
                           fn=functools.partial(fn, block_rows=rb),
-                          n_in=len(ops2)),
+                          picks=row_picks(specs, rows + pad, rb)),
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
         input_output_aliases=dict(donate),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*ops2)
     if not isinstance(outs, (tuple, list)):

@@ -33,7 +33,6 @@ accumulator budget and block-extent math.
 from __future__ import annotations
 
 import functools
-import math
 from typing import Callable, Sequence
 
 import jax
@@ -41,11 +40,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
 from repro.kernels.fused_elementwise import (
-    _bcast_row_index,
-    _largest_divisor_leq,
+    block_specs,
+    read_block,
+    row_block,
+    row_picks,
+    row_view,
 )
+from repro.kernels.tiling import LANE, VMEM_LIMIT_BYTES, tiled_divisor
 
 
 # VMEM budget for the f32 accumulator (and, symmetrically, the rhs
@@ -67,25 +69,70 @@ def _block_budget(block: int, n_dim: int,
 def _row_block(rows: int, epi_specs: Sequence[tuple],
                rows_block: int, n_dim: int,
                vmem_bytes: int | None = None, batch: int = 1) -> int:
-    """Row-block extent: the largest divisor of the rep/tile/bcast gcd
-    (or of ``rows``) that fits the (VMEM-clamped) block budget — exact
-    tiling, so donation aliases always hold.  With ``batch`` > 1 the
-    block must also divide the PER-BATCH row extent so every row block
-    sits inside a single batch slice of the outer grid."""
+    """Row-block extent: the largest block the epilogue operands admit
+    (``row_block``; else a divisor of ``rows``) that fits the
+    (VMEM-clamped) block budget — exact tiling, so donation aliases
+    always hold.  With ``batch`` > 1 the block must also divide the
+    PER-BATCH row extent so every row block sits inside a single batch
+    slice of the outer grid."""
     limit = max(min(_block_budget(rows_block, n_dim, vmem_bytes), rows), 1)
-    g = 0   # rows_block must divide every rep repeat factor/tile period
+    per = rows // batch if batch > 1 else 0
+    return row_block(rows, epi_specs, limit, per) or \
+        tiled_divisor(rows, limit, full=rows)
+
+
+def contraction_block(k_dim: int, n_dim: int, block: int = 512,
+            vmem_bytes: int | None = None) -> int:
+    """Contraction block extent: the largest divisor of ``k_dim`` within
+    the VMEM clamp that is lane-aligned (a multiple of 128, or all of
+    ``k_dim``) — the k block is the lhs tile's lane axis."""
+    limit = max(min(_block_budget(block, n_dim, vmem_bytes), k_dim), 1)
+    return tiled_divisor(k_dim, limit, full=k_dim, aligns=(LANE,))
+
+
+def _epi_views(epi_specs: Sequence[tuple], rows: int, rb: int) -> list:
+    """Epilogue operand views with 2-D ``(i, k)`` grid index maps."""
+    out = []
     for spec in epi_specs:
-        role, op_rows = spec[0], spec[1]
-        if role == "rep":
-            g = math.gcd(g, rows // op_rows)
-        elif role == "tile":
-            g = math.gcd(g, op_rows)
-        elif role == "bcast":   # must divide the innermost out lead dim
-            g = math.gcd(g, spec[4][-1])
-    if batch > 1:
-        per = rows // batch
-        g = math.gcd(g, per) if g else per
-    return _largest_divisor_leq(g if g else rows, limit)
+        view, block, f = row_view(spec, rows, rb)
+        out.append((view, block, lambda i, k, f=f: (f(i), 0)))
+    return out
+
+
+def matmul_layout(rows: int, k_dim: int, n_dim: int,
+                  lhs_specs: Sequence[tuple], rhs_specs: Sequence[tuple],
+                  epi_specs: Sequence[tuple], out_cols: Sequence[int], *,
+                  batch: int = 1, vmem_bytes: int | None = None,
+                  rows_block: int = 512, k_block: int = 512):
+    """The forward kernel's geometry: ``(rb, rk, ins, outs)`` with one
+    ``(view, block, index_map)`` per operand (lhs side, rhs side,
+    epilogue — the kernel's argument order) and per output."""
+    rb = _row_block(rows, epi_specs, rows_block, n_dim, vmem_bytes, batch)
+    rk = contraction_block(k_dim, n_dim, k_block, vmem_bytes)
+    q_steps = (rows // batch) // rb       # row blocks per batch slice
+    ins = []
+    for spec in lhs_specs:
+        c = spec[2]
+        if spec[0] == "param_k":
+            if c == k_dim:
+                ins.append(((1, c), (1, rk), lambda i, k: (0, k)))
+            else:               # [1, 1] scalar param
+                ins.append(((1, c), (1, c), lambda i, k: (0, 0)))
+        else:                   # bulk_k
+            ins.append(((rows, k_dim), (rb, rk), lambda i, k: (i, k)))
+    for spec in rhs_specs:
+        c = spec[2]
+        if spec[0] == "param_w":
+            ins.append(((1, c), (1, c), lambda i, k: (0, 0)))
+        elif batch > 1:         # bulk_w slice of the [batch * K, N] view
+            ins.append(((batch * k_dim, n_dim), (rk, n_dim),
+                        lambda i, k, q=q_steps, nk=k_dim // rk:
+                        ((i // q) * nk + k, 0)))
+        else:                   # bulk_w: a raw [K, N] weight-side operand
+            ins.append(((k_dim, n_dim), (rk, n_dim), lambda i, k: (k, 0)))
+    ins += _epi_views(epi_specs, rows, rb)
+    outs = [((rows, c), (rb, c), lambda i, k: (i, 0)) for c in out_cols]
+    return rb, rk, ins, outs
 
 
 def matmul_row_blocks(rows: int, epi_specs: Sequence[tuple],
@@ -103,7 +150,8 @@ def matmul_row_blocks(rows: int, epi_specs: Sequence[tuple],
 
 
 def _mm_kernel(*refs, pro_fn: Callable, rhs_pro_fn: Callable, n_lhs: int,
-               n_rhs: int, epi_fn: Callable, n_epi: int, acc_dtype):
+               n_rhs: int, epi_fn: Callable, epi_picks: Sequence, acc_dtype):
+    n_epi = len(epi_picks)
     acc_ref = refs[-1]
     ki = pl.program_id(1)
     nk = pl.num_programs(1)
@@ -119,7 +167,8 @@ def _mm_kernel(*refs, pro_fn: Callable, rhs_pro_fn: Callable, n_lhs: int,
     @pl.when(ki == nk - 1)
     def _store():
         h = acc_ref[...].astype(acc_dtype)
-        epi_vals = [r[...] for r in refs[n_lhs + n_rhs:n_lhs + n_rhs + n_epi]]
+        epi_vals = [read_block(r, p) for r, p in zip(
+            refs[n_lhs + n_rhs:n_lhs + n_rhs + n_epi], epi_picks)]
         outs = epi_fn(h, *epi_vals)
         for o_ref, o in zip(refs[n_lhs + n_rhs + n_epi:-1], outs):
             o_ref[...] = o.astype(o_ref.dtype)
@@ -170,70 +219,14 @@ def fused_matmul_segment(
     slice's [K, N] once per row block of that slice (the batch axes are
     outer grid positions realized through the block index maps).
     """
-    rb = _row_block(rows, epi_specs, rows_block, n_dim, vmem_bytes, batch)
-    rk = _largest_divisor_leq(
-        k_dim, max(min(_block_budget(k_block, n_dim, vmem_bytes),
-                       k_dim), 1))
+    rb, rk, in_views, out_views = matmul_layout(
+        rows, k_dim, n_dim, lhs_specs, rhs_specs, epi_specs, out_cols,
+        batch=batch, vmem_bytes=vmem_bytes, rows_block=rows_block,
+        k_block=k_block)
     grid = (rows // rb, k_dim // rk)
-    q_steps = (rows // batch) // rb       # row blocks per batch slice
-
-    ops2, in_specs = [], []
-    for spec, v in zip(lhs_specs, lhs_operands):
-        role, c = spec[0], spec[2]
-        v = jnp.asarray(v)
-        if role == "param_k":
-            ops2.append(v.reshape(1, c))
-            if c == k_dim:
-                in_specs.append(pl.BlockSpec((1, rk), lambda i, k: (0, k)))
-            else:               # [1, 1] scalar param
-                in_specs.append(pl.BlockSpec((1, c), lambda i, k: (0, 0)))
-        else:                   # bulk_k
-            ops2.append(v.reshape(rows, k_dim))
-            in_specs.append(pl.BlockSpec((rb, rk), lambda i, k: (i, k)))
-    for spec, v in zip(rhs_specs, rhs_operands):
-        role, c = spec[0], spec[2]
-        v = jnp.asarray(v)
-        if role == "param_w":
-            ops2.append(v.reshape(1, c))
-            in_specs.append(pl.BlockSpec((1, c), lambda i, k: (0, 0)))
-        elif batch > 1:         # bulk_w slice of the [batch * K, N] view
-            ops2.append(v.reshape(batch * k_dim, n_dim))
-            in_specs.append(pl.BlockSpec(
-                (rk, n_dim),
-                lambda i, k, q=q_steps, nk=k_dim // rk:
-                ((i // q) * nk + k, 0)))
-        else:                   # bulk_w: a raw [K, N] weight-side operand
-            ops2.append(v.reshape(k_dim, n_dim))
-            in_specs.append(pl.BlockSpec((rk, n_dim), lambda i, k: (k, 0)))
-    for spec, v in zip(epi_specs, epi_operands):
-        role, op_rows, c = spec[0], spec[1], spec[2]
-        v = jnp.asarray(v)
-        if role == "param":
-            ops2.append(v.reshape(1, c))
-            in_specs.append(pl.BlockSpec((1, c), lambda i, k: (0, 0)))
-        elif role == "bulk":
-            ops2.append(v.reshape(rows, c))
-            in_specs.append(pl.BlockSpec((rb, c), lambda i, k: (i, 0)))
-        elif role == "rep":
-            q = (rows // op_rows) // rb   # rb divides the repeat factor
-            ops2.append(v.reshape(op_rows, c))
-            in_specs.append(
-                pl.BlockSpec((1, c), lambda i, k, q=q: (i // q, 0)))
-        elif role == "bcast":             # interior broadcast
-            brows, idx_fn = _bcast_row_index(spec[3], spec[4], rb)
-            ops2.append(v.reshape(op_rows, c))
-            in_specs.append(pl.BlockSpec(
-                (brows, c), lambda i, k, f=idx_fn: (f(i), 0)))
-        else:                             # tile: rb divides the period
-            p = op_rows // rb
-            ops2.append(v.reshape(op_rows, c))
-            in_specs.append(
-                pl.BlockSpec((rb, c), lambda i, k, p=p: (i % p, 0)))
-
-    out_shape = [jax.ShapeDtypeStruct((rows, c), dt)
-                 for c, dt in zip(out_cols, out_dtypes)]
-    out_specs = [pl.BlockSpec((rb, c), lambda i, k: (i, 0))
-                 for c in out_cols]
+    operands = (*lhs_operands, *rhs_operands, *epi_operands)
+    ops2, in_specs, out_shape, out_specs = block_specs(
+        operands, in_views, out_views, out_dtypes)
     n_mm = len(lhs_operands) + len(rhs_operands)
     aliases = {n_mm + bi: oi for bi, oi in donate}
 
@@ -245,7 +238,7 @@ def fused_matmul_segment(
             n_lhs=len(lhs_operands),
             n_rhs=len(rhs_operands),
             epi_fn=functools.partial(epi_fn, block_rows=rb),
-            n_epi=len(epi_operands),
+            epi_picks=row_picks(epi_specs, rows, rb),
             acc_dtype=acc_dtype),
         grid=grid,
         in_specs=in_specs,
@@ -253,8 +246,9 @@ def fused_matmul_segment(
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((rb, n_dim), jnp.float32)],
         input_output_aliases=aliases,
-        compiler_params=_compat.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*ops2)
     if not isinstance(outs, (tuple, list)):

@@ -43,12 +43,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
 from repro.kernels.fused_elementwise import (
-    _bcast_row_index,
-    _largest_divisor_leq,
+    block_specs,
+    read_block,
+    row_picks,
 )
-from repro.kernels.fused_matmul import _block_budget, _row_block
+from repro.kernels.fused_matmul import (
+    _block_budget,
+    _epi_views,
+    _row_block,
+    contraction_block,
+)
+from repro.kernels.tiling import LANE, VMEM_LIMIT_BYTES, tiled_divisor
 
 # dx = g @ wT contracts lhs lane with RHS LANE (dim 1 of the [K,N]
 # weight): the column-major read of the forward weight.
@@ -58,7 +64,8 @@ _DRHS_DIMS = (((0,), (0,)), ((), ()))
 
 
 def _dlhs_kernel(*refs, pro_fn: Callable, epi_fn: Callable, n_lhs: int,
-                 n_epi: int, acc_dtype):
+                 epi_picks: Sequence, acc_dtype):
+    n_epi = len(epi_picks)
     acc_ref = refs[-1]
     ki = pl.program_id(1)
     nk = pl.num_programs(1)
@@ -75,10 +82,42 @@ def _dlhs_kernel(*refs, pro_fn: Callable, epi_fn: Callable, n_lhs: int,
     @pl.when(ki == nk - 1)
     def _store():
         h = acc_ref[...].astype(acc_dtype)
-        epi_vals = [r[...] for r in refs[n_lhs + 1:n_lhs + 1 + n_epi]]
+        epi_vals = [read_block(r, p) for r, p in zip(
+            refs[n_lhs + 1:n_lhs + 1 + n_epi], epi_picks)]
         outs = epi_fn(h, *epi_vals)
         for o_ref, o in zip(refs[n_lhs + 1 + n_epi:-1], outs):
             o_ref[...] = o.astype(o_ref.dtype)
+
+
+def dlhs_layout(rows: int, k_dim: int, n_dim: int,
+                lhs_specs: Sequence[tuple], epi_specs: Sequence[tuple],
+                out_cols: Sequence[int], *, batch: int = 1,
+                vmem_bytes: int | None = None, rows_block: int = 512,
+                k_block: int = 512):
+    """The dlhs kernel's geometry: ``(rb, ck, ins, outs)`` with one
+    ``(view, block, index_map)`` per operand (lhs side, the [n, k]
+    weight, epilogue) and per output."""
+    rb = _row_block(rows, epi_specs, rows_block, n_dim, vmem_bytes, batch)
+    ck = contraction_block(k_dim, n_dim, k_block, vmem_bytes)
+    q_steps = (rows // batch) // rb       # row blocks per batch slice
+    ins = []
+    for spec in lhs_specs:
+        c = spec[2]
+        if spec[0] == "param_k":
+            if c == k_dim:
+                ins.append(((1, c), (1, ck), lambda i, k: (0, k)))
+            else:               # [1, 1] scalar param
+                ins.append(((1, c), (1, c), lambda i, k: (0, 0)))
+        else:                   # bulk_k: the [rows, k_dim] cotangent
+            ins.append(((rows, k_dim), (rb, ck), lambda i, k: (i, k)))
+    if batch > 1:
+        ins.append(((batch * n_dim, k_dim), (n_dim, ck),
+                    lambda i, k, q=q_steps: (i // q, k)))
+    else:
+        ins.append(((n_dim, k_dim), (n_dim, ck), lambda i, k: (0, k)))
+    ins += _epi_views(epi_specs, rows, rb)
+    outs = [((rows, c), (rb, c), lambda i, k: (i, 0)) for c in out_cols]
+    return rb, ck, ins, outs
 
 
 def fused_matmul_dlhs_segment(
@@ -117,62 +156,13 @@ def fused_matmul_dlhs_segment(
     blocks never straddle a batch slice, and the rhs — viewed
     [batch * n_dim, k_dim] — streams its own slice per row block.
     """
-    rb = _row_block(rows, epi_specs, rows_block, n_dim, vmem_bytes, batch)
-    ck = _largest_divisor_leq(
-        k_dim, max(min(_block_budget(k_block, n_dim, vmem_bytes),
-                       k_dim), 1))
+    rb, ck, in_views, out_views = dlhs_layout(
+        rows, k_dim, n_dim, lhs_specs, epi_specs, out_cols, batch=batch,
+        vmem_bytes=vmem_bytes, rows_block=rows_block, k_block=k_block)
     grid = (rows // rb, k_dim // ck)
-    q_steps = (rows // batch) // rb       # row blocks per batch slice
-
-    ops2, in_specs = [], []
-    for spec, v in zip(lhs_specs, lhs_operands):
-        role, c = spec[0], spec[2]
-        v = jnp.asarray(v)
-        if role == "param_k":
-            ops2.append(v.reshape(1, c))
-            if c == k_dim:
-                in_specs.append(pl.BlockSpec((1, ck), lambda i, k: (0, k)))
-            else:               # [1, 1] scalar param
-                in_specs.append(pl.BlockSpec((1, c), lambda i, k: (0, 0)))
-        else:                   # bulk_k: the [rows, k_dim] cotangent
-            ops2.append(v.reshape(rows, k_dim))
-            in_specs.append(pl.BlockSpec((rb, ck), lambda i, k: (i, k)))
-    if batch > 1:
-        ops2.append(jnp.asarray(rhs).reshape(batch * n_dim, k_dim))
-        in_specs.append(pl.BlockSpec(
-            (n_dim, ck), lambda i, k, q=q_steps: (i // q, k)))
-    else:
-        ops2.append(jnp.asarray(rhs).reshape(n_dim, k_dim))
-        in_specs.append(pl.BlockSpec((n_dim, ck), lambda i, k: (0, k)))
-    for spec, v in zip(epi_specs, epi_operands):
-        role, op_rows, c = spec[0], spec[1], spec[2]
-        v = jnp.asarray(v)
-        if role == "param":
-            ops2.append(v.reshape(1, c))
-            in_specs.append(pl.BlockSpec((1, c), lambda i, k: (0, 0)))
-        elif role == "bulk":
-            ops2.append(v.reshape(rows, c))
-            in_specs.append(pl.BlockSpec((rb, c), lambda i, k: (i, 0)))
-        elif role == "rep":
-            q = (rows // op_rows) // rb   # rb divides the repeat factor
-            ops2.append(v.reshape(op_rows, c))
-            in_specs.append(
-                pl.BlockSpec((1, c), lambda i, k, q=q: (i // q, 0)))
-        elif role == "bcast":             # interior broadcast
-            brows, idx_fn = _bcast_row_index(spec[3], spec[4], rb)
-            ops2.append(v.reshape(op_rows, c))
-            in_specs.append(pl.BlockSpec(
-                (brows, c), lambda i, k, f=idx_fn: (f(i), 0)))
-        else:                             # tile: rb divides the period
-            p = op_rows // rb
-            ops2.append(v.reshape(op_rows, c))
-            in_specs.append(
-                pl.BlockSpec((rb, c), lambda i, k, p=p: (i % p, 0)))
-
-    out_shape = [jax.ShapeDtypeStruct((rows, c), dt)
-                 for c, dt in zip(out_cols, out_dtypes)]
-    out_specs = [pl.BlockSpec((rb, c), lambda i, k: (i, 0))
-                 for c in out_cols]
+    operands = (*lhs_operands, rhs, *epi_operands)
+    ops2, in_specs, out_shape, out_specs = block_specs(
+        operands, in_views, out_views, out_dtypes)
     aliases = {len(lhs_operands) + 1 + bi: oi for bi, oi in donate}
 
     outs = pl.pallas_call(
@@ -181,7 +171,7 @@ def fused_matmul_dlhs_segment(
             pro_fn=functools.partial(pro_fn, block_rows=rb),
             epi_fn=functools.partial(epi_fn, block_rows=rb),
             n_lhs=len(lhs_operands),
-            n_epi=len(epi_operands),
+            epi_picks=row_picks(epi_specs, rows, rb),
             acc_dtype=acc_dtype),
         grid=grid,
         in_specs=in_specs,
@@ -189,8 +179,9 @@ def fused_matmul_dlhs_segment(
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((rb, n_dim), jnp.float32)],
         input_output_aliases=aliases,
-        compiler_params=_compat.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*ops2)
     if not isinstance(outs, (tuple, list)):
@@ -212,10 +203,20 @@ def drhs_blocks(rows: int, n_dim: int, rows_block: int = 512,
     the row block divides the PER-BATCH row extent so no output tile
     straddles a batch slice."""
     per = rows // batch
-    nb = _largest_divisor_leq(n_dim, max(min(n_block, n_dim), 1))
-    pb = _largest_divisor_leq(
-        per, max(min(_block_budget(rows_block, nb, vmem_bytes), per), 1))
+    nb = tiled_divisor(n_dim, max(min(n_block, n_dim), 1), full=n_dim,
+                       aligns=(LANE,))
+    # the row block is the lane axis of the contraction-major lhs view
+    pb = tiled_divisor(
+        per, max(min(_block_budget(rows_block, nb, vmem_bytes), per), 1),
+        full=per, aligns=(LANE,))
     return pb, nb
+
+
+def drhs_m_block(m_dim: int, batch: int = 1, m_block: int = 512) -> int:
+    """Contraction (M) block extent of the drhs kernel: the sublane axis
+    of both streamed operands."""
+    return tiled_divisor(m_dim, max(min(m_block, m_dim), 1),
+                         full=batch * m_dim)
 
 
 def drhs_grid_blocks(rows: int, n_dim: int, rows_block: int = 512,
@@ -253,6 +254,46 @@ def _drhs_kernel(*refs, epi_fn: Callable, n_epi: int, acc_dtype):
         outs = epi_fn(h, *epi_vals)
         for o_ref, o in zip(refs[2 + n_epi:-1], outs):
             o_ref[...] = o.astype(o_ref.dtype)
+
+
+def drhs_layout(m_dim: int, rows: int, n_dim: int,
+                epi_specs: Sequence[tuple], out_cols: Sequence[int], *,
+                batch: int = 1, vmem_bytes: int | None = None,
+                rows_block: int = 512, n_block: int = 512,
+                m_block: int = 512):
+    """The drhs kernel's geometry: ``(pb, nb, mb, ins, outs)`` with one
+    ``(view, block, index_map)`` per operand (activation, cotangent,
+    epilogue) and per output."""
+    pb, nb = drhs_blocks(rows, n_dim, rows_block, n_block, vmem_bytes,
+                         batch)
+    mb = drhs_m_block(m_dim, batch, m_block)
+    q_steps = (rows // batch) // pb       # row blocks per batch slice
+    m_rows = m_dim // mb                  # m blocks per batch slice
+    lhs_view = (batch * m_dim, rows // batch)
+    rhs_view = (batch * m_dim, n_dim)
+    if batch > 1:
+        ins = [(lhs_view, (mb, pb),
+                lambda i, j, m, q=q_steps, mr=m_rows:
+                ((i // q) * mr + m, i % q)),
+               (rhs_view, (mb, nb),
+                lambda i, j, m, q=q_steps, mr=m_rows:
+                ((i // q) * mr + m, j))]
+    else:
+        ins = [(lhs_view, (mb, pb), lambda i, j, m: (m, i)),
+               (rhs_view, (mb, nb), lambda i, j, m: (m, j))]
+    for spec in epi_specs:
+        role, c = spec[0], spec[2]
+        if role == "param":
+            if c == n_dim:
+                ins.append(((1, c), (1, nb), lambda i, j, m: (0, j)))
+            else:               # [1, 1] scalar param
+                ins.append(((1, c), (1, c), lambda i, j, m: (0, 0)))
+        elif c == n_dim:        # bulk [rows, n_dim]
+            ins.append(((rows, c), (pb, nb), lambda i, j, m: (i, j)))
+        else:                   # bulk [rows, 1] column
+            ins.append(((rows, c), (pb, c), lambda i, j, m: (i, 0)))
+    outs = [((rows, c), (pb, nb), lambda i, j, m: (i, j)) for c in out_cols]
+    return pb, nb, mb, ins, outs
 
 
 def fused_matmul_drhs_segment(
@@ -293,51 +334,14 @@ def fused_matmul_drhs_segment(
     output rows, and the row-block index selects the owning batch's
     m-row range so each output tile reduces ONLY its own slice.
     """
-    pb, nb = drhs_blocks(rows, n_dim, rows_block, n_block, vmem_bytes,
-                         batch)
-    mb = _largest_divisor_leq(m_dim, max(min(m_block, m_dim), 1))
+    pb, nb, mb, in_views, out_views = drhs_layout(
+        m_dim, rows, n_dim, epi_specs, out_cols, batch=batch,
+        vmem_bytes=vmem_bytes, rows_block=rows_block, n_block=n_block,
+        m_block=m_block)
     grid = (rows // pb, n_dim // nb, m_dim // mb)
-    q_steps = (rows // batch) // pb       # row blocks per batch slice
-    m_rows = m_dim // mb                  # m blocks per batch slice
-
-    ops2 = [jnp.asarray(lhs).reshape(batch * m_dim, rows // batch),
-            jnp.asarray(rhs).reshape(batch * m_dim, n_dim)]
-    if batch > 1:
-        in_specs = [
-            pl.BlockSpec((mb, pb),
-                         lambda i, j, m, q=q_steps, mr=m_rows:
-                         ((i // q) * mr + m, i % q)),
-            pl.BlockSpec((mb, nb),
-                         lambda i, j, m, q=q_steps, mr=m_rows:
-                         ((i // q) * mr + m, j)),
-        ]
-    else:
-        in_specs = [pl.BlockSpec((mb, pb), lambda i, j, m: (m, i)),
-                    pl.BlockSpec((mb, nb), lambda i, j, m: (m, j))]
-    for spec, v in zip(epi_specs, epi_operands):
-        role, op_rows, c = spec[0], spec[1], spec[2]
-        v = jnp.asarray(v)
-        if role == "param":
-            ops2.append(v.reshape(1, c))
-            if c == n_dim:
-                in_specs.append(
-                    pl.BlockSpec((1, nb), lambda i, j, m: (0, j)))
-            else:               # [1, 1] scalar param
-                in_specs.append(
-                    pl.BlockSpec((1, c), lambda i, j, m: (0, 0)))
-        else:                   # bulk: [rows, n_dim] or a [rows, 1] column
-            ops2.append(v.reshape(rows, c))
-            if c == n_dim:
-                in_specs.append(
-                    pl.BlockSpec((pb, nb), lambda i, j, m: (i, j)))
-            else:
-                in_specs.append(
-                    pl.BlockSpec((pb, c), lambda i, j, m: (i, 0)))
-
-    out_shape = [jax.ShapeDtypeStruct((rows, c), dt)
-                 for c, dt in zip(out_cols, out_dtypes)]
-    out_specs = [pl.BlockSpec((pb, nb), lambda i, j, m: (i, j))
-                 for _ in out_cols]
+    operands = (lhs, rhs, *epi_operands)
+    ops2, in_specs, out_shape, out_specs = block_specs(
+        operands, in_views, out_views, out_dtypes)
     aliases = {2 + bi: oi for bi, oi in donate}
 
     outs = pl.pallas_call(
@@ -352,8 +356,9 @@ def fused_matmul_drhs_segment(
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((pb, nb), jnp.float32)],
         input_output_aliases=aliases,
-        compiler_params=_compat.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*ops2)
     if not isinstance(outs, (tuple, list)):

@@ -3,11 +3,14 @@ runtime mechanism.
 
 Every kernel entry point in ``repro.kernels.ops`` routes through the
 process-wide ``KernelGuard``.  A dispatch tries its impl *chain*
-(``pallas -> interpret -> ref``) in order: a launch/lowering failure of
-one impl demotes to the next, and the pure-jnp ``ref`` path — the far
-pipeline, which the MPU design guarantees can always run the program
-(§IV-B1) — is the terminal fallback that is never faulted and never
-quarantined.
+(``pallas -> ref``, ``interpret -> ref``) in order: a launch/lowering
+failure of one impl demotes to the next, and the pure-jnp ``ref`` path —
+the far pipeline, which the MPU design guarantees can always run the
+program (§IV-B1) — is the terminal fallback that is never faulted and
+never quarantined.  A compiled kernel never demotes to the Pallas
+interpreter: that would run a TPU program on the host's emulator in
+silence.  Every demotion is counted (``stats()``), so a caller that must
+run the kernel can assert that none happened.
 
 After ``threshold`` *consecutive* failures of one (kernel, impl) pair,
 that pair is **quarantined**: future chains skip it without attempting
@@ -43,7 +46,7 @@ import jax
 
 #: fallback chain per requested impl — ref (the far pipeline) is last.
 FALLBACK_CHAIN: dict[str, tuple[str, ...]] = {
-    "pallas": ("pallas", "interpret", "ref"),
+    "pallas": ("pallas", "ref"),
     "interpret": ("interpret", "ref"),
     "ref": ("ref",),
 }

@@ -43,7 +43,6 @@ from repro.kernels.fused_matmul_bwd import (
 from repro.kernels.fused_matmul_bwd import (
     fused_matmul_drhs_segment as _fused_drhs_pallas,
 )
-from repro.kernels.guard import default_impl as _default_impl
 from repro.kernels.guard import kernel_guard
 from repro.kernels.guard import resolve_impl as _resolve
 from repro.kernels.rmsnorm import rmsnorm as _rmsnorm_pallas

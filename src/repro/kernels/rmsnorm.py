@@ -15,7 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
 
 
 def _fwd_kernel(x_ref, s_ref, o_ref, *, eps: float):
@@ -83,7 +82,7 @@ def _rmsnorm_bwd(eps, rows_block, interpret, res, g2):
                    pl.BlockSpec((d,), lambda r: (0,))],
         out_shape=[jax.ShapeDtypeStruct((rows, d), x2.dtype),
                    jax.ShapeDtypeStruct((d,), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),  # ds accumulates across steps
         interpret=interpret,
     )(x2, scale, g2)

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
 
 
 def _ssd_kernel(x_ref, logd_ref, dt_ref, b_ref, c_ref, y_ref, state_ref,
@@ -102,7 +101,7 @@ def ssd_scan(
                                lambda bb, hh, cc: (bb, cc, hh, 0)),
         out_shape=jax.ShapeDtypeStruct((b, sq, h, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(x, logd, dt, bmat, cmat)

@@ -14,7 +14,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
 
 
 def _wkv6_kernel(r_ref, k_ref, v_ref, logw_ref, u_ref, y_ref, state_ref,
@@ -94,7 +93,7 @@ def wkv6(
                                lambda bb, hh, cc: (bb, cc, hh, 0)),
         out_shape=jax.ShapeDtypeStruct((b, sq, h, vv), r.dtype),
         scratch_shapes=[pltpu.VMEM((kk, vv), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(r, k, v, logw, u)

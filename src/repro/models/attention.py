@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.kernels import _compat
 from repro.models.layers import (
     Params,
     apply_rope,
@@ -291,7 +290,7 @@ def _seq_sharded_attention(q, k, v, *, causal: bool, window: int):
             q_l, k_g, v_g, causal=causal, window=window,
             q_offset=idx * s_loc)
 
-    return _compat.shard_map(
+    return jax.shard_map(
         local,
         mesh=pol.mesh,
         in_specs=(P(fsdp, "model", None, None),
@@ -416,6 +415,12 @@ def write_kv_page_entries(pages: jnp.ndarray, new: jnp.ndarray,
     return pages.at[page_ids, :, offsets].set(new.astype(pages.dtype))
 
 
+def _paged_kernel() -> bool:
+    """True where the paged decode streams pages through the Pallas
+    kernel's block index maps (TPU); elsewhere it gathers them in jnp."""
+    return jax.default_backend() == "tpu"
+
+
 def attention_decode_paged(
     params: Params,
     cfg: ModelConfig,
@@ -450,7 +455,7 @@ def attention_decode_paged(
     off = slot % page
     pages_k = write_kv_page_entries(pages_k, k[:, 0], gp, off)
     pages_v = write_kv_page_entries(pages_v, v[:, 0], gp, off)
-    if jax.default_backend() == "tpu":
+    if _paged_kernel():
         from repro.kernels import ops as kops
         out = kops.paged_decode_attention(
             q[:, 0], pages_k, pages_v, block_tables, lengths)
@@ -554,7 +559,7 @@ def _split_kv_decode_sharded(q, cache_k, cache_v, new_k, new_v, slot,
         nq = out.shape[1] * out.shape[2]
         return out.reshape(b, nq, -1).astype(q_l.dtype), kc, vc
 
-    return _compat.shard_map(
+    return jax.shard_map(
         local,
         mesh=pol.mesh,
         in_specs=(P(fsdp, None, None),

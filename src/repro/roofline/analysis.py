@@ -128,7 +128,7 @@ def jaxpr_cost(closed, *, with_fusion: bool = True,
             if hasattr(v, "aval")))
         sub_mult = None
         sub = None
-        if name == "pjit":
+        if name == "jit":
             sub, sub_mult = eqn.params["jaxpr"], 1.0
         elif name == "closed_call":
             sub, sub_mult = eqn.params["call_jaxpr"], 1.0
@@ -138,9 +138,8 @@ def jaxpr_cost(closed, *, with_fusion: bool = True,
             # axes is genuinely redundant execution and counts as such)
             sub = eqn.params["jaxpr"]
             sub_mult = float(getattr(eqn.params.get("mesh"), "size", 1))
-        elif name in ("custom_jvp_call", "custom_vjp_call",
-                      "custom_vjp_call_jaxpr"):
-            sub = eqn.params.get("call_jaxpr") or eqn.params.get("fun_jaxpr")
+        elif name in ("custom_jvp_call", "custom_vjp_call"):
+            sub = eqn.params["call_jaxpr"]
             sub_mult = 1.0
         elif name in ("remat", "checkpoint", "remat2"):
             sub, sub_mult = eqn.params["jaxpr"], 1.0

@@ -184,16 +184,49 @@ def test_mutation_missing_segment_is_decision_drift():
 def test_mutation_vmem_budget_beyond_capacity():
     """A corrupted vmem budget lets the kernel pick an accumulator block
     larger than physical VMEM — the one accumulator case that is an
-    error, not the advisory 8-row-floor warning."""
+    error, not the advisory 8-row-floor warning.  (A 16 MiB policy
+    budget keeps the 128-wide k block the tiling rule needs at N=32768;
+    the mutation then lifts the clamp to a 512-row, 64 MiB
+    accumulator.)"""
     x = jnp.zeros((512, 256))
-    w = jnp.zeros((256, 65536))
+    w = jnp.zeros((256, 32768))
     plan = offload_report(lambda x, w: jnp.tanh(x @ w) * 2.0, x, w,
-                          bulk_threshold=64)
+                          policy=OffloadPolicy(bulk_threshold=64,
+                                               vmem_budget=16 << 20))
     seg = next(s for s in plan.segments
                if s.matmul is not None and s.matmul.form == "fwd")
     assert not has_errors(verify_plan(plan))
     seg.vmem_bytes = 1 << 40
     assert "vmem-accumulator" in _rules(verify_plan(plan))
+
+
+def test_mutation_tpu_tiling():
+    """A corrupted vmem budget shrinks the k block of a fused anchor
+    below the 128-lane tile: the TPU compiler would refuse the kernel,
+    and the verifier says so (rule ``tpu-tiling``)."""
+    x = jnp.zeros((64, 512))
+    w = jnp.zeros((512, 1024))
+    plan = offload_report(lambda x, w: jnp.tanh(x @ w) * 2.0, x, w,
+                          bulk_threshold=64)
+    seg = next(s for s in plan.segments if s.matmul is not None)
+    assert not seg.tiling_violations()
+    assert "tpu-tiling" not in _rules(verify_plan(plan))
+    seg.vmem_bytes = 4096
+    assert "tpu-tiling" in _rules(verify_plan(plan))
+
+
+def test_planner_declines_untileable_anchor():
+    """The LM-head shape: N=152064 leaves the VMEM clamp an 8-wide k
+    block, which no TPU block may have — the planner declines the anchor
+    (recording why) instead of emitting a kernel that cannot compile."""
+    h = jax.ShapeDtypeStruct((4, 2048), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((2048, 152064), jnp.bfloat16)
+    plan = offload_report(lambda h, w: jnp.tanh(h @ w), h, w)
+    assert not any(s.matmul is not None for s in plan.segments)
+    (anchor,) = [d for d in plan.decisions if d.tier == "anchor"]
+    assert not anchor.fused
+    assert anchor.reason.startswith("TPU block tiling: ")
+    assert not has_errors(verify_plan(plan))
 
 
 # ---------------------------------------------------------------------------

@@ -29,7 +29,8 @@ from repro.sharding import cache_spec_tree, param_spec_tree, to_shardings
 from repro.sharding.constraints import activation_sharding
 
 AXES, SHAPE = ("data", "model"), (2, 4)
-mesh = jax.make_mesh(SHAPE, AXES)
+mesh = jax.make_mesh(SHAPE, AXES,
+                     axis_types=(jax.sharding.AxisType.Auto,) * len(AXES))
 
 # a reduced config whose dims divide the mesh: heads 4 % 4 == 0 but
 # kv heads 2 % 4 != 0 -> exercises the seq_mp + split-KV shard_map paths

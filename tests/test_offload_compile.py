@@ -22,6 +22,7 @@ Covers the acceptance contract of the rewriter:
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import (
     mpu_offload,
@@ -236,7 +237,9 @@ def test_broadcast_fusion_numerics_vs_ref_dtypes():
 
 def test_row_broadcast_rep_operand_fuses():
     """[B,1,D] against [B,S,D] fuses via a rep index map instead of
-    ending the segment."""
+    ending the segment.  Each block reads one operand row, fetched in a
+    block that meets the TPU tiling rule (here the whole [B, D] array)
+    and picked in VMEM."""
     def gated(a, m):
         return jnp.tanh(a) * m + a * 0.5
 
@@ -246,9 +249,35 @@ def test_row_broadcast_rep_operand_fuses():
     assert len(plan.segments) == 1
     roles = {sp.role for sp in plan.segments[0].operand_specs}
     assert "rep" in roles
+    assert not plan.segments[0].tiling_violations()
     got = mpu_offload(gated, bulk_threshold=1024, impl="interpret")(a, m)
     np.testing.assert_allclose(np.asarray(got), np.asarray(gated(a, m)),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("b,s", [(2, 1024), (4, 8), (256, 16)])
+def test_rep_operand_row_spans(b, s, dtype):
+    """A [B,1,D] rep operand against [B,S,D] under each way a row block
+    can meet it: one operand row per block (S=1024), a whole-array
+    block of several repeats (S=8), and 8-row spans fetched in 128-row
+    blocks of a 256-row operand (S=16).  f32 rows are loaded with a
+    dynamic one-row slice, bf16 rows picked by a masked sum; both equal
+    the un-offloaded function."""
+    def gated(a, m):
+        return jnp.tanh(a) * m + a * 0.5
+
+    a = _rand((b, s, 128)).astype(dtype)
+    m = _rand((b, 1, 128), 1).astype(dtype)
+    plan = offload_report(gated, a, m, bulk_threshold=1024)
+    (seg,) = plan.segments
+    assert "rep" in {sp.role for sp in seg.operand_specs}
+    assert not seg.tiling_violations()
+    got = mpu_offload(gated, bulk_threshold=1024, impl="interpret")(a, m)
+    tol = 1e-5 if dtype == jnp.float32 else 1e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(gated(a, m), np.float32),
+                               rtol=tol, atol=tol)
 
 
 def test_lane_split_swiglu_fuses():
@@ -466,7 +495,7 @@ def test_pjit_donated_invars_survive_rewrite():
     x, w = _rand((32, 32)), _rand((32, 32), 1) * 0.1
     fn = mpu_offload(f, bulk_threshold=64, impl="interpret")
     rewritten = fn.rewritten(x, w)
-    pjits = [e for e in rewritten.jaxpr.eqns if e.primitive.name == "pjit"]
+    pjits = [e for e in rewritten.jaxpr.eqns if e.primitive.name == "jit"]
     assert pjits, "pjit eqn was dropped by the rewrite"
     assert any(any(e.params.get("donated_invars", ())) for e in pjits)
     got = fn(x, w)

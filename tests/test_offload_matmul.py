@@ -392,7 +392,7 @@ def test_f64_dot_not_anchored():
     def fn(x, w, b):
         return jax.nn.gelu(x @ w + b)
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(fn)(
             jax.ShapeDtypeStruct((128, 64), jnp.float64),
             jax.ShapeDtypeStruct((64, 64), jnp.float64),
@@ -513,16 +513,38 @@ def test_interior_broadcast_fuses():
     dims.  With the "bcast" operand role the row-block index decomposes
     over the output's leading dims and strides only the operand's
     non-broadcast dims, so the whole chain fuses as ONE segment instead
-    of conservatively splitting (the former ROADMAP limitation)."""
+    of conservatively splitting (the former ROADMAP limitation).  Here
+    the operand's innermost lead dim is broadcast, so each row block
+    reads ONE operand row, fetched in a tile-legal block and picked in
+    VMEM.  (The row block divides the innermost output lead dim, 8
+    here, so the bulk blocks meet the sublane tile too.)"""
+    def fn(a, m):
+        return jnp.tanh(a) * m + a * 0.5
+
+    a = _rand((2, 3, 8, 8, 16))
+    m = _rand((2, 1, 8, 1, 16), 1)
+    plan = offload_report(fn, a, m, bulk_threshold=64)
+    assert len(plan.segments) == 1
+    roles = {s.role for s in plan.segments[0].operand_specs}
+    assert "bcast" in roles, f"expected a bcast operand, got {roles}"
+    assert not plan.segments[0].tiling_violations()
+    _check(fn, a, m)
+
+
+def test_interior_broadcast_untileable_row_block_declines():
+    """With an innermost output lead dim of 5 the row block must divide
+    5, so the [240, 16] bulk operand would be read in 5-row blocks,
+    which the TPU sublane tile forbids: the candidate declines with the
+    tiling rule as its reason and the function still computes right."""
     def fn(a, m):
         return jnp.tanh(a) * m + a * 0.5
 
     a = _rand((2, 3, 8, 5, 16))
     m = _rand((2, 1, 8, 1, 16), 1)
     plan = offload_report(fn, a, m, bulk_threshold=64)
-    assert len(plan.segments) == 1
-    roles = {s.role for s in plan.segments[0].operand_specs}
-    assert "bcast" in roles, f"expected a bcast operand, got {roles}"
+    assert len(plan.decisions) == 1 and not plan.segments
+    assert plan.decisions[0].reason.startswith(
+        "TPU block tiling: block (5, 16)")
     _check(fn, a, m)
 
 

@@ -430,3 +430,21 @@ def test_engine_legacy_kwargs_warn_and_policy_threads(rng):
     report = eng2.explain_decode()
     assert isinstance(report, DecisionReport)
     assert report.policy.mode == "cost"
+
+
+@pytest.mark.parametrize("platform,kind,ok", [
+    ("cpu", "cpu", True), ("tpu", "TPU v5 lite", True),
+    ("tpu", "TPU v4", False), ("gpu", "NVIDIA H100", False)])
+def test_cost_mode_refuses_undescribed_device(platform, kind, ok):
+    """mode="cost" prices with the machine model's constants: on an
+    accelerator they do not describe it refuses instead of pricing the
+    wrong chip (a CPU host plans for the modelled machine)."""
+    from types import SimpleNamespace
+
+    device = SimpleNamespace(platform=platform, device_kind=kind)
+    policy = OffloadPolicy(mode="cost")
+    if ok:
+        policy.check_cost_target(device)
+    else:
+        with pytest.raises(ValueError, match=kind):
+            policy.check_cost_target(device)

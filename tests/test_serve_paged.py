@@ -301,3 +301,43 @@ def test_verify_paged_tables_catches_corruption():
     eng.pool.tables[0, 1] = eng.num_pages + 7
     rules = {f.rule for f in eng.verify_paged_tables()}
     assert "page-table-bounds" in rules
+
+
+def test_offloaded_paged_decode_with_kernel_matches(monkeypatch):
+    """The decode step on its paged-kernel branch (the TPU path, here in
+    interpret mode) through the offload rewriter: the rewritten program
+    re-binds the ``pallas_call`` eqn among its segments, and its logits
+    and page pools equal the un-offloaded step's."""
+    import repro.kernels.guard as guard
+    import repro.models.attention as attention
+    from repro.core.offload import mpu_offload
+    from repro.core.policy import OffloadPolicy
+
+    monkeypatch.setattr(attention, "_paged_kernel", lambda: True)
+    monkeypatch.setattr(guard, "default_impl", lambda: "interpret")
+    cfg, params = _mk(dtype="float32")
+    model = build_model(cfg)
+    slots, page, max_len = 4, 8, 32
+    pages = 1 + slots * (max_len // page)
+    cache = model.init_paged_cache(slots, pages, page)
+    cache = jax.tree.map(
+        lambda a: _rand(a.size, a.shape, a.dtype)
+        if jnp.issubdtype(a.dtype, jnp.floating) else a, cache)
+    args = (params, cache, jnp.asarray([3, 17, 250, 9], jnp.int32),
+            jnp.asarray([5, 8, 15, 30], jnp.int32),
+            jnp.arange(1, pages, dtype=jnp.int32).reshape(slots, -1),
+            jnp.asarray([True, True, False, True]))
+
+    def paged_decode(params, cache, tok, pos, tables, active):
+        return model.decode_step_paged(params, cache, tok, pos, tables,
+                                       active, max_len=max_len)
+
+    assert "pallas_call" in str(jax.make_jaxpr(paged_decode)(*args))
+    wrapped = mpu_offload(paged_decode, policy=OffloadPolicy(
+        bulk_threshold=64, impl="interpret"))
+    assert wrapped.explain(*args).n_fused >= 1
+    got = jax.jit(wrapped)(*args)
+    want = jax.jit(paged_decode)(*args)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-5, atol=1e-5)

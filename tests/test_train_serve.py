@@ -149,3 +149,29 @@ def test_engine_swa_generation_crosses_window_boundary():
         pos += 1
         want.append(tok)
     assert got == want
+
+
+@pytest.mark.parametrize("n,shape", [(1, (1, 1)), (2, (2, 1)), (4, (2, 2)),
+                                     (8, (4, 2)), (256, (16, 16))])
+def test_train_mesh_shape_from_device_count(n, shape):
+    from repro.launch.train import mesh_shape
+    assert mesh_shape(n) == shape
+
+
+def test_train_launcher_refuses_offload_on_a_sharded_mesh():
+    """Mosaic kernels cannot be partitioned automatically, so the
+    launcher refuses --offload on a mesh of more than one device instead
+    of running a different plan; one device is fine."""
+    from types import SimpleNamespace
+
+    from repro.launch.train import check_offload_mesh
+
+    def trainer(offload, size):
+        return SimpleNamespace(tcfg=SimpleNamespace(offload=offload),
+                               mesh=SimpleNamespace(size=size),
+                               mesh_shape=(size, 1))
+
+    check_offload_mesh(trainer(True, 1))
+    check_offload_mesh(trainer(False, 4))
+    with pytest.raises(SystemExit, match="--offload"):
+        check_offload_mesh(trainer(True, 4))
