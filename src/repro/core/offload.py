@@ -133,6 +133,7 @@ from typing import Any, Callable, Sequence
 import jax
 import jax.numpy as jnp
 from jax.extend import core as jcore
+from jax.extend import source_info_util
 
 from repro.core.isa import Loc
 from repro.core.locator import (
@@ -423,6 +424,9 @@ class OffloadPlan:
     # under rides along so a plan is self-describing.
     decisions: list[SegmentDecision] = field(default_factory=list)
     policy: OffloadPolicy | None = None
+    # name stack of the eqn (scan, jit) whose body this plan covers,
+    # outer ones first; set by the runner, "" at the top level
+    scope: str = ""
 
     def report(self) -> DecisionReport:
         """The per-segment decision report (see ``DecisionReport``),
@@ -435,9 +439,12 @@ class OffloadPlan:
         from repro.core.policy import DEFAULT_POLICY
 
         statuses = decision_statuses(self)
+        eqns = self.annotation.jaxpr.jaxpr.eqns
         return DecisionReport(
             policy=self.policy or DEFAULT_POLICY,
-            decisions=[d._with(verified=s)
+            decisions=[d._with(verified=s, scope="/".join(
+                           x for x in (self.scope, _decision_scope(eqns, d))
+                           if x))
                        for d, s in zip(self.decisions, statuses)],
             naive_bytes=self.naive_hbm_bytes,
             fused_bytes=self.fused_hbm_bytes,
@@ -626,12 +633,26 @@ def _inline_body(eqn) -> Any | None:
     return body
 
 
+def _eqn_scope(eqn, *extra: str):
+    """Trace under ``eqn``'s own source info, as ``jax.core.eval_jaxpr``
+    re-binds an eqn: its name stack (the model's ``jax.named_scope``s)
+    extends the current one, so what the re-bind emits keeps those names
+    in the compiled program's op metadata.  ``extra`` scopes go on top."""
+    stack = source_info_util.current_name_stack() + eqn.source_info.name_stack
+    for name in extra:
+        stack = stack.extend(name)
+    return source_info_util.user_context(eqn.source_info.traceback,
+                                         name_stack=stack)
+
+
 def _bind_eqn(eqn, invals) -> tuple:
-    """Re-bind ``eqn``'s primitive on ``invals``; always a tuple of outs.
-    ``get_bind_params`` turns jaxpr-valued params (a ``custom_vjp_call``'s
-    rules, a ``jit``'s body) back into what ``bind`` expects."""
+    """Re-bind ``eqn``'s primitive on ``invals`` under its own source
+    info; always a tuple of outs.  ``get_bind_params`` turns jaxpr-valued
+    params (a ``custom_vjp_call``'s rules, a ``jit``'s body) back into
+    what ``bind`` expects."""
     subfuns, params = eqn.primitive.get_bind_params(eqn.params)
-    out = eqn.primitive.bind(*subfuns, *invals, **params)
+    with _eqn_scope(eqn):
+        out = eqn.primitive.bind(*subfuns, *invals, **params)
     return tuple(out) if eqn.primitive.multiple_results else (out,)
 
 
@@ -657,7 +678,8 @@ def _flatten_calls(closed: jcore.ClosedJaxpr) -> jcore.ClosedJaxpr:
         for eqn in c.jaxpr.eqns:
             body = _inline_body(eqn)
             if body is not None:
-                outs = ev(body, [read(v) for v in eqn.invars])
+                with _eqn_scope(eqn):
+                    outs = ev(body, [read(v) for v in eqn.invars])
             else:
                 outs = _bind_eqn(eqn, [read(v) for v in eqn.invars])
             for var, val in zip(eqn.outvars, outs):
@@ -1776,14 +1798,10 @@ def plan_offload(closed: jcore.ClosedJaxpr, *,
             tier="anchor" if anchor_spec is not None else "elementwise",
             n_compute=n_compute, near_bytes=seg.io_bytes(),
             far_bytes=far_b, tiling=tiling[0] if tiling else None)
-        form = None
-        if anchor_spec is not None:
-            form = "flash" if anchor_spec.flash is not None \
-                else anchor_spec.form
         decision = decision._with(
-            form=form, rows=cur_rows, roles=tuple(roles),
+            form=_segment_form(seg), rows=cur_rows, roles=tuple(roles),
             batch=anchor_spec.batch_shape if anchor_spec is not None
-            else ())
+            else (), eqn=min(seg.all_eqn_idx))
         decisions.append(decision)
         if decision.fused:
             segments.append(seg)
@@ -1998,6 +2016,41 @@ def _segment_arg_vars(seg: Segment) -> list[Any]:
     return arg_vars
 
 
+def _segment_form(seg: Segment) -> str | None:
+    """A segment's anchor form: None for an elementwise grid, ``flash``
+    for a flash anchor (whose base form is dlhs), else fwd/dlhs/drhs."""
+    mm = seg.matmul
+    if mm is None:
+        return None
+    return "flash" if mm.flash is not None else mm.form
+
+
+def _form_kernel(form: str | None) -> str:
+    """The guarded kernel a segment of ``form`` runs as."""
+    return {None: "fused_segment_grid", "drhs": "fused_matmul_drhs",
+            "flash": "fused_flash",
+            "dlhs": "fused_matmul_dlhs"}.get(form, "fused_matmul")
+
+
+def _segment_kernel(seg: Segment) -> str:
+    return _form_kernel(_segment_form(seg))
+
+
+def _decision_scope(eqns: Sequence, d: SegmentDecision) -> str:
+    """The name a candidate's ops carry in the compiled program, relative
+    to the program holding it: its first eqn's name stack, then
+    ``near/<kernel>`` when it runs as a fused kernel (the rewriter
+    dispatches it under ``near``, the kernel guard under the kernel).
+    Read from the eqns as traced, so a plan replayed from the plan
+    cache names what this trace's program carries."""
+    if not 0 <= d.eqn < len(eqns):
+        return ""
+    stack = eqns[d.eqn].source_info.name_stack
+    if d.fused:
+        stack = stack.extend("near").extend(_form_kernel(d.form))
+    return str(stack)
+
+
 def _segment_dispatch(eqns: Sequence, seg: Segment, vals: Sequence, *,
                       impl: str, donate: Sequence[tuple[int, int]] = ()):
     """Dispatch one planned segment to its fused kernel, routing by
@@ -2007,7 +2060,8 @@ def _segment_dispatch(eqns: Sequence, seg: Segment, vals: Sequence, *,
     epi_meta = tuple(s.meta for s in seg.operand_specs)
     out_dtypes = [v.aval.dtype for v in seg.outputs]
     mm = seg.matmul
-    if mm is None:
+    kernel = _segment_kernel(seg)
+    if kernel == "fused_segment_grid":
         return kops.fused_segment_grid(
             _segment_fn(eqns, seg), list(vals), epi_meta, rows=seg.rows,
             out_cols=seg.out_cols, out_dtypes=out_dtypes, donate=donate,
@@ -2016,16 +2070,15 @@ def _segment_dispatch(eqns: Sequence, seg: Segment, vals: Sequence, *,
     lhs_vals = list(vals[:n_lhs])
     rhs_vals = list(vals[n_lhs:n_lhs + n_rhs])
     epi_vals = list(vals[n_lhs + n_rhs:])
-    if mm.form == "drhs":
+    if kernel == "fused_matmul_drhs":
         return kops.fused_matmul_drhs_segment(
             _segment_fn(eqns, seg), lhs_vals[0], rhs_vals[0], epi_vals,
             epi_meta, m_dim=mm.k, rows=seg.rows, n_dim=mm.n,
             acc_dtype=mm.out_dtype, out_cols=seg.out_cols,
             out_dtypes=out_dtypes, donate=donate, impl=impl,
             batch=mm.batch, vmem_bytes=seg.vmem_bytes)
-    if mm.flash is not None:
-        # QK^T -> scale/softmax -> PV as ONE segment; must route before
-        # the plain dlhs check (a flash anchor's base form IS dlhs)
+    if kernel == "fused_flash":
+        # QK^T -> scale/softmax -> PV as ONE segment
         fl = mm.flash
         return kops.fused_flash_segment(
             _flash_softmax_fn(eqns, mm), lhs_vals[0], rhs_vals[0],
@@ -2034,7 +2087,7 @@ def _segment_dispatch(eqns: Sequence, seg: Segment, vals: Sequence, *,
             scores_shape=fl["scores_shape"],
             scores_dtype=fl["scores_dtype"], out_dtype=out_dtypes[0],
             impl=impl)
-    if mm.form == "dlhs":
+    if kernel == "fused_matmul_dlhs":
         return kops.fused_matmul_dlhs_segment(
             _prologue_fn(eqns, mm), _segment_fn(eqns, seg), lhs_vals,
             tuple(s.meta for s in mm.lhs_specs), rhs_vals[0], epi_vals,
@@ -2468,7 +2521,7 @@ class _PlanLedger:
 
 def _build_runner(closed: jcore.ClosedJaxpr, *, policy: OffloadPolicy,
                   donate_leaves: Sequence[int] = (),
-                  ledger: "_PlanLedger | None" = None
+                  ledger: "_PlanLedger | None" = None, scope: str = ""
                   ) -> tuple[Callable, OffloadPlan, jcore.ClosedJaxpr]:
     """The compile-time pass: flatten + plan once under ``policy``, then
     bake every offload decision into a flat list of step closures.
@@ -2484,7 +2537,8 @@ def _build_runner(closed: jcore.ClosedJaxpr, *, policy: OffloadPolicy,
     ``ledger`` threads the persistent plan cache through the recursion:
     in replay mode each level's plan is reconstructed from the durable
     payload instead of running the planner; in record mode each level's
-    plan is captured for persistence."""
+    plan is captured for persistence.  ``scope`` is the name stack of
+    the eqns whose bodies hold this level (``OffloadPlan.scope``)."""
     closed = _flatten_calls(closed)
     donate_invars = frozenset(closed.jaxpr.invars[i] for i in donate_leaves)
     if ledger is not None and ledger.replaying:
@@ -2494,15 +2548,17 @@ def _build_runner(closed: jcore.ClosedJaxpr, *, policy: OffloadPolicy,
                             donate_invars=donate_invars)
         if ledger is not None:
             ledger.record(closed, plan)
+    plan.scope = scope
     jaxpr = closed.jaxpr
     eqns = jaxpr.eqns
     seg_by_start = {s.span_start: s for s in plan.segments}
 
-    def recurse(inner: jcore.ClosedJaxpr, donate_inner: Sequence[int] = ()
-                ) -> tuple[Callable, tuple]:
+    def recurse(eqn, inner: jcore.ClosedJaxpr,
+                donate_inner: Sequence[int] = ()) -> tuple[Callable, tuple]:
         inner_run, inner_plan, inner_flat = _build_runner(
             inner, policy=policy, donate_leaves=donate_inner,
-            ledger=ledger)
+            ledger=ledger, scope="/".join(
+                x for x in (scope, str(eqn.source_info.name_stack)) if x))
         plan.inner_plans.append(inner_plan)
         return inner_run, tuple(inner_flat.consts)
 
@@ -2511,9 +2567,11 @@ def _build_runner(closed: jcore.ClosedJaxpr, *, policy: OffloadPolicy,
         arg_vars = _segment_arg_vars(seg)
         call = _segment_vjp(eqns, seg, donate=tuple(seg.donations),
                             policy=policy)
+        first = eqns[min(seg.all_eqn_idx)]
 
         def step(env, read):
-            outs = call(*[read(v) for v in arg_vars])
+            with _eqn_scope(first, "near"):
+                outs = call(*[read(v) for v in arg_vars])
             for var, val, shp in zip(seg.outputs, outs, out_shapes):
                 env[var] = val.reshape(shp)
         return step
@@ -2527,7 +2585,7 @@ def _build_runner(closed: jcore.ClosedJaxpr, *, policy: OffloadPolicy,
         # carries, so in-place reuse within one iteration is safe; the
         # planner still verifies the value is dead past the segment)
         inner_run, inner_consts = recurse(
-            p["jaxpr"], donate_inner=tuple(
+            eqn, p["jaxpr"], donate_inner=tuple(
                 range(n_consts, n_consts + n_carry)))
 
         def step(env, read):
@@ -2540,17 +2598,20 @@ def _build_runner(closed: jcore.ClosedJaxpr, *, policy: OffloadPolicy,
                 outs = inner_run(inner_consts, (*sc, *carry, *x))
                 return tuple(outs[:n_carry]), tuple(outs[n_carry:])
 
-            carry, ys = jax.lax.scan(
-                body, carry0, xs, length=p["length"],
-                reverse=p.get("reverse", False),
-                unroll=p.get("unroll", 1))
+            with _eqn_scope(eqn):
+                carry, ys = jax.lax.scan(
+                    body, carry0, xs, length=p["length"],
+                    reverse=p.get("reverse", False),
+                    unroll=p.get("unroll", 1))
             for var, val in zip(eqn.outvars, (*carry, *ys)):
                 env[var] = val
         return step
 
     def make_inline_call_step(eqn, inner_run, inner_consts) -> Callable:
         def step(env, read):
-            outs = inner_run(inner_consts, [read(v) for v in eqn.invars])
+            with _eqn_scope(eqn):
+                outs = inner_run(inner_consts,
+                                 [read(v) for v in eqn.invars])
             for var, val in zip(eqn.outvars, outs):
                 env[var] = val
         return step
@@ -2559,7 +2620,7 @@ def _build_runner(closed: jcore.ClosedJaxpr, *, policy: OffloadPolicy,
         """Re-emit non-trivial jit eqns through ``jax.jit`` so their
         in/out shardings and donated invars survive the rewrite instead
         of being dropped on inlining."""
-        inner_run, inner_consts = recurse(eqn.params["jaxpr"])
+        inner_run, inner_consts = recurse(eqn, eqn.params["jaxpr"])
         in_sh = eqn.params.get("in_shardings", ())
         out_sh = eqn.params.get("out_shardings", ())
         donated = tuple(i for i, d
@@ -2583,7 +2644,8 @@ def _build_runner(closed: jcore.ClosedJaxpr, *, policy: OffloadPolicy,
         jitted = jax.jit(call, donate_argnums=donated, **jit_kwargs)
 
         def step(env, read):
-            outs = jitted(*[read(v) for v in eqn.invars])
+            with _eqn_scope(eqn):
+                outs = jitted(*[read(v) for v in eqn.invars])
             for var, val in zip(eqn.outvars, outs):
                 env[var] = val
         return step

@@ -317,6 +317,14 @@ class SegmentDecision:
     fused: bool
     reason: str
     batch: tuple = ()            # batch grid axes of a batched anchor
+    # the candidate's first eqn in the planned jaxpr (-1: not known)
+    eqn: int = -1
+    # filled by OffloadPlan.report() from the eqn's name stack, ending in
+    # ``near/<kernel>`` when fused, with the call sites of the bodies
+    # holding it in front: the name the candidate's ops carry in the
+    # compiled program (a device trace's op names read the same string
+    # under the program's and loops' own components)
+    scope: str = ""
     # decision-vs-plan cross-check, filled by OffloadPlan.report():
     # "ok" when the emitted segment matches this row, "MISMATCH(...)"
     # when it disagrees (rows/form drift), "MISSING-SEGMENT" when a
@@ -386,7 +394,9 @@ class DecisionReport:
         for r, d in zip(rows[1:], self.all_decisions()):
             line = "  ".join(c.ljust(w) for c, w in zip(r, widths))
             lines.append(f"{line}  {d.reason}")
+            pad = " " * (sum(widths) + 2 * len(widths))
             if d.roles:
-                lines.append(" " * (sum(widths) + 2 * len(widths))
-                             + f"operands: {', '.join(d.roles)}")
+                lines.append(f"{pad}operands: {', '.join(d.roles)}")
+            if d.scope:
+                lines.append(f"{pad}scope: {d.scope}")
         return "\n".join(lines)

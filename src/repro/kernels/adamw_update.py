@@ -60,6 +60,7 @@ def adamw_update(
         out_shape=[jax.ShapeDtypeStruct(p2.shape, p.dtype),
                    jax.ShapeDtypeStruct(p2.shape, jnp.float32),
                    jax.ShapeDtypeStruct(p2.shape, jnp.float32)],
+        name="adamw_update",
         interpret=interpret,
     )(p2, g2, m2, v2, hyper)
     unflat = lambda a: a[:rows].reshape(shape)

@@ -138,6 +138,7 @@ def decode_attention(
         out_shape=jax.ShapeDtypeStruct((b, nk, g, h), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="decode_attention",
         interpret=interpret,
     )(lengths.astype(jnp.int32), qr, kr, vr)
     return out.reshape(b, nq, h)
@@ -240,6 +241,7 @@ def paged_decode_attention(
         out_shape=jax.ShapeDtypeStruct((b, nk, g, h), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="paged_decode_attention",
         interpret=interpret,
     )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32), qr,
       k_pages.reshape(-1, nk, page, h), v_pages.reshape(-1, nk, page, h))

@@ -162,6 +162,7 @@ def flash_attention(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
+        name="flash_attention",
         interpret=interpret,
     )(qr, kr, vr)
     out = res[0] if return_lse else res[0]

@@ -205,6 +205,7 @@ def flash_attention_bwd(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
+        name="flash_attention_dkv",
         interpret=interpret,
     )(ql, kl, vl, dol, lsel, dvecl)
 
@@ -225,6 +226,7 @@ def flash_attention_bwd(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
+        name="flash_attention_dq",
         interpret=interpret,
     )(ql, kl, vl, dol, lsel, dvecl)
 

@@ -87,6 +87,7 @@ def fused_elementwise(
         out_specs=[pl.BlockSpec((rows_block, c), lambda r: (r, 0))
                    for _ in out_shape],
         out_shape=out_shape,
+        name="fused_elementwise",
         interpret=interpret,
     )(*b2, *p2)
     if not isinstance(outs, (tuple, list)):
@@ -405,6 +406,7 @@ def fused_segment_grid(
         input_output_aliases=dict(donate),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        name="fused_segment_grid",
         interpret=interpret,
     )(*ops2)
     if not isinstance(outs, (tuple, list)):

@@ -249,6 +249,7 @@ def fused_matmul_segment(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        name="fused_matmul",
         interpret=interpret,
     )(*ops2)
     if not isinstance(outs, (tuple, list)):

@@ -182,6 +182,7 @@ def fused_matmul_dlhs_segment(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        name="fused_matmul_dlhs",
         interpret=interpret,
     )(*ops2)
     if not isinstance(outs, (tuple, list)):
@@ -359,6 +360,7 @@ def fused_matmul_drhs_segment(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        name="fused_matmul_drhs",
         interpret=interpret,
     )(*ops2)
     if not isinstance(outs, (tuple, list)):

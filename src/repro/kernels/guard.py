@@ -86,7 +86,6 @@ class KernelGuard:
     quarantines: int = 0            # (kernel, impl) pairs ever quarantined
     _consec: dict[tuple[str, str], int] = field(default_factory=dict)
     _quarantined: set[tuple[str, str]] = field(default_factory=set)
-    _per_kernel: dict[str, dict[str, int]] = field(default_factory=dict)
 
     # -- queries ------------------------------------------------------------
     def is_quarantined(self, kernel: str, impl: str) -> bool:
@@ -109,27 +108,17 @@ class KernelGuard:
             return False
         return any((k, im) in self._quarantined for k in SEGMENT_KERNELS)
 
-    def health(self) -> dict[str, dict[str, int]]:
-        """Per-kernel failure/fallback counts (for debugging/reports)."""
-        return {k: dict(v) for k, v in self._per_kernel.items()}
-
     def stats(self) -> dict[str, int]:
         return {"kernel_failures": self.kernel_failures,
                 "kernel_fallbacks": self.kernel_fallbacks,
                 "quarantines": self.quarantines}
 
     # -- bookkeeping --------------------------------------------------------
-    def _bump(self, kernel: str, key: str) -> None:
-        self._per_kernel.setdefault(kernel, {})
-        self._per_kernel[kernel][key] = \
-            self._per_kernel[kernel].get(key, 0) + 1
-
     def record_failure(self, kernel: str, impl: str) -> bool:
         """Count one failed attempt; returns True if this failure
         tripped the quarantine.  ref never quarantines (a ref failure
         is a real bug, not a flaky launch)."""
         self.kernel_failures += 1
-        self._bump(kernel, f"failures_{impl}")
         if impl == "ref":
             return False
         key = (kernel, impl)
@@ -139,7 +128,6 @@ class KernelGuard:
             self._quarantined.add(key)
             self.quarantines += 1
             self.epoch += 1
-            self._bump(kernel, f"quarantined_{impl}")
             return True
         return False
 
@@ -160,14 +148,20 @@ class KernelGuard:
         """Run ``attempt(im)`` for each impl in the fallback chain until
         one succeeds.  Non-ref attempts first consult the installed
         fault injector (which may raise a simulated launch failure).
-        If every impl fails, the last error propagates."""
+        If every impl fails, the last error propagates.
+
+        Each attempt traces under ``jax.named_scope(kernel)``: the
+        Pallas call and, after a demotion, the ref path's XLA ops carry
+        the kernel's name in the compiled program's op metadata, which
+        is how a device trace attributes their time."""
         chain = self.chain(kernel, impl)
         errors: list[Exception] = []
         for i, im in enumerate(chain):
             try:
                 if im != "ref" and self.injector is not None:
                     self.injector.kernel_launch(kernel, im)
-                out = attempt(im)
+                with jax.named_scope(kernel):
+                    out = attempt(im)
             except Exception as e:  # noqa: BLE001 — demote, don't die
                 errors.append(e)
                 self.record_failure(kernel, im)
@@ -175,7 +169,6 @@ class KernelGuard:
             self.record_success(kernel, im)
             if i > 0:
                 self.kernel_fallbacks += 1
-                self._bump(kernel, f"fallback_{im}")
             return out
         raise errors[-1]
 

@@ -55,6 +55,7 @@ def _call_fwd(x2, scale, eps, rows_block, interpret):
                   pl.BlockSpec((d,), lambda r: (0,))],
         out_specs=pl.BlockSpec((rows_block, d), lambda r: (r, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x2.dtype),
+        name="rmsnorm",
         interpret=interpret,
     )(x2, scale)
 
@@ -84,6 +85,7 @@ def _rmsnorm_bwd(eps, rows_block, interpret, res, g2):
                    jax.ShapeDtypeStruct((d,), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),  # ds accumulates across steps
+        name="rmsnorm_bwd",
         interpret=interpret,
     )(x2, scale, g2)
     return dx, ds.astype(scale.dtype)

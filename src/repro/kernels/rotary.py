@@ -46,6 +46,7 @@ def rotary(x: jnp.ndarray, positions: jnp.ndarray, *, theta: float = 10000.0,
                   pl.BlockSpec((rows_block, n, h), lambda i: (i, 0, 0))],
         out_specs=pl.BlockSpec((rows_block, n, h), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(xp.shape, x.dtype),
+        name="rotary",
         interpret=interpret,
     )(pp.astype(jnp.int32), xp)
     return out[:r]

@@ -103,6 +103,7 @@ def ssd_scan(
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        name="ssd_scan",
         interpret=interpret,
     )(x, logd, dt, bmat, cmat)
     return y[:, :s]

@@ -95,6 +95,7 @@ def wkv6(
         scratch_shapes=[pltpu.VMEM((kk, vv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        name="wkv6",
         interpret=interpret,
     )(r, k, v, logw, u)
     return y[:, :s]

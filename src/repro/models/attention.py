@@ -73,22 +73,24 @@ def project_qkv(params: Params, cfg: ModelConfig, x: jnp.ndarray,
     h = cfg.resolved_head_dim
     nq, nkv = cfg.num_heads, cfg.num_kv_heads
     kv_src = x if kv_input is None else kv_input
-    q = x @ params["wq"].astype(x.dtype)
-    k = kv_src @ params["wk"].astype(x.dtype)
-    v = kv_src @ params["wv"].astype(x.dtype)
-    if cfg.qkv_bias:
-        q = q + params["bq"].astype(x.dtype)
-        k = k + params["bk"].astype(x.dtype)
-        v = v + params["bv"].astype(x.dtype)
-    q = q.reshape(*q.shape[:-1], nq, h)
-    k = k.reshape(*k.shape[:-1], nkv, h)
-    v = v.reshape(*v.shape[:-1], nkv, h)
-    if cfg.qk_norm:
-        q = rmsnorm_apply(params["q_norm"], q, cfg.norm_eps)
-        k = rmsnorm_apply(params["k_norm"], k, cfg.norm_eps)
+    with jax.named_scope("qkv"):
+        q = x @ params["wq"].astype(x.dtype)
+        k = kv_src @ params["wk"].astype(x.dtype)
+        v = kv_src @ params["wv"].astype(x.dtype)
+        if cfg.qkv_bias:
+            q = q + params["bq"].astype(x.dtype)
+            k = k + params["bk"].astype(x.dtype)
+            v = v + params["bv"].astype(x.dtype)
+        q = q.reshape(*q.shape[:-1], nq, h)
+        k = k.reshape(*k.shape[:-1], nkv, h)
+        v = v.reshape(*v.shape[:-1], nkv, h)
+        if cfg.qk_norm:
+            q = rmsnorm_apply(params["q_norm"], q, cfg.norm_eps)
+            k = rmsnorm_apply(params["k_norm"], k, cfg.norm_eps)
     if positions is not None and kv_input is None:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        with jax.named_scope("rope"):
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, positions, cfg.rope_theta)
     return q, k, v
 
 
@@ -453,21 +455,26 @@ def attention_decode_paged(
     pi = jnp.clip(slot // page, 0, block_tables.shape[1] - 1)
     gp = jnp.where(active, block_tables[jnp.arange(B), pi], 0)
     off = slot % page
-    pages_k = write_kv_page_entries(pages_k, k[:, 0], gp, off)
-    pages_v = write_kv_page_entries(pages_v, v[:, 0], gp, off)
-    if _paged_kernel():
-        from repro.kernels import ops as kops
-        out = kops.paged_decode_attention(
-            q[:, 0], pages_k, pages_v, block_tables, lengths)
-    else:
-        # slice the gather to the logical capacity: the bucketed table
-        # width rounds up to pow2 pages, and trimming the tail keeps the
-        # chunked online-softmax bit-identical to the dense-cache path
-        k_cache = gather_kv_pages(pages_k, block_tables)[:, :kv_capacity]
-        v_cache = gather_kv_pages(pages_v, block_tables)[:, :kv_capacity]
-        out = decode_attention(q[:, 0], k_cache, v_cache, lengths, window=0)
-    out = out.reshape(B, 1, cfg.num_heads * cfg.resolved_head_dim)
-    return out @ params["wo"].astype(x.dtype), pages_k, pages_v
+    with jax.named_scope("kv_write"):
+        pages_k = write_kv_page_entries(pages_k, k[:, 0], gp, off)
+        pages_v = write_kv_page_entries(pages_v, v[:, 0], gp, off)
+    with jax.named_scope("paged_attention"):
+        if _paged_kernel():
+            from repro.kernels import ops as kops
+            out = kops.paged_decode_attention(
+                q[:, 0], pages_k, pages_v, block_tables, lengths)
+        else:
+            # slice the gather to the logical capacity: the bucketed
+            # table width rounds up to pow2 pages, and trimming the tail
+            # keeps the chunked online-softmax bit-identical to the
+            # dense-cache path
+            k_cache = gather_kv_pages(pages_k, block_tables)[:, :kv_capacity]
+            v_cache = gather_kv_pages(pages_v, block_tables)[:, :kv_capacity]
+            out = decode_attention(q[:, 0], k_cache, v_cache, lengths,
+                                   window=0)
+    with jax.named_scope("out_proj"):
+        out = out.reshape(B, 1, cfg.num_heads * cfg.resolved_head_dim)
+        return out @ params["wo"].astype(x.dtype), pages_k, pages_v
 
 
 def attention_prefill_chunk(

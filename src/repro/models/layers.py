@@ -130,19 +130,21 @@ def init_embedding(key, vocab: int, d_model: int, *, pad_to: int = 256,
 
 
 def embed_apply(params: Params, tokens: jnp.ndarray, dtype=jnp.bfloat16) -> jnp.ndarray:
-    return params["table"].astype(dtype)[tokens]
+    with jax.named_scope("embed"):
+        return params["table"].astype(dtype)[tokens]
 
 
 def lm_head_apply(params: Params, x: jnp.ndarray, vocab: int) -> jnp.ndarray:
     """Returns fp32 logits truncated to the logical vocab size."""
-    if "head" in params:
-        w = params["head"].astype(x.dtype)
-        logits = x @ w
-    else:
-        logits = x @ params["table"].astype(x.dtype).T
-    logits = shard_act(logits, "batch", *((None,) * (logits.ndim - 2)),
-                       "vocab")
-    return logits[..., :vocab].astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        if "head" in params:
+            w = params["head"].astype(x.dtype)
+            logits = x @ w
+        else:
+            logits = x @ params["table"].astype(x.dtype).T
+        logits = shard_act(logits, "batch", *((None,) * (logits.ndim - 2)),
+                           "vocab")
+        return logits[..., :vocab].astype(jnp.float32)
 
 
 def cross_entropy_loss(

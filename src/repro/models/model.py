@@ -145,30 +145,36 @@ def build_model(cfg: ModelConfig) -> Model:
         right-padded to a shape bucket.  The last-token logits are read
         at the real end and the SWA rolling capture arranges by the real
         length, so one trace serves every prompt in the bucket."""
-        tokens = batch["tokens"]
-        b, s = tokens.shape
-        x = embed_apply(params["embed"], tokens, dtype)
-        enc_memory = None
-        offset = 0
-        if is_encdec:
-            enc_memory = encode(params, batch["frontend"].astype(dtype))
-        elif has_frontend:
-            prefix = batch["frontend"].astype(dtype)
-            x = jnp.concatenate([prefix, x], axis=1)
-            offset = prefix.shape[1]
-        s_total = x.shape[1]
-        positions = jnp.broadcast_to(jnp.arange(s_total)[None], (b, s_total))
-        total_len = None if length is None else offset + length
-        h, cache = stack_prefill(params["decoder"], cfg, x, positions,
-                                 max_len, enc_memory=enc_memory,
-                                 cache_dtype=dtype, length=total_len)
-        if total_len is None:
-            h_last = h[:, -1:]
-        else:
-            h_last = jax.lax.dynamic_slice_in_dim(h, total_len - 1, 1, axis=1)
-        h_last = rmsnorm_apply(params["final_ln"], h_last, cfg.norm_eps)
-        logits = lm_head_apply(params["embed"], h_last[:, 0], cfg.vocab_size)
-        return logits, cache
+        with jax.named_scope("prefill"):
+            tokens = batch["tokens"]
+            b, s = tokens.shape
+            x = embed_apply(params["embed"], tokens, dtype)
+            enc_memory = None
+            offset = 0
+            if is_encdec:
+                enc_memory = encode(params, batch["frontend"].astype(dtype))
+            elif has_frontend:
+                prefix = batch["frontend"].astype(dtype)
+                x = jnp.concatenate([prefix, x], axis=1)
+                offset = prefix.shape[1]
+            s_total = x.shape[1]
+            positions = jnp.broadcast_to(jnp.arange(s_total)[None],
+                                         (b, s_total))
+            total_len = None if length is None else offset + length
+            h, cache = stack_prefill(params["decoder"], cfg, x, positions,
+                                     max_len, enc_memory=enc_memory,
+                                     cache_dtype=dtype, length=total_len)
+            if total_len is None:
+                h_last = h[:, -1:]
+            else:
+                h_last = jax.lax.dynamic_slice_in_dim(h, total_len - 1, 1,
+                                                      axis=1)
+            with jax.named_scope("final_norm"):
+                h_last = rmsnorm_apply(params["final_ln"], h_last,
+                                       cfg.norm_eps)
+            logits = lm_head_apply(params["embed"], h_last[:, 0],
+                                   cfg.vocab_size)
+            return logits, cache
 
     def decode_step(params: Params, cache: Cache, token: jnp.ndarray,
                     pos: jnp.ndarray, enc_memory: jnp.ndarray | None = None
@@ -193,12 +199,15 @@ def build_model(cfg: ModelConfig) -> Model:
         """token/pos [B]; block_tables [B,NP]; active [B] bool.  Inactive
         rows compute but write only the reserved scratch page (attention)
         or freeze their state row (recurrent)."""
-        x = embed_apply(params["embed"], token[:, None], dtype)
-        h, cache = stack_decode_paged(params["decoder"], cfg, x, cache, pos,
-                                      block_tables, active, max_len=max_len)
-        h = rmsnorm_apply(params["final_ln"], h, cfg.norm_eps)
-        logits = lm_head_apply(params["embed"], h[:, 0], cfg.vocab_size)
-        return logits, cache
+        with jax.named_scope("decode"):
+            x = embed_apply(params["embed"], token[:, None], dtype)
+            h, cache = stack_decode_paged(params["decoder"], cfg, x, cache,
+                                          pos, block_tables, active,
+                                          max_len=max_len)
+            with jax.named_scope("final_norm"):
+                h = rmsnorm_apply(params["final_ln"], h, cfg.norm_eps)
+            logits = lm_head_apply(params["embed"], h[:, 0], cfg.vocab_size)
+            return logits, cache
 
     def prefill_chunk(params: Params, cache: Cache, tokens: jnp.ndarray,
                       block_table: jnp.ndarray, ctx_len: jnp.ndarray,

@@ -130,18 +130,20 @@ def block_prefill_apply(params: Params, cfg: ModelConfig, kind: BlockKind,
     ``length`` (traced scalar): real token count when the input is
     right-padded to a shape bucket — see ``attention_prefill_apply``."""
     if kind in ("attention", "shared_attention"):
-        h = rmsnorm_apply(params["ln1"], x, cfg.norm_eps)
-        y, k_c, v_c = attention_prefill_apply(
-            params["attn"], cfg, h, positions, max_len, cache_dtype,
-            length=length)
-        x = x + y
+        with jax.named_scope("attn"):
+            h = rmsnorm_apply(params["ln1"], x, cfg.norm_eps)
+            y, k_c, v_c = attention_prefill_apply(
+                params["attn"], cfg, h, positions, max_len, cache_dtype,
+                length=length)
+            x = x + y
         if "cross" in params and enc_memory is not None:
             h = rmsnorm_apply(params["ln_cross"], x, cfg.norm_eps)
             x = x + attention_apply(params["cross"], cfg, h, positions,
                                     causal=False, kv_input=enc_memory)
-        h = rmsnorm_apply(params["ln2"], x, cfg.norm_eps)
-        y, _ = _ffn(params["ffn"], cfg, h)
-        return x + y, {"k": k_c, "v": v_c}
+        with jax.named_scope("mlp"):
+            h = rmsnorm_apply(params["ln2"], x, cfg.norm_eps)
+            y, _ = _ffn(params["ffn"], cfg, h)
+            return x + y, {"k": k_c, "v": v_c}
     if kind == "mamba2":
         h = rmsnorm_apply(params["ln1"], x, cfg.norm_eps)
         y, cache = ssm_mod.mamba2_apply(params["mamba"], cfg, h,
@@ -450,14 +452,16 @@ def block_decode_paged(params: Params, cfg: ModelConfig, kind: BlockKind,
     if kind in ("attention", "shared_attention"):
         w = cfg.sliding_window
         cap = min(max_len, w) if w > 0 else max_len
-        h = rmsnorm_apply(params["ln1"], x, cfg.norm_eps)
-        y, pk, pv = attention_decode_paged(
-            params["attn"], cfg, h, cache["k"], cache["v"], pos,
-            block_tables, active, kv_capacity=cap)
-        x = x + y
-        h = rmsnorm_apply(params["ln2"], x, cfg.norm_eps)
-        y, _ = _ffn(params["ffn"], cfg, h)
-        return x + y, {"k": pk, "v": pv}
+        with jax.named_scope("attn"):
+            h = rmsnorm_apply(params["ln1"], x, cfg.norm_eps)
+            y, pk, pv = attention_decode_paged(
+                params["attn"], cfg, h, cache["k"], cache["v"], pos,
+                block_tables, active, kv_capacity=cap)
+            x = x + y
+        with jax.named_scope("mlp"):
+            h = rmsnorm_apply(params["ln2"], x, cfg.norm_eps)
+            y, _ = _ffn(params["ffn"], cfg, h)
+            return x + y, {"k": pk, "v": pv}
     x, new_cache = block_decode_apply(params, cfg, kind, x, cache, pos)
     return x, _mask_recurrent(new_cache, cache, active)
 

@@ -38,6 +38,15 @@ Knobs: ``page_size`` (tokens per KV page), ``num_pages`` (pool size;
 default fits ``slots`` full-length requests — smaller values
 oversubscribe and exercise preemption), ``prefill_chunk`` (0 = whole
 prompts), ``bucket_prompts`` (pow2 admit bucketing).
+
+Spans: ``admit`` runs in a ``jax.profiler.TraceAnnotation``
+``engine.admit`` (rid, prompt and bucket tokens, slot) and ``step`` in a
+``StepTraceAnnotation`` ``engine.step`` (step number, active rows) whose
+children are ``engine.prepare`` (deadlines, resume, guard epoch, prefill
+chunk, page growth), ``engine.dispatch`` (table upload, rng split, the
+jitted call), ``engine.sync`` (the one host wait on the device) and
+``engine.emit`` (per-slot bookkeeping).  They land on the trace clock
+of whatever profiler is running; with none, each costs a check.
 """
 from __future__ import annotations
 
@@ -48,6 +57,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.kernels.guard import kernel_guard
@@ -128,6 +138,7 @@ class Engine:
         self._slot_emitted: list[list[int]] = [[] for _ in range(slots)]
         self._slot_seq = np.zeros((slots,), np.int64)  # admit order (preempt youngest)
         self._admit_seq = 0
+        self._step_num = 0
         self._prefilling: dict[int, dict] = {}  # slot -> {req, prompt, ctx}
         self._requeue: list[Request] = []
         # robustness state: submit() queue (bounded by max_queue),
@@ -482,34 +493,41 @@ class Engine:
         self._stamp_deadline(req)
         toks = np.asarray(req.prompt, np.int32).reshape(-1)
         s = toks.shape[0]
-        if self._chunkable and s > self.prefill_chunk:
-            need = self.pool.pages_for(min(self.prefill_chunk, s))
+        chunked = self._chunkable and s > self.prefill_chunk
+        if chunked:
+            s_b = self.prefill_chunk
+        else:
+            s_b = bucket_length(s, self.max_len) if self.bucket_prompts else s
+        with TraceAnnotation("engine.admit", rid=req.rid, prompt_tokens=s,
+                             bucket_tokens=s_b, slot=slot):
+            if chunked:
+                need = self.pool.pages_for(min(self.prefill_chunk, s))
+                if not self._pool_ensure(slot, need)[0]:
+                    return False
+                self._occupy(slot, req, pos0=s)
+                self._prefilling[slot] = {"req": req, "prompt": toks,
+                                          "ctx": 0}
+                return True
+            need = (self.pool.pages_for(self.kv_capacity)
+                    if self.cfg.sliding_window > 0
+                    else self.pool.pages_for(min(s_b, self.kv_capacity)))
             if not self._pool_ensure(slot, need)[0]:
                 return False
+            tokens = np.zeros((1, s_b), np.int32)
+            tokens[0, :s] = toks
+            if self._has_frontend:
+                from repro.models.frontends import synth_frontend_embeddings
+                frontend = synth_frontend_embeddings(
+                    jax.random.fold_in(self.rng, req.rid), self.cfg, 1)
+            else:
+                frontend = np.zeros((1,), np.float32)  # unused traced arg
+            self.cache, self._state = self._admit_fn(
+                self.params, self.cache, self._state, tokens, frontend,
+                int(s), int(slot), jnp.asarray(self.pool.tables[slot]),
+                int(req.max_new_tokens - 1), float(req.temperature))
             self._occupy(slot, req, pos0=s)
-            self._prefilling[slot] = {"req": req, "prompt": toks, "ctx": 0}
+            self._decode_active[slot] = True
             return True
-        s_b = bucket_length(s, self.max_len) if self.bucket_prompts else s
-        need = (self.pool.pages_for(self.kv_capacity)
-                if self.cfg.sliding_window > 0
-                else self.pool.pages_for(min(s_b, self.kv_capacity)))
-        if not self._pool_ensure(slot, need)[0]:
-            return False
-        tokens = np.zeros((1, s_b), np.int32)
-        tokens[0, :s] = toks
-        if self._has_frontend:
-            from repro.models.frontends import synth_frontend_embeddings
-            frontend = synth_frontend_embeddings(
-                jax.random.fold_in(self.rng, req.rid), self.cfg, 1)
-        else:
-            frontend = np.zeros((1,), np.float32)  # unused traced arg
-        self.cache, self._state = self._admit_fn(
-            self.params, self.cache, self._state, tokens, frontend,
-            int(s), int(slot), jnp.asarray(self.pool.tables[slot]),
-            int(req.max_new_tokens - 1), float(req.temperature))
-        self._occupy(slot, req, pos0=s)
-        self._decode_active[slot] = True
-        return True
 
     def _advance_prefill(self):
         """Run ONE prompt chunk for the oldest prefilling slot —
@@ -635,48 +653,58 @@ class Engine:
         """One engine step: sweep deadlines, resume paused slots,
         advance at most one prefill chunk, then one fused decode for all
         active slots.  Returns [(rid, token)]."""
-        if self._injector is not None:
-            self._injector.slow_step()
-        self._check_deadlines()
-        self._resume_paused()
-        self._check_guard_epoch()
-        if self._prefilling:
-            self._advance_prefill()
-        if not self._decode_active.any():
-            return []
-        self._grow_pages()
-        if not self._decode_active.any():
-            return []
-        if self._injector is not None:
-            poison = self._injector.poison_slots(self._decode_active)
-        else:
-            poison = np.zeros((self.slots,), bool)
-        self.rng, sub = jax.random.split(self.rng)
-        emitted, was_active, done, bad, self._state, self.cache = \
-            self._step_fn(self.params, self.cache, self._state,
-                          jnp.asarray(self.pool.tables), sub, poison)
-        # the single host sync of the step
-        em, wa, dn, bd = (np.asarray(emitted), np.asarray(was_active),
-                          np.asarray(done), np.asarray(bad))
-        out = []
-        for s in range(self.slots):
-            if not wa[s]:
-                continue
-            tok = int(em[s])
-            out.append((int(self._slot_rid[s]), tok))
-            self._slot_emitted[s].append(tok)
-            self._host_pos[s] += 1
-            if bd[s]:
-                # non-finite logits: this step's emit (computed from the
-                # previous step's finite logits) stands, the NEXT token
-                # would be garbage — abort just this request
-                if not dn[s]:
-                    self._state = self._deactivate_fn(self._state, int(s))
-                self._finish(s, "aborted", "nan_logits")
-                self.serve_counters["nan_aborts"] += 1
-            elif dn[s]:
-                self._finish(s)
-        return out
+        self._step_num += 1
+        with StepTraceAnnotation("engine.step", step_num=self._step_num,
+                                 active=int(self._decode_active.sum())):
+            with TraceAnnotation("engine.prepare"):
+                if self._injector is not None:
+                    self._injector.slow_step()
+                self._check_deadlines()
+                self._resume_paused()
+                self._check_guard_epoch()
+                if self._prefilling:
+                    self._advance_prefill()
+                if not self._decode_active.any():
+                    return []
+                self._grow_pages()
+                if not self._decode_active.any():
+                    return []
+                if self._injector is not None:
+                    poison = self._injector.poison_slots(self._decode_active)
+                else:
+                    poison = np.zeros((self.slots,), bool)
+            with TraceAnnotation("engine.dispatch"):
+                self.rng, sub = jax.random.split(self.rng)
+                emitted, was_active, done, bad, self._state, self.cache = \
+                    self._step_fn(self.params, self.cache, self._state,
+                                  jnp.asarray(self.pool.tables), sub, poison)
+            with TraceAnnotation("engine.sync"):
+                # the single host sync of the step
+                em, wa, dn, bd = (np.asarray(emitted),
+                                  np.asarray(was_active),
+                                  np.asarray(done), np.asarray(bad))
+            with TraceAnnotation("engine.emit"):
+                out = []
+                for s in range(self.slots):
+                    if not wa[s]:
+                        continue
+                    tok = int(em[s])
+                    out.append((int(self._slot_rid[s]), tok))
+                    self._slot_emitted[s].append(tok)
+                    self._host_pos[s] += 1
+                    if bd[s]:
+                        # non-finite logits: this step's emit (computed
+                        # from the previous step's finite logits) stands,
+                        # the NEXT token would be garbage — abort just
+                        # this request
+                        if not dn[s]:
+                            self._state = self._deactivate_fn(self._state,
+                                                              int(s))
+                        self._finish(s, "aborted", "nan_logits")
+                        self.serve_counters["nan_aborts"] += 1
+                    elif dn[s]:
+                        self._finish(s)
+                return out
 
     # -- submission / lifecycle --------------------------------------------
     def submit(self, req: Request) -> str:
