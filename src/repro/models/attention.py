@@ -413,8 +413,18 @@ def write_kv_page_entries(pages: jnp.ndarray, new: jnp.ndarray,
                           ) -> jnp.ndarray:
     """Scatter per-row entries into the pool: ``new`` [R, NK, H] lands at
     ``pages[page_ids[r], :, offsets[r]]``.  Rows meant to be dropped
-    should point at the reserved scratch page 0."""
-    return pages.at[page_ids, :, offsets].set(new.astype(pages.dtype))
+    should point at the reserved scratch page 0.
+
+    The scatter runs on the pool viewed as ``[P * NK * page, H]`` rows:
+    indexing dims 0 and 2 of the 4-D pool makes the TPU compiler lay the
+    pool out head-minor for the scatter and copy it whole back into the
+    layout the paged kernel reads, where a row scatter keeps the
+    kernel's layout and updates the pool in place."""
+    _, nk, page, h = pages.shape
+    rows = (page_ids[:, None] * nk + jnp.arange(nk)) * page + offsets[:, None]
+    flat = pages.reshape(-1, h).at[rows.reshape(-1)].set(
+        new.reshape(-1, h).astype(pages.dtype))
+    return flat.reshape(pages.shape)
 
 
 def _paged_kernel() -> bool:
@@ -434,10 +444,17 @@ def attention_decode_paged(
     active: jnp.ndarray,          # [B] bool — inactive rows write scratch
     *,
     kv_capacity: int,             # logical per-request cache size
+    page_offset: jnp.ndarray | int = 0,  # this layer's first pool page
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One decode step against the paged pool: project the new token,
     scatter its K/V into the owning page (inactive rows land in the
-    reserved scratch page 0), attend over the *bucketed* gathered pages.
+    layer's reserved scratch page), attend over the *bucketed* gathered
+    pages.
+
+    The pool may hold several layers' pools back to back
+    (``[L * P, ...]``): ``page_offset`` (``layer * P``) shifts every
+    table entry into this layer's rows, so the write updates one row of
+    the shared buffer in place and nothing slices a layer out of it.
 
     Single-device path (the distributed engine uses the sequence-sharded
     dense cache).  On TPU the gather never happens — the paged Pallas
@@ -453,8 +470,9 @@ def attention_decode_paged(
         lengths = pos + 1
     lengths = jnp.where(active, lengths, 0)
     pi = jnp.clip(slot // page, 0, block_tables.shape[1] - 1)
-    gp = jnp.where(active, block_tables[jnp.arange(B), pi], 0)
+    gp = jnp.where(active, block_tables[jnp.arange(B), pi], 0) + page_offset
     off = slot % page
+    block_tables = block_tables + page_offset
     with jax.named_scope("kv_write"):
         pages_k = write_kv_page_entries(pages_k, k[:, 0], gp, off)
         pages_v = write_kv_page_entries(pages_v, v[:, 0], gp, off)

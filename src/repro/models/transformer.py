@@ -446,9 +446,11 @@ def _mask_recurrent(new: Cache, old: Cache, active: jnp.ndarray) -> Cache:
 def block_decode_paged(params: Params, cfg: ModelConfig, kind: BlockKind,
                        x: jnp.ndarray, cache: Cache, pos: jnp.ndarray,
                        block_tables: jnp.ndarray, active: jnp.ndarray, *,
-                       max_len: int) -> tuple[jnp.ndarray, Cache]:
+                       max_len: int, page_offset: jnp.ndarray | int = 0
+                       ) -> tuple[jnp.ndarray, Cache]:
     """Single-token decode with paged attention KV. x [B,1,d]; pos [B];
-    block_tables [B,NP]; active [B] bool."""
+    block_tables [B,NP]; active [B] bool; ``page_offset`` locates the
+    layer's pages in a pool that holds several layers."""
     if kind in ("attention", "shared_attention"):
         w = cfg.sliding_window
         cap = min(max_len, w) if w > 0 else max_len
@@ -456,7 +458,8 @@ def block_decode_paged(params: Params, cfg: ModelConfig, kind: BlockKind,
             h = rmsnorm_apply(params["ln1"], x, cfg.norm_eps)
             y, pk, pv = attention_decode_paged(
                 params["attn"], cfg, h, cache["k"], cache["v"], pos,
-                block_tables, active, kv_capacity=cap)
+                block_tables, active, kv_capacity=cap,
+                page_offset=page_offset)
             x = x + y
         with jax.named_scope("mlp"):
             h = rmsnorm_apply(params["ln2"], x, cfg.norm_eps)
@@ -475,24 +478,53 @@ def stack_decode_paged(params: Params, cfg: ModelConfig, x: jnp.ndarray,
 
     Every layer shares one block table per request: tables index each
     layer's own pool with identical page ids, so admit/evict move O(1)
-    table rows instead of O(layers) cache slices."""
+    table rows instead of O(layers) cache slices.
+
+    The layer scan carries each stacked pool ``[n_periods, P, ...]``
+    whole, viewed as ``[n_periods * P, ...]``, and layer ``i`` reads and
+    writes it at page offset ``i * P``: the new token's K/V row is
+    scattered into the carried buffer in place, where passing the pools
+    as scan inputs and outputs would slice, copy and restack every
+    layer's pool on every step.  Recurrent state rows stay scan inputs
+    and outputs; they are small."""
     num_layers = cfg.num_layers if num_layers is None else num_layers
     pattern, n_periods, rem = _pattern_layout(cfg, num_layers)
+    paged = {str(i) for i, k in enumerate(pattern)
+             if k in ("attention", "shared_attention")}
 
-    def period_body(h, inp):
-        period_params, period_cache = inp
-        new_cache = {}
+    def period_body(carry, inp):
+        h, pools = carry
+        layer, period_params, period_state = inp
+        pools, new_state = dict(pools), {}
         for p_idx, kind in enumerate(pattern):
+            key = str(p_idx)
             bp = (params["shared_attn"] if kind == "shared_attention"
-                  else period_params.get(str(p_idx)))
-            h, new_cache[str(p_idx)] = block_decode_paged(
-                bp, cfg, kind, h, period_cache[str(p_idx)], pos,
-                block_tables, active, max_len=max_len)
-        return h, new_cache
+                  else period_params.get(key))
+            if key in paged:
+                n_pages = pools[key]["k"].shape[0] // n_periods
+                h, pools[key] = block_decode_paged(
+                    bp, cfg, kind, h, pools[key], pos, block_tables,
+                    active, max_len=max_len, page_offset=layer * n_pages)
+            else:
+                h, new_state[key] = block_decode_paged(
+                    bp, cfg, kind, h, period_state[key], pos,
+                    block_tables, active, max_len=max_len)
+        return (h, pools), new_state
 
     if n_periods > 0:
-        x, new_stack_cache = jax.lax.scan(
-            period_body, x, (params["stack"], cache["stack"]))
+        stack = cache["stack"]
+        pools = {key: jax.tree.map(
+            lambda a: a.reshape((-1,) + a.shape[2:]), stack[key])
+            for key in paged}
+        state = {key: c for key, c in stack.items() if key not in paged}
+        (x, pools), new_stack_cache = jax.lax.scan(
+            period_body, (x, pools),
+            (jnp.arange(n_periods, dtype=jnp.int32), params["stack"],
+             state))
+        for key in paged:
+            new_stack_cache[key] = jax.tree.map(
+                lambda a: a.reshape((n_periods, -1) + a.shape[1:]),
+                pools[key])
     else:
         new_stack_cache = cache["stack"]
     new_rem_cache = {}

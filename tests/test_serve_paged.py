@@ -102,6 +102,22 @@ def test_paged_decode_attention_matches_ref(b, np_, page, nq, nk, h, dtype):
                                           lengths)
     np.testing.assert_allclose(
         out.astype(np.float32), want.astype(np.float32), **_tol(dtype))
+    # the same pool as layer 1 of three, read through the flattened
+    # stack at page offset 1 * P as the decode layer scan reads it
+    k_stack = jnp.stack([_rand(3, k_pages.shape, dtype), k_pages,
+                         _rand(4, k_pages.shape, dtype)])
+    v_stack = jnp.stack([_rand(5, v_pages.shape, dtype), v_pages,
+                         _rand(6, v_pages.shape, dtype)])
+    flat_k = k_stack.reshape(-1, nk, page, h)
+    flat_v = v_stack.reshape(-1, nk, page, h)
+    for layer in range(3):
+        own = out if layer == 1 else ops.paged_decode_attention(
+            q, k_stack[layer], v_stack[layer], tables, lengths,
+            impl="interpret")
+        got = ops.paged_decode_attention(
+            q, flat_k, flat_v, tables + layer * pool_pages, lengths,
+            impl="interpret")
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(own))
 
 
 def test_paged_decode_ignores_pages_past_length():
@@ -141,6 +157,105 @@ def test_decode_attention_head_major_matches_ref(dtype):
                                     vc.transpose(0, 2, 1, 3), lengths)
     np.testing.assert_allclose(
         out.astype(np.float32), want.astype(np.float32), **_tol(dtype))
+
+
+# ------------------------------------------------- stacked pools in the scan
+def _layerwise_stack_decode(params, cfg, x, cache, pos, block_tables,
+                            active, *, max_len):
+    """The decode stack with each layer's own pool sliced into the layer
+    scan and restacked out of it: the layout the carried pools replace."""
+    from repro.models.transformer import _pattern_layout, block_decode_paged
+
+    pattern, _, rem = _pattern_layout(cfg, cfg.num_layers)
+
+    def block(bp, kind, h, c):
+        bp = params["shared_attn"] if kind == "shared_attention" else bp
+        return block_decode_paged(bp, cfg, kind, h, c, pos, block_tables,
+                                  active, max_len=max_len)
+
+    def period(h, inp):
+        pp, pc = inp
+        out = {}
+        for i, kind in enumerate(pattern):
+            h, out[str(i)] = block(pp.get(str(i)), kind, h, pc[str(i)])
+        return h, out
+
+    x, stack = jax.lax.scan(period, x, (params["stack"], cache["stack"]))
+    new_rem = {}
+    for i in range(rem):
+        x, new_rem[str(i)] = block(params["rem"].get(str(i)), pattern[i],
+                                   x, cache["rem"][str(i)])
+    return x, {"stack": stack, "rem": new_rem}
+
+
+def _written_rows(old, new):
+    """(page, offset) of every pool row the step changed."""
+    diff = np.any(np.asarray(old) != np.asarray(new), axis=(1, 3))
+    return set(zip(*map(lambda a: a.tolist(), np.nonzero(diff))))
+
+
+@pytest.mark.parametrize("arch,over", [
+    ("qwen3-1.7b", dict(num_layers=3)),
+    ("mixtral-8x7b", dict(num_layers=3, sliding_window=8, moe=None)),
+    ("zamba2-1.2b", dict(num_layers=5,
+                         block_pattern=("shared_attention", "mamba2"))),
+], ids=["attention", "swa", "hybrid-remainder"])
+def test_stacked_pool_decode_matches_layerwise(monkeypatch, arch, over):
+    """Several decode steps through ``stack_decode_paged`` with the
+    stacked pools carried through the layer scan: logits and every
+    cache leaf bit-identical to the layer-by-layer pools, and each
+    layer's rows (scratch writes of inactive slots included) written
+    only inside that layer's own pool."""
+    import repro.models.model as model_mod
+
+    cfg = tiny(arch, **over)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    slots, page, max_len = 3, 4, 16
+    n_pages = 1 + slots * (max_len // page)
+    cache = jax.tree.map(lambda a: _rand(a.size, a.shape, a.dtype),
+                         model.init_paged_cache(slots, n_pages, page))
+    perm = np.random.default_rng(1).permutation(np.arange(1, n_pages))
+    tables = jnp.asarray(perm.reshape(slots, -1).astype(np.int32))
+    tok = jnp.asarray([3, 17, 40], jnp.int32)
+    pos = jnp.asarray([5, 9, 11], jnp.int32)
+    active = jnp.asarray([True, False, True])
+    cap = min(max_len, cfg.sliding_window or max_len)
+
+    def jit_step():     # a function of its own: jit caches by function
+        def step(params, cache, tok, pos):
+            return model.decode_step_paged(params, cache, tok, pos, tables,
+                                           active, max_len=max_len)
+        return jax.jit(step)
+
+    carried, layerwise = jit_step(), jit_step()
+    got_cache = want_cache = cache
+    for _ in range(3):
+        with monkeypatch.context() as m:     # traced on the first call
+            m.setattr(model_mod, "stack_decode_paged",
+                      _layerwise_stack_decode)
+            want, want_cache = layerwise(params, want_cache, tok, pos)
+        got, new_cache = carried(params, got_cache, tok, pos)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        for g, w in zip(jax.tree.leaves(new_cache),
+                        jax.tree.leaves(want_cache)):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        slot = pos % cap if cfg.sliding_window else np.minimum(pos, cap - 1)
+        expect = {(int(tables[b, slot[b] // page]) if active[b] else 0,
+                   int(slot[b] % page)) for b in range(slots)}
+        pools = [(got_cache[part][key], new_cache[part][key])
+                 for part in ("stack", "rem")
+                 for key in got_cache[part] if "k" in got_cache[part][key]]
+        assert pools
+        for old, new in pools:
+            for name in ("k", "v"):
+                shape = (-1,) + old[name].shape[-4:]    # one pool per layer
+                for o, n in zip(old[name].reshape(shape),
+                                new[name].reshape(shape)):
+                    assert _written_rows(o, n) == expect
+        got_cache = new_cache
+        # every row moves on, so each step writes rows not yet written
+        tok, pos = jnp.argmax(got, -1).astype(jnp.int32), pos + 1
 
 
 # ------------------------------------------------------------------ engine

@@ -9,6 +9,7 @@ of these compiles live in this one file.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -197,14 +198,11 @@ def test_fused_segment_grid_rep_operand(one_chip, rows, op_rows, dtype):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_offloaded_paged_decode_step(one_chip, on_tpu_paths):
-    """The serving engine's decode step at full width and depth: paged
-    kernel plus the greedy offload plan, compiled for one chip, with
-    the LM head declined and no kernel demoted."""
+def _paged_decode_step(one_chip):
+    """The serving engine's decode step at full width and depth, with
+    the shapes of its arguments placed on one chip."""
     from repro.models import build_model
-    from repro.models.attention import _paged_kernel
 
-    assert _paged_kernel()
     model = build_model(CFG)
     slots, page, max_len = 4, 64, 128
     pages = 1 + slots * (max_len // page)
@@ -225,6 +223,17 @@ def test_offloaded_paged_decode_step(one_chip, on_tpu_paths):
         return model.decode_step_paged(params, cache, tok, pos, tables,
                                        active, max_len=max_len)
 
+    return paged_decode, args
+
+
+def test_offloaded_paged_decode_step(one_chip, on_tpu_paths):
+    """The serving engine's decode step at full width and depth: paged
+    kernel plus the greedy offload plan, compiled for one chip, with
+    the LM head declined and no kernel demoted."""
+    from repro.models.attention import _paged_kernel
+
+    assert _paged_kernel()
+    paged_decode, args = _paged_decode_step(one_chip)
     before = kernel_guard().stats()
     wrapped = mpu_offload(paged_decode, policy=OffloadPolicy(mode="greedy"))
     report = wrapped.explain(*args)
@@ -242,3 +251,28 @@ def test_offloaded_paged_decode_step(one_chip, on_tpu_paths):
     after = kernel_guard().stats()
     assert after["kernel_failures"] == before["kernel_failures"]
     assert after["kernel_fallbacks"] == before["kernel_fallbacks"]
+
+
+def test_offloaded_paged_decode_step_writes_pools_in_place(one_chip,
+                                                           on_tpu_paths):
+    """Compiled as the engine compiles it, with the cache donated, the
+    step writes each new K/V row into the stacked pools in place: no
+    copy, slice or slice update yields an array shaped like one layer's
+    pool, the stacked pools or their flat view."""
+    paged_decode, args = _paged_decode_step(one_chip)
+    wrapped = mpu_offload(paged_decode, policy=OffloadPolicy())
+    compiled = jax.jit(wrapped, donate_argnums=(1,)).lower(*args).compile()
+    stack_k = args[1]["stack"]["0"]["k"]
+    n_layers, n_pages = stack_k.shape[:2]
+    pool = stack_k.shape[2:]
+    rows = n_layers * n_pages * pool[0] * pool[1]
+    pool_shapes = {(n_pages, *pool), (n_layers, n_pages, *pool),
+                   (n_layers * n_pages, *pool), (rows, pool[2])}
+    hlo = compiled.as_text()
+    instr = re.compile(
+        r"%(\S+) = bf16\[([\d,]*)\]\S* "
+        r"(copy|dynamic-slice|dynamic-update-slice)\(")
+    copies = [(name, dims) for name, dims, _ in instr.findall(hlo)
+              if tuple(int(d) for d in dims.split(",") if d != "1")
+              in pool_shapes]
+    assert copies == [], f"whole-pool copies in the step: {copies}"
