@@ -25,11 +25,57 @@ class MoEConfig:
 
     num_experts: int
     top_k: int
-    # capacity factor for fixed-capacity dispatch (train path); decode uses
-    # dense-gather dispatch which needs no capacity.
+    # capacity factor of the fixed-capacity dispatch the training forward
+    # uses; 0 routes training dropless too.  Serving (prefill and decode)
+    # is always dropless.
     capacity_factor: float = 1.25
     router_jitter: float = 0.0
     aux_loss_weight: float = 0.01
+    # width of one routed expert's SwiGLU; 0 -> the model's d_ff
+    d_expert: int = 0
+    # shared experts, computed for every token as one SwiGLU of
+    # num_shared * d_expert
+    num_shared: int = 0
+    # router scores: "softmax" over all experts, or "sigmoid" per expert
+    # (DeepSeek-V3); with score_bias the top-k is chosen on score + a
+    # per-expert correction bias (noaux_tc) and weighted by the score;
+    # the top-k weights are normalised to sum 1, then scaled
+    scoring: str = "softmax"
+    score_bias: bool = False
+    routed_scale: float = 1.0
+    # the experts this chip holds, (first, count), of num_experts: the
+    # router routes over all of them, the layer computes its own
+    # experts' part of the result.  None holds every expert.
+    held: tuple[int, int] | None = None
+
+    def expert_width(self, d_ff: int) -> int:
+        return self.d_expert or d_ff
+
+    @property
+    def held_range(self) -> tuple[int, int]:
+        return self.held or (0, self.num_experts)
+
+
+@dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3) without a query
+    low-rank path: keys and values are decompressed from one normed
+    latent of ``kv_rank`` per token, and RoPE runs on a separate
+    ``rope_dim`` slice of the query and on one key slice shared by all
+    heads.  The decode cache holds ``kv_rank + rope_dim`` per token."""
+
+    kv_rank: int = 512
+    nope_dim: int = 128
+    rope_dim: int = 64
+    v_dim: int = 128
+
+    @property
+    def qk_dim(self) -> int:
+        return self.nope_dim + self.rope_dim
+
+    @property
+    def cache_dim(self) -> int:
+        return self.kv_rank + self.rope_dim
 
 
 @dataclass(frozen=True)
@@ -90,6 +136,10 @@ class ModelConfig:
     block_pattern: tuple[BlockKind, ...] = ("attention",)
 
     moe: MoEConfig | None = None
+    mla: MLAConfig | None = None
+    # leading layers with a dense SwiGLU of d_ff in place of the MoE
+    # (DeepSeek-V3's first_k_dense_replace)
+    first_dense: int = 0
     ssm: SSMConfig | None = None
     rwkv: RWKVConfig | None = None
 
@@ -129,15 +179,25 @@ class ModelConfig:
         if not self.tie_embeddings:
             total += self.vocab_size * d  # lm head
         def attn_params() -> int:
+            if self.mla is not None:
+                m = self.mla
+                return (d * nq * m.qk_dim + d * m.cache_dim + m.kv_rank
+                        + m.kv_rank * nq * (m.nope_dim + m.v_dim)
+                        + nq * m.v_dim * d)
             p = d * (nq * h) + 2 * d * (nkv * h) + (nq * h) * d
             if self.qkv_bias:
                 p += nq * h + 2 * nkv * h
             return p
-        def ffn_params() -> int:
+        def ffn_params(dense_layer: bool = False) -> int:
             n_mats = 3 if self.gated_mlp else 2
             dense = n_mats * d * self.d_ff
-            if self.moe is not None:
-                return self.moe.num_experts * dense + d * self.moe.num_experts
+            if self.moe is not None and not dense_layer:
+                m = self.moe
+                f = m.expert_width(self.d_ff)
+                return (m.held_range[1] * n_mats * d * f
+                        + m.num_shared * n_mats * d * f
+                        + d * m.num_experts
+                        + (m.num_experts if m.score_bias else 0))
             return dense
         def mamba_params() -> int:
             s = self.ssm or SSMConfig()
@@ -156,10 +216,10 @@ class ModelConfig:
             p += 6 * d  # token-shift mixes
             p += d * self.d_ff + self.d_ff * d  # channel mix
             return p
-        for kind in self.layer_kinds():
+        for i, kind in enumerate(self.layer_kinds()):
             total += 2 * d  # norms
             if kind in ("attention", "shared_attention"):
-                total += attn_params() + ffn_params()
+                total += attn_params() + ffn_params(i < self.first_dense)
             elif kind == "mamba2":
                 total += mamba_params() + ffn_params()
             elif kind == "rwkv6":
@@ -180,11 +240,13 @@ class ModelConfig:
             return self.param_count()
         full = self.param_count()
         d = self.d_model
-        dense = 3 * d * self.d_ff
+        dense = 3 * d * self.moe.expert_width(self.d_ff)
         n_moe_layers = sum(
-            1 for k in self.layer_kinds() if k in ("attention", "shared_attention")
+            1 for i, k in enumerate(self.layer_kinds())
+            if k in ("attention", "shared_attention") and i >= self.first_dense
         )
-        inactive = n_moe_layers * (self.moe.num_experts - self.moe.top_k) * dense
+        held = self.moe.held_range[1]
+        inactive = n_moe_layers * max(0, held - self.moe.top_k) * dense
         return full - inactive
 
 
@@ -320,9 +382,16 @@ def reduced(config: ModelConfig, **overrides) -> ModelConfig:
         small["enc_num_layers"] = 2
         small["enc_seq_len"] = 16
     if config.moe is not None:
-        small["moe"] = MoEConfig(
-            num_experts=min(4, config.moe.num_experts), top_k=min(2, config.moe.top_k)
-        )
+        m = config.moe
+        e = min(4, m.num_experts) if m.held is None else min(8, m.num_experts)
+        small["moe"] = dataclasses.replace(
+            m, num_experts=e, top_k=min(2, m.top_k),
+            d_expert=32 if m.d_expert else 0,
+            held=None if m.held is None else (0, e // 2))
+    if config.mla is not None:
+        small["mla"] = MLAConfig(kv_rank=32, nope_dim=16, rope_dim=8,
+                                 v_dim=16)
+        small["num_layers"] = config.first_dense + 2
     if config.ssm is not None:
         small["ssm"] = SSMConfig(state_dim=16, head_dim=16, expand=2, chunk_size=16)
     if config.rwkv is not None:

@@ -13,6 +13,7 @@ _ARCH_MODULES: dict[str, str] = {
     "seamless-m4t-medium": "repro.configs.seamless_m4t_medium",
     "mixtral-8x7b": "repro.configs.mixtral_8x7b",
     "moonshot-v1-16b-a3b": "repro.configs.moonshot_v1_16b_a3b",
+    "moonshot-v1-16b-a3b-ep8": "repro.configs.moonshot_v1_16b_a3b_ep8",
     "qwen2.5-32b": "repro.configs.qwen2_5_32b",
     "deepseek-7b": "repro.configs.deepseek_7b",
     "internlm2-20b": "repro.configs.internlm2_20b",

@@ -1558,6 +1558,11 @@ def plan_offload(closed: jcore.ClosedJaxpr, *,
         if any(jnp.dtype(v.aval.dtype).itemsize > 4
                for v in (lhs_v, rhs_v, out)):
             return False
+        # nor do they honour a precision the dot asks for (a float32
+        # router at the highest precision would run at the kernel's)
+        if any(p not in (None, jax.lax.Precision.DEFAULT)
+               for p in eqn.params.get("precision") or ()):
+            return False
         if out.aval.size < bulk_threshold:
             return False
         form = None

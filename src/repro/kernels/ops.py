@@ -45,6 +45,8 @@ from repro.kernels.fused_matmul_bwd import (
 )
 from repro.kernels.guard import kernel_guard
 from repro.kernels.guard import resolve_impl as _resolve
+from repro.kernels.mla_decode import paged_mla_decode as _paged_mla_pallas
+from repro.kernels.moe_experts import moe_experts as _moe_experts_pallas
 from repro.kernels.rmsnorm import rmsnorm as _rmsnorm_pallas
 from repro.kernels.rotary import rotary as _rotary_pallas
 from repro.kernels.ssd_scan import ssd_scan as _ssd_pallas
@@ -90,6 +92,33 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
                                     lengths, interpret=(im == "interpret"),
                                     **kw)
     return kernel_guard().run("paged_decode_attention", impl, attempt)
+
+
+def paged_mla_decode(q, pages, block_tables, lengths, *, rank: int,
+                     scale: float, impl: Impl = "auto", **kw):
+    """Absorbed latent attention over a paged latent pool."""
+    def attempt(im):
+        if im == "ref":
+            return _ref.ref_paged_mla_decode(q, pages, block_tables, lengths,
+                                             rank=rank, scale=scale)
+        return _paged_mla_pallas(q, pages, block_tables, lengths, rank=rank,
+                                 scale=scale, interpret=(im == "interpret"),
+                                 **kw)
+    return kernel_guard().run("paged_mla_decode", impl, attempt)
+
+
+def moe_experts(x, tile_group, n_tiles, w_gate, w_up, w_down, *, tm: int,
+                act: str = "silu", impl: Impl = "auto", **kw):
+    """Grouped SwiGLU of expert-sorted, tile-padded rows over the held
+    experts' weights."""
+    def attempt(im):
+        if im == "ref":
+            return _ref.ref_moe_experts(x, tile_group, n_tiles, w_gate,
+                                        w_up, w_down, tm=tm, act=act)
+        return _moe_experts_pallas(x, tile_group, n_tiles, w_gate, w_up,
+                                   w_down, tm=tm, act=act,
+                                   interpret=(im == "interpret"), **kw)
+    return kernel_guard().run("moe_experts", impl, attempt)
 
 
 def rmsnorm(x, scale, *, eps: float = 1e-5, impl: Impl = "auto", **kw):

@@ -81,6 +81,59 @@ def ref_paged_decode_attention(q, k_pages, v_pages, block_tables, lengths):
     return ref_decode_attention(q, k_cache, v_cache, lengths)
 
 
+def ref_mla_decode(q, rows, lengths, *, rank: int, scale: float):
+    """Absorbed latent attention: q [B,NQ,C]; rows [B,T,C] (each token's
+    ``[c ‖ k_pe]``); lengths [B].  Returns [B,NQ,rank] in rows' dtype:
+    the softmax(q · row * scale)-weighted sum of the rows' first
+    ``rank`` entries."""
+    t = rows.shape[1]
+    s = jnp.einsum("bqc,btc->bqt", q.astype(rows.dtype), rows,
+                   preferred_element_type=jnp.float32) * scale
+    ok = jnp.arange(t)[None, :] < lengths[:, None]
+    s = jnp.where(ok[:, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bqt,btr->bqr", p.astype(rows.dtype), rows[..., :rank],
+                     preferred_element_type=jnp.float32)
+    return out.astype(rows.dtype)
+
+
+def gather_rows(pages, block_tables):
+    """[P, page, C] pool + [B, NP] table -> [B, NP * page, C]."""
+    b, n_pages = block_tables.shape
+    page, c = pages.shape[1:]
+    return pages[block_tables].reshape(b, n_pages * page, c)
+
+
+def ref_paged_mla_decode(q, pages, block_tables, lengths, *, rank: int,
+                         scale: float):
+    """``ref_mla_decode`` over the pages a block table names."""
+    return ref_mla_decode(q, gather_rows(pages, block_tables), lengths,
+                          rank=rank, scale=scale)
+
+
+def ref_moe_experts(x, tile_group, n_tiles, w_gate, w_up, w_down, *,
+                    tm: int, act: str = "silu"):
+    """Each ``tm``-row tile of x [M, d] through the SwiGLU of expert
+    ``tile_group[t]`` (gate/up [E, d, f], down [E, f, d]); tiles past
+    ``n_tiles[0]`` give zeros.  Matmul operands in x's dtype, f32
+    accumulation."""
+    acts = {"silu": jax.nn.silu, "gelu": jax.nn.gelu, "relu": jax.nn.relu}
+    m = x.shape[0]
+    tile = jnp.arange(m) // tm
+    group = jnp.where(tile < n_tiles[0], tile_group[tile], -1)
+    y = jnp.zeros(x.shape, jnp.float32)
+    for e in range(w_gate.shape[0]):
+        g = jnp.dot(x, w_gate[e].astype(x.dtype),
+                    preferred_element_type=jnp.float32)
+        u = jnp.dot(x, w_up[e].astype(x.dtype),
+                    preferred_element_type=jnp.float32)
+        h = (acts[act](g) * u).astype(x.dtype)
+        ye = jnp.dot(h, w_down[e].astype(x.dtype),
+                     preferred_element_type=jnp.float32)
+        y = jnp.where((group == e)[:, None], ye, y)
+    return y.astype(x.dtype)
+
+
 def ref_ssd_scan(x, logd, dt, bmat, cmat, state0=None):
     """Sequential SSD oracle.  x [B,S,H,P]; logd,dt [B,S,H];
     bmat,cmat [B,S,N].  Returns (y [B,S,H,P], state [B,H,P,N])."""

@@ -112,7 +112,7 @@ def _block_mask(q_pos: jnp.ndarray, k_pos: jnp.ndarray, *, causal: bool,
 def blockwise_attention(
     q: jnp.ndarray,  # [B, S, NQ, H]
     k: jnp.ndarray,  # [B, T, NK, H]
-    v: jnp.ndarray,  # [B, T, NK, H]
+    v: jnp.ndarray,  # [B, T, NK, Hv]
     *,
     causal: bool = True,
     window: int = 0,
@@ -123,10 +123,13 @@ def blockwise_attention(
     """Online-softmax attention; never materializes [S, T] scores.
 
     ``q_offset``: absolute position of q[0] (for cached decode/prefill
-    continuation).  Softmax statistics are fp32.
+    continuation).  Softmax statistics are fp32.  Values may be of
+    another head size than queries and keys (latent attention); the
+    output has the values' [B, S, NQ, Hv].
     """
     B, S, NQ, H = q.shape
     T, NK = k.shape[1], k.shape[2]
+    HV = v.shape[-1]
     G = NQ // NK
     q_block = min(q_block, S)
     kv_block = min(kv_block, T)
@@ -141,7 +144,7 @@ def blockwise_attention(
     # [nqb, B, qb, NK, G, H]
     qb = qp.reshape(B, nqb, q_block, NK, G, H).transpose(1, 0, 2, 3, 4, 5)
     kb = kp.reshape(B, nkb, kv_block, NK, H).transpose(1, 0, 2, 3, 4)
-    vb = vp.reshape(B, nkb, kv_block, NK, H).transpose(1, 0, 2, 3, 4)
+    vb = vp.reshape(B, nkb, kv_block, NK, HV).transpose(1, 0, 2, 3, 4)
     scale = 1.0 / (H ** 0.5)
 
     def q_step(_, q_idx_and_block):
@@ -171,7 +174,7 @@ def blockwise_attention(
             acc_new = acc * corr[..., None] + pv
             return (acc_new, m_new, l_new), None
 
-        acc0 = jnp.zeros((B, q_block, NK, G, H), jnp.float32)
+        acc0 = jnp.zeros((B, q_block, NK, G, HV), jnp.float32)
         m0 = jnp.full((B, q_block, NK, G), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, q_block, NK, G), jnp.float32)
         (acc, m, l), _ = jax.lax.scan(
@@ -181,8 +184,8 @@ def blockwise_attention(
         return None, out.astype(q.dtype)
 
     _, ob = jax.lax.scan(q_step, None, (jnp.arange(nqb), qb))
-    # ob: [nqb, B, qb, NK, G, H] -> [B, S, NQ, H]
-    out = ob.transpose(1, 0, 2, 3, 4, 5).reshape(B, nqb * q_block, NQ, H)
+    # ob: [nqb, B, qb, NK, G, HV] -> [B, S, NQ, HV]
+    out = ob.transpose(1, 0, 2, 3, 4, 5).reshape(B, nqb * q_block, NQ, HV)
     return out[:, :S]
 
 

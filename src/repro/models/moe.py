@@ -1,7 +1,23 @@
-"""Mixture-of-experts FFN with sort-based (dropless-style) dispatch.
+"""Mixture-of-experts FFN: a dropless path for serving and a
+fixed-capacity path for training.
 
-Routing works on *grouped tokens* ``[G, N, d]`` (train/prefill: G = batch
-rows, N = seq; decode: G = 1, N = batch).  Dispatch builds per-expert
+``moe_dropless`` (prefill, decode, and training where
+``capacity_factor`` is 0) routes every token over all experts and
+computes the experts this chip holds (``MoEConfig.held``) for every
+token routed to them: the (token, expert) pairs sort by held expert,
+each expert's rows pad to whole tiles, and one grouped SwiGLU
+(``repro.kernels.ops.moe_experts``) runs over the tiles in use.  No
+token is dropped, so pad tokens never change real tokens' outputs.
+Pairs routed to experts held elsewhere add nothing here: on a chip of
+an expert-parallel deployment that is the chip's part of the layer.
+Shared experts run for every token.  The router is float32 at the
+highest matmul precision (sigmoid or softmax scores, an optional
+correction bias that picks the top-k but does not weight them,
+normalised and scaled top-k weights).
+
+``moe_apply`` is the fixed-capacity path of training (every expert
+held, softmax routing).  Routing works on *grouped tokens* ``[G, N, d]``
+(G = batch rows, N = seq).  Dispatch builds per-expert
 buffers ``[G, E, C, d]`` via argsort + gather — no [tokens, E, C] one-hot
 tensor is ever materialized, so the dispatch scales to 64-expert configs.
 
@@ -20,21 +36,38 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, MoEConfig
-from repro.models.layers import Params, activation, dense_init
+from repro.models.layers import (
+    Params,
+    activation,
+    dense_init,
+    init_mlp,
+    mlp_apply,
+    round_up,
+)
 from repro.sharding.constraints import shard_act
 
 
 def init_moe(key, cfg: ModelConfig, dtype=jnp.float32) -> Params:
-    assert cfg.moe is not None
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.moe.num_experts
+    """Router over all experts, the held experts' matrices ([E_held, d,
+    f] gate and up, [E_held, f, d] down), the correction bias and the
+    shared experts where the config has them."""
+    moe = cfg.moe
+    assert moe is not None
+    d, f, e = cfg.d_model, moe.expert_width(cfg.d_ff), moe.num_experts
+    n = moe.held_range[1]
     ks = jax.random.split(key, 4)
     scale = 1.0 / jnp.sqrt(d)
-    return {
+    p = {
         "router": dense_init(ks[0], d, e, dtype),
-        "gate": (jax.random.normal(ks[1], (e, d, f)) * scale).astype(dtype),
-        "up": (jax.random.normal(ks[2], (e, d, f)) * scale).astype(dtype),
-        "down": (jax.random.normal(ks[3], (e, f, d)) / jnp.sqrt(f)).astype(dtype),
+        "gate": (jax.random.normal(ks[1], (n, d, f)) * scale).astype(dtype),
+        "up": (jax.random.normal(ks[2], (n, d, f)) * scale).astype(dtype),
+        "down": (jax.random.normal(ks[3], (n, f, d)) / jnp.sqrt(f)).astype(dtype),
     }
+    if moe.score_bias:
+        p["router_bias"] = jnp.zeros((e,), dtype)
+    if moe.num_shared:
+        p["shared"] = init_mlp(jax.random.fold_in(key, 4), d, moe.num_shared * f, dtype=dtype)
+    return p
 
 
 def capacity(n_tokens: int, moe: MoEConfig) -> int:
@@ -133,16 +166,89 @@ def moe_apply(params: Params, cfg: ModelConfig, x: jnp.ndarray
     return y, aux.astype(jnp.float32) * moe.aux_loss_weight
 
 
-def moe_apply_tokens(params: Params, cfg: ModelConfig, x: jnp.ndarray
-                     ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Adapter for [B, S, d] (train/prefill; groups = batch rows) and
-    [B, 1, d] (decode; a single group of B tokens)."""
-    b, s, d = x.shape
-    if s == 1:
-        y, aux = moe_apply(params, cfg, x.reshape(1, b, d))
-        return y.reshape(b, 1, d), aux
-    y, aux = moe_apply(params, cfg, x)
-    return y, aux
+def select_experts(params: Params, moe: MoEConfig, x: jnp.ndarray):
+    """x [T, d] -> (weights [T, k] f32, experts [T, k]) over all
+    ``num_experts``, the router in float32."""
+    logits = jnp.dot(x.astype(jnp.float32),
+                     params["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    if moe.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+    choice = scores
+    if moe.score_bias:
+        choice = scores + params["router_bias"].astype(jnp.float32)
+    _, idx = jax.lax.top_k(choice, moe.top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return w * moe.routed_scale, idx
+
+
+def tile_rows(n_tokens: int, moe: MoEConfig) -> int:
+    """Rows of one ``moe_experts`` tile: the pow2 at or above the rows a
+    held expert gets on average, from 16 (a bf16 sublane tile) to 256."""
+    mean = -(-n_tokens * moe.top_k // moe.num_experts)
+    return min(256, max(16, 1 << max(0, mean - 1).bit_length()))
+
+
+def moe_dropless(params: Params, cfg: ModelConfig, x: jnp.ndarray,
+                 row_mask: jnp.ndarray | None = None
+                 ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """x [T, d] -> (y [T, d], routed [E_held] int32): the held experts'
+    weighted part of the layer for every token routed to them, plus the
+    shared experts; ``routed`` counts the (token, held expert) pairs of
+    the rows ``row_mask`` keeps (every row without one)."""
+    moe = cfg.moe
+    t, d = x.shape
+    k = moe.top_k
+    first, n = moe.held_range
+    with jax.named_scope("router"):
+        w, idx = select_experts(params, moe, x)
+    with jax.named_scope("dispatch"):
+        local = idx - first
+        held = (local >= 0) & (local < n)
+        key = jnp.where(held, local, n).reshape(-1)         # [T*k]
+        sizes = jnp.bincount(key, length=n + 1).astype(jnp.int32)
+        tm = tile_rows(t, moe)
+        padded = round_up(sizes[:n], tm)
+        ends = jnp.cumsum(padded)
+        starts_pad = jnp.concatenate([ends - padded,
+                                      jnp.zeros((1,), jnp.int32)])
+        starts = jnp.cumsum(sizes) - sizes
+        order = jnp.argsort(key, stable=True)
+        sorted_key = key[order]
+        rank = jnp.arange(t * k, dtype=jnp.int32) - starts[sorted_key]
+        m = round_up(t * min(k, n) + n * (tm - 1), tm)
+        dest_sorted = jnp.where(sorted_key < n,
+                                starts_pad[sorted_key] + rank, m)
+        dest = jnp.zeros((t * k,), jnp.int32).at[order].set(dest_sorted)
+        tokens = jnp.repeat(jnp.arange(t, dtype=jnp.int32), k)
+        src = jnp.full((m + 1,), t, jnp.int32).at[dest].set(tokens)[:m]
+        xs = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])[src]
+        n_tiles = (ends[-1] // tm).reshape(1)
+        tile_group = jnp.minimum(jnp.searchsorted(
+            ends // tm, jnp.arange(m // tm, dtype=jnp.int32), side="right"),
+            n - 1).astype(jnp.int32)
+    with jax.named_scope("experts"):
+        from repro.kernels import ops as kops
+        ys = kops.moe_experts(xs, tile_group, n_tiles, params["gate"],
+                              params["up"], params["down"], tm=tm,
+                              act=cfg.act)
+    with jax.named_scope("combine"):
+        # float32 products summed on the vector unit: a matmul would
+        # round the routing weights to its input precision
+        yk = ys[jnp.minimum(dest, m - 1)].reshape(t, k, d)
+        yk = jnp.where(held[..., None], yk.astype(jnp.float32), 0.0)
+        y = jnp.sum(yk * jnp.where(held, w, 0.0)[..., None],
+                    axis=1).astype(x.dtype)
+    if "shared" in params:
+        with jax.named_scope("shared"):
+            y = y + mlp_apply(params["shared"], x, cfg.act)
+    keep = held if row_mask is None else held & row_mask[:, None]
+    routed = jnp.bincount(jnp.where(keep, local, n).reshape(-1),
+                          length=n + 1)[:n].astype(jnp.int32)
+    return y, routed
 
 
 def reference_moe(params: Params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:

@@ -14,6 +14,13 @@ inside the body:
 ``shared_attention`` positions share one parameter set (tied weights, as
 in Zamba2) but keep *per-occurrence* KV caches.
 
+Leading dense layers (``cfg.first_dense``, DeepSeek-V3's
+``first_k_dense_replace``) sit unstacked under ``"lead"`` and run before
+the scan: attention blocks whose FFN is a dense SwiGLU of ``d_ff`` where
+the stacked layers have the MoE.  Attention is multi-head latent
+attention (``repro.models.mla``) where ``cfg.mla`` is set; its cache is
+one latent row per token (leaf ``"ckv"``) in place of K and V.
+
 Block kinds:
     attention         norm→attn(+cross)→norm→ffn(dense MLP or MoE)
     shared_attention  same, tied weights
@@ -46,7 +53,14 @@ from repro.models.layers import (
     mlp_apply,
     rmsnorm_apply,
 )
-from repro.models.moe import init_moe, moe_apply_tokens
+from repro.models.mla import (
+    cache_row,
+    init_mla,
+    mla_apply,
+    mla_decode,
+    mla_decode_paged,
+)
+from repro.models.moe import init_moe, moe_apply, moe_dropless
 from repro.sharding.constraints import shard_act
 
 Cache = dict[str, Any]
@@ -57,16 +71,21 @@ Cache = dict[str, Any]
 # ---------------------------------------------------------------------------
 
 def init_block(key, cfg: ModelConfig, kind: BlockKind, *,
-               cross: bool = False, dtype=jnp.float32) -> Params:
+               cross: bool = False, dtype=jnp.float32,
+               dense_ffn: bool = False) -> Params:
+    """``dense_ffn``: a dense SwiGLU of ``d_ff`` where the config has
+    MoE (a leading dense layer)."""
     ks = jax.random.split(key, 6)
     d = cfg.d_model
     if kind in ("attention", "shared_attention"):
+        attn = (init_mla(ks[0], cfg, dtype=dtype) if cfg.mla is not None
+                else init_attention(ks[0], cfg, dtype=dtype))
         p: Params = {
             "ln1": init_rmsnorm(d, dtype),
-            "attn": init_attention(ks[0], cfg, dtype=dtype),
+            "attn": attn,
             "ln2": init_rmsnorm(d, dtype),
         }
-        if cfg.moe is not None:
+        if cfg.moe is not None and not dense_ffn:
             p["ffn"] = init_moe(ks[1], cfg, dtype)
         else:
             p["ffn"] = init_mlp(ks[1], d, cfg.d_ff, gated=cfg.gated_mlp,
@@ -85,10 +104,31 @@ def init_block(key, cfg: ModelConfig, kind: BlockKind, *,
     raise ValueError(kind)
 
 
-def _ffn(params: Params, cfg: ModelConfig, x: jnp.ndarray):
-    if cfg.moe is not None:
-        return moe_apply_tokens(params, cfg, x)
-    return mlp_apply(params, x, cfg.act), jnp.zeros((), jnp.float32)
+def _ffn_scope(params: Params, cfg: ModelConfig) -> str:
+    if "router" in params:
+        return "moe"
+    return "dense_mlp" if cfg.moe is not None else "mlp"
+
+
+def _ffn(params: Params, cfg: ModelConfig, x: jnp.ndarray, *,
+         train: bool = False, row_mask: jnp.ndarray | None = None):
+    """x [B, S, d] -> (y, MoE aux loss, routed counts or None).  A MoE
+    runs dropless (``moe_dropless``; ``routed`` counts its rows that
+    ``row_mask`` [B] keeps), except in training with a capacity
+    factor."""
+    zero = jnp.zeros((), jnp.float32)
+    if "router" not in params:
+        return mlp_apply(params, x, cfg.act), zero, None
+    moe = cfg.moe
+    if train and moe.capacity_factor > 0:
+        assert moe.held is None and not moe.num_shared, \
+            "the capacity path holds every expert and no shared ones"
+        y, aux = moe_apply(params, cfg, x)
+        return y, aux, None
+    b, s, d = x.shape
+    mask = None if row_mask is None else jnp.repeat(row_mask, s)
+    y, routed = moe_dropless(params, cfg, x.reshape(b * s, d), mask)
+    return y.reshape(b, s, d), zero, routed
 
 
 def block_apply(params: Params, cfg: ModelConfig, kind: BlockKind,
@@ -99,13 +139,17 @@ def block_apply(params: Params, cfg: ModelConfig, kind: BlockKind,
     aux = jnp.zeros((), jnp.float32)
     if kind in ("attention", "shared_attention"):
         h = rmsnorm_apply(params["ln1"], x, cfg.norm_eps)
-        x = x + attention_apply(params["attn"], cfg, h, positions, causal=causal)
+        if cfg.mla is not None:
+            x = x + mla_apply(params["attn"], cfg, h, positions)[0]
+        else:
+            x = x + attention_apply(params["attn"], cfg, h, positions,
+                                    causal=causal)
         if "cross" in params and enc_memory is not None:
             h = rmsnorm_apply(params["ln_cross"], x, cfg.norm_eps)
             x = x + attention_apply(params["cross"], cfg, h, positions,
                                     causal=False, kv_input=enc_memory)
         h = rmsnorm_apply(params["ln2"], x, cfg.norm_eps)
-        y, aux = _ffn(params["ffn"], cfg, h)
+        y, aux, _ = _ffn(params["ffn"], cfg, h, train=True)
         return x + y, aux
     if kind == "mamba2":
         h = rmsnorm_apply(params["ln1"], x, cfg.norm_eps)
@@ -132,18 +176,25 @@ def block_prefill_apply(params: Params, cfg: ModelConfig, kind: BlockKind,
     if kind in ("attention", "shared_attention"):
         with jax.named_scope("attn"):
             h = rmsnorm_apply(params["ln1"], x, cfg.norm_eps)
-            y, k_c, v_c = attention_prefill_apply(
-                params["attn"], cfg, h, positions, max_len, cache_dtype,
-                length=length)
+            if cfg.mla is not None:
+                y, rows = mla_apply(params["attn"], cfg, h, positions)
+                pad = max_len - rows.shape[1]
+                cache = {"ckv": jnp.pad(rows, ((0, 0), (0, pad), (0, 0)))
+                         .astype(cache_dtype)}
+            else:
+                y, k_c, v_c = attention_prefill_apply(
+                    params["attn"], cfg, h, positions, max_len, cache_dtype,
+                    length=length)
+                cache = {"k": k_c, "v": v_c}
             x = x + y
         if "cross" in params and enc_memory is not None:
             h = rmsnorm_apply(params["ln_cross"], x, cfg.norm_eps)
             x = x + attention_apply(params["cross"], cfg, h, positions,
                                     causal=False, kv_input=enc_memory)
-        with jax.named_scope("mlp"):
+        with jax.named_scope(_ffn_scope(params["ffn"], cfg)):
             h = rmsnorm_apply(params["ln2"], x, cfg.norm_eps)
-            y, _ = _ffn(params["ffn"], cfg, h)
-            return x + y, {"k": k_c, "v": v_c}
+            y, _, _ = _ffn(params["ffn"], cfg, h)
+            return x + y, cache
     if kind == "mamba2":
         h = rmsnorm_apply(params["ln1"], x, cfg.norm_eps)
         y, cache = ssm_mod.mamba2_apply(params["mamba"], cfg, h,
@@ -169,6 +220,8 @@ def block_prefill_apply(params: Params, cfg: ModelConfig, kind: BlockKind,
 def init_block_cache(cfg: ModelConfig, kind: BlockKind, batch: int,
                      max_len: int, dtype=jnp.bfloat16) -> Cache:
     if kind in ("attention", "shared_attention"):
+        if cfg.mla is not None:
+            return {"ckv": jnp.zeros((batch, max_len, cache_row(cfg)), dtype)}
         h = cfg.resolved_head_dim
         size = min(max_len, cfg.sliding_window) if cfg.sliding_window else max_len
         return {
@@ -189,16 +242,20 @@ def block_decode_apply(params: Params, cfg: ModelConfig, kind: BlockKind,
     """Single-token decode. x [B,1,d]; pos [B]."""
     if kind in ("attention", "shared_attention"):
         h = rmsnorm_apply(params["ln1"], x, cfg.norm_eps)
-        y, k, v = attention_decode_apply(
-            params["attn"], cfg, h, cache["k"], cache["v"], pos)
+        if cfg.mla is not None:
+            y, rows = mla_decode(params["attn"], cfg, h, cache["ckv"], pos)
+            cache = {**cache, "ckv": rows}
+        else:
+            y, k, v = attention_decode_apply(
+                params["attn"], cfg, h, cache["k"], cache["v"], pos)
+            cache = {**cache, "k": k, "v": v}
         x = x + y
-        cache = {**cache, "k": k, "v": v}
         if "cross" in params and enc_memory is not None:
             h = rmsnorm_apply(params["ln_cross"], x, cfg.norm_eps)
             x = x + attention_apply(params["cross"], cfg, h, pos[:, None],
                                     causal=False, kv_input=enc_memory)
         h = rmsnorm_apply(params["ln2"], x, cfg.norm_eps)
-        y, _ = _ffn(params["ffn"], cfg, h)
+        y, _, _ = _ffn(params["ffn"], cfg, h)
         return x + y, cache
     if kind == "mamba2":
         h = rmsnorm_apply(params["ln1"], x, cfg.norm_eps)
@@ -225,10 +282,17 @@ def _pattern_layout(cfg: ModelConfig, num_layers: int):
     return pattern, num_layers // p, num_layers % p
 
 
+def _lead_keys(tree: Params) -> list[str]:
+    """The leading dense layers of a params or cache tree, in order."""
+    return sorted(tree.get("lead", {}), key=int)
+
+
 def init_stack(key, cfg: ModelConfig, *, num_layers: int | None = None,
                cross: bool = False, pattern_override=None,
                dtype=jnp.float32) -> Params:
     num_layers = cfg.num_layers if num_layers is None else num_layers
+    lead = 0 if pattern_override else cfg.first_dense
+    num_layers -= lead
     cfg_pattern, n_periods, rem = _pattern_layout(cfg, num_layers)
     pattern = pattern_override or cfg_pattern
     if pattern_override:
@@ -254,6 +318,10 @@ def init_stack(key, cfg: ModelConfig, *, num_layers: int | None = None,
         if pos < rem:
             params["rem"][str(pos)] = init_block(
                 keys[next(ki)], cfg, kind, cross=cross, dtype=dtype)
+    if lead:
+        params["lead"] = {str(i): init_block(
+            jax.random.fold_in(key, (1 << 20) + i), cfg, "attention",
+            cross=cross, dtype=dtype, dense_ffn=True) for i in range(lead)}
     return params
 
 
@@ -264,6 +332,8 @@ def stack_apply(params: Params, cfg: ModelConfig, x: jnp.ndarray,
                 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Full-sequence stack. Returns (x, total_moe_aux)."""
     num_layers = cfg.num_layers if num_layers is None else num_layers
+    lead = _lead_keys(params)
+    num_layers -= len(lead)
     pattern = pattern_override or cfg.block_pattern
     n_periods, rem = num_layers // len(pattern), num_layers % len(pattern)
 
@@ -284,6 +354,10 @@ def stack_apply(params: Params, cfg: ModelConfig, x: jnp.ndarray,
         return (h, aux), None
 
     aux0 = jnp.zeros((), jnp.float32)
+    for key in lead:
+        x, a = block(params["lead"][key], cfg, "attention", x, positions,
+                     enc_memory, causal)
+        aux0 = aux0 + a
     if n_periods > 0:
         (x, aux0), _ = jax.lax.scan(period_body, (x, aux0), params["stack"])
     for pos in range(rem):
@@ -299,8 +373,13 @@ def init_stack_cache(cfg: ModelConfig, batch: int, max_len: int,
                      *, num_layers: int | None = None,
                      dtype=jnp.bfloat16) -> Cache:
     num_layers = cfg.num_layers if num_layers is None else num_layers
-    pattern, n_periods, rem = _pattern_layout(cfg, num_layers)
+    pattern, n_periods, rem = _pattern_layout(
+        cfg, num_layers - cfg.first_dense)
     cache: Cache = {"stack": {}, "rem": {}}
+    if cfg.first_dense:
+        cache["lead"] = {str(i): init_block_cache(
+            cfg, "attention", batch, max_len, dtype)
+            for i in range(cfg.first_dense)}
     for pos, kind in enumerate(pattern):
         one = init_block_cache(cfg, kind, batch, max_len, dtype)
         if n_periods > 0:
@@ -321,7 +400,13 @@ def stack_prefill(params: Params, cfg: ModelConfig, x: jnp.ndarray,
                   ) -> tuple[jnp.ndarray, Cache]:
     """Parallel prefill through the stack, emitting the decode cache."""
     num_layers = cfg.num_layers if num_layers is None else num_layers
-    pattern, n_periods, rem = _pattern_layout(cfg, num_layers)
+    lead = _lead_keys(params)
+    pattern, n_periods, rem = _pattern_layout(cfg, num_layers - len(lead))
+    lead_cache = {}
+    for key in lead:
+        x, lead_cache[key] = block_prefill_apply(
+            params["lead"][key], cfg, "attention", x, positions, max_len,
+            enc_memory, cache_dtype, length)
 
     def period_body(h, period_params):
         caches = {}
@@ -345,7 +430,10 @@ def stack_prefill(params: Params, cfg: ModelConfig, x: jnp.ndarray,
         x, rem_cache[str(p_idx)] = block_prefill_apply(
             bp, cfg, kind, x, positions, max_len, enc_memory, cache_dtype,
             length)
-    return x, {"stack": stack_cache, "rem": rem_cache}
+    out = {"stack": stack_cache, "rem": rem_cache}
+    if lead:
+        out["lead"] = lead_cache
+    return x, out
 
 
 def stack_decode(params: Params, cfg: ModelConfig, x: jnp.ndarray,
@@ -355,7 +443,13 @@ def stack_decode(params: Params, cfg: ModelConfig, x: jnp.ndarray,
                  ) -> tuple[jnp.ndarray, Cache]:
     """Single-token decode through the whole stack."""
     num_layers = cfg.num_layers if num_layers is None else num_layers
-    pattern, n_periods, rem = _pattern_layout(cfg, num_layers)
+    lead = _lead_keys(params)
+    pattern, n_periods, rem = _pattern_layout(cfg, num_layers - len(lead))
+    new_lead_cache = {}
+    for key in lead:
+        x, new_lead_cache[key] = block_decode_apply(
+            params["lead"][key], cfg, "attention", x, cache["lead"][key],
+            pos, enc_memory=enc_memory)
 
     def period_body(h, inp):
         period_params, period_cache = inp
@@ -382,7 +476,10 @@ def stack_decode(params: Params, cfg: ModelConfig, x: jnp.ndarray,
         x, new_rem_cache[str(p_idx)] = block_decode_apply(
             bp, cfg, kind, x, cache["rem"][str(p_idx)], pos,
             enc_memory=enc_memory)
-    return x, {"stack": new_stack_cache, "rem": new_rem_cache}
+    out = {"stack": new_stack_cache, "rem": new_rem_cache}
+    if lead:
+        out["lead"] = new_lead_cache
+    return x, out
 
 
 # ---------------------------------------------------------------------------
@@ -401,8 +498,12 @@ def init_block_cache_paged(cfg: ModelConfig, kind: BlockKind, slots: int,
                            dtype=jnp.bfloat16) -> Cache:
     """Per-block cache for the paged engine: attention kinds get a global
     page pool ``[P, NK, page, H]`` shared by all slots (page 0 reserved
-    as write scratch); recurrent kinds keep per-slot state rows."""
+    as write scratch) — latent attention one pool ``[P, page, row]`` of
+    latent rows; recurrent kinds keep per-slot state rows."""
     if kind in ("attention", "shared_attention"):
+        if cfg.mla is not None:
+            return {"ckv": jnp.zeros((num_pages, page_size, cache_row(cfg)),
+                                     dtype)}
         h = cfg.resolved_head_dim
         return {
             "k": jnp.zeros((num_pages, cfg.num_kv_heads, page_size, h), dtype),
@@ -418,9 +519,19 @@ def init_block_cache_paged(cfg: ModelConfig, kind: BlockKind, slots: int,
 def init_stack_cache_paged(cfg: ModelConfig, slots: int, num_pages: int,
                            page_size: int, *, num_layers: int | None = None,
                            dtype=jnp.bfloat16) -> Cache:
+    """The paged cache; with a MoE also ``moe_routed`` [E_held] int32,
+    the (token, held expert) pairs of the decode steps' active rows,
+    summed over layers and steps."""
     num_layers = cfg.num_layers if num_layers is None else num_layers
-    pattern, n_periods, rem = _pattern_layout(cfg, num_layers)
+    pattern, n_periods, rem = _pattern_layout(
+        cfg, num_layers - cfg.first_dense)
     cache: Cache = {"stack": {}, "rem": {}}
+    if cfg.first_dense:
+        cache["lead"] = {str(i): init_block_cache_paged(
+            cfg, "attention", slots, num_pages, page_size, dtype)
+            for i in range(cfg.first_dense)}
+    if cfg.moe is not None:
+        cache["moe_routed"] = jnp.zeros((cfg.moe.held_range[1],), jnp.int32)
     for pos, kind in enumerate(pattern):
         one = init_block_cache_paged(cfg, kind, slots, num_pages, page_size,
                                      dtype)
@@ -447,26 +558,35 @@ def block_decode_paged(params: Params, cfg: ModelConfig, kind: BlockKind,
                        x: jnp.ndarray, cache: Cache, pos: jnp.ndarray,
                        block_tables: jnp.ndarray, active: jnp.ndarray, *,
                        max_len: int, page_offset: jnp.ndarray | int = 0
-                       ) -> tuple[jnp.ndarray, Cache]:
+                       ) -> tuple[jnp.ndarray, Cache, jnp.ndarray | None]:
     """Single-token decode with paged attention KV. x [B,1,d]; pos [B];
     block_tables [B,NP]; active [B] bool; ``page_offset`` locates the
-    layer's pages in a pool that holds several layers."""
+    layer's pages in a pool that holds several layers.  Returns (x, the
+    block's cache, the MoE's routed counts of the active rows or
+    None)."""
     if kind in ("attention", "shared_attention"):
         w = cfg.sliding_window
         cap = min(max_len, w) if w > 0 else max_len
         with jax.named_scope("attn"):
             h = rmsnorm_apply(params["ln1"], x, cfg.norm_eps)
-            y, pk, pv = attention_decode_paged(
-                params["attn"], cfg, h, cache["k"], cache["v"], pos,
-                block_tables, active, kv_capacity=cap,
-                page_offset=page_offset)
+            if cfg.mla is not None:
+                y, rows = mla_decode_paged(
+                    params["attn"], cfg, h, cache["ckv"], pos, block_tables,
+                    active, kv_capacity=cap, page_offset=page_offset)
+                new_cache = {"ckv": rows}
+            else:
+                y, pk, pv = attention_decode_paged(
+                    params["attn"], cfg, h, cache["k"], cache["v"], pos,
+                    block_tables, active, kv_capacity=cap,
+                    page_offset=page_offset)
+                new_cache = {"k": pk, "v": pv}
             x = x + y
-        with jax.named_scope("mlp"):
+        with jax.named_scope(_ffn_scope(params["ffn"], cfg)):
             h = rmsnorm_apply(params["ln2"], x, cfg.norm_eps)
-            y, _ = _ffn(params["ffn"], cfg, h)
-            return x + y, {"k": pk, "v": pv}
+            y, _, routed = _ffn(params["ffn"], cfg, h, row_mask=active)
+            return x + y, new_cache, routed
     x, new_cache = block_decode_apply(params, cfg, kind, x, cache, pos)
-    return x, _mask_recurrent(new_cache, cache, active)
+    return x, _mask_recurrent(new_cache, cache, active), None
 
 
 def stack_decode_paged(params: Params, cfg: ModelConfig, x: jnp.ndarray,
@@ -486,14 +606,27 @@ def stack_decode_paged(params: Params, cfg: ModelConfig, x: jnp.ndarray,
     scattered into the carried buffer in place, where passing the pools
     as scan inputs and outputs would slice, copy and restack every
     layer's pool on every step.  Recurrent state rows stay scan inputs
-    and outputs; they are small."""
+    and outputs; they are small.  Leading dense layers run first, each on
+    its own pool; a MoE's routed counts add into ``moe_routed``."""
     num_layers = cfg.num_layers if num_layers is None else num_layers
-    pattern, n_periods, rem = _pattern_layout(cfg, num_layers)
+    lead = _lead_keys(params)
+    pattern, n_periods, rem = _pattern_layout(cfg, num_layers - len(lead))
     paged = {str(i) for i, k in enumerate(pattern)
              if k in ("attention", "shared_attention")}
+    routed = cache.get("moe_routed")
+
+    def count(total, new):
+        return total if new is None else total + new
+
+    new_lead_cache = {}
+    for key in lead:
+        x, new_lead_cache[key], r = block_decode_paged(
+            params["lead"][key], cfg, "attention", x, cache["lead"][key],
+            pos, block_tables, active, max_len=max_len)
+        routed = count(routed, r)
 
     def period_body(carry, inp):
-        h, pools = carry
+        h, pools, routed = carry
         layer, period_params, period_state = inp
         pools, new_state = dict(pools), {}
         for p_idx, kind in enumerate(pattern):
@@ -501,15 +634,16 @@ def stack_decode_paged(params: Params, cfg: ModelConfig, x: jnp.ndarray,
             bp = (params["shared_attn"] if kind == "shared_attention"
                   else period_params.get(key))
             if key in paged:
-                n_pages = pools[key]["k"].shape[0] // n_periods
-                h, pools[key] = block_decode_paged(
+                n_pages = jax.tree.leaves(pools[key])[0].shape[0] // n_periods
+                h, pools[key], r = block_decode_paged(
                     bp, cfg, kind, h, pools[key], pos, block_tables,
                     active, max_len=max_len, page_offset=layer * n_pages)
             else:
-                h, new_state[key] = block_decode_paged(
+                h, new_state[key], r = block_decode_paged(
                     bp, cfg, kind, h, period_state[key], pos,
                     block_tables, active, max_len=max_len)
-        return (h, pools), new_state
+            routed = count(routed, r)
+        return (h, pools, routed), new_state
 
     if n_periods > 0:
         stack = cache["stack"]
@@ -517,8 +651,8 @@ def stack_decode_paged(params: Params, cfg: ModelConfig, x: jnp.ndarray,
             lambda a: a.reshape((-1,) + a.shape[2:]), stack[key])
             for key in paged}
         state = {key: c for key, c in stack.items() if key not in paged}
-        (x, pools), new_stack_cache = jax.lax.scan(
-            period_body, (x, pools),
+        (x, pools, routed), new_stack_cache = jax.lax.scan(
+            period_body, (x, pools, routed),
             (jnp.arange(n_periods, dtype=jnp.int32), params["stack"],
              state))
         for key in paged:
@@ -532,10 +666,16 @@ def stack_decode_paged(params: Params, cfg: ModelConfig, x: jnp.ndarray,
         kind = pattern[p_idx]
         bp = (params["shared_attn"] if kind == "shared_attention"
               else params["rem"][str(p_idx)])
-        x, new_rem_cache[str(p_idx)] = block_decode_paged(
+        x, new_rem_cache[str(p_idx)], r = block_decode_paged(
             bp, cfg, kind, x, cache["rem"][str(p_idx)], pos,
             block_tables, active, max_len=max_len)
-    return x, {"stack": new_stack_cache, "rem": new_rem_cache}
+        routed = count(routed, r)
+    out = {"stack": new_stack_cache, "rem": new_rem_cache}
+    if lead:
+        out["lead"] = new_lead_cache
+    if routed is not None:
+        out["moe_routed"] = routed
+    return x, out
 
 
 def stack_prefill_chunk(params: Params, cfg: ModelConfig, x: jnp.ndarray,
@@ -544,7 +684,9 @@ def stack_prefill_chunk(params: Params, cfg: ModelConfig, x: jnp.ndarray,
                         num_layers: int | None = None) -> tuple[jnp.ndarray, Cache]:
     """One prompt chunk through an attention-only stack, scattering K/V
     straight into the request's pages.  x [1,C,d]; block_table [NP];
-    ctx_len/n_valid scalars.  Dense attention only (asserted upstream)."""
+    ctx_len/n_valid scalars.  Dense attention only (asserted upstream),
+    no leading dense layers."""
+    assert not _lead_keys(params)
     num_layers = cfg.num_layers if num_layers is None else num_layers
     pattern, n_periods, rem = _pattern_layout(cfg, num_layers)
 
@@ -555,7 +697,7 @@ def stack_prefill_chunk(params: Params, cfg: ModelConfig, x: jnp.ndarray,
             block_table, ctx_len, n_valid)
         h = h + y
         hn = rmsnorm_apply(bp["ln2"], h, cfg.norm_eps)
-        y, _ = _ffn(bp["ffn"], cfg, hn)
+        y, _, _ = _ffn(bp["ffn"], cfg, hn)
         return h + y, {"k": pk, "v": pv}
 
     def period_body(h, inp):

@@ -156,13 +156,16 @@ class Engine:
 
         self.rng = jax.random.PRNGKey(seed)
         self._has_frontend = cfg.frontend != "none"
-        # pow2 admit bucketing is exact only when no recurrent state or
-        # MoE capacity can see the pad tokens
-        self.bucket_prompts = (bucket_prompts and attention_only_pattern(cfg)
-                               and cfg.moe is None)
+        # pow2 admit bucketing is exact only when no recurrent state can
+        # see the pad tokens; a MoE serves dropless, so pad tokens take
+        # no capacity from real ones
+        self.bucket_prompts = bucket_prompts and attention_only_pattern(cfg)
         # chunked prefill: dense causal attention scattering straight
         # into pages — no SWA rolling, no frontend prefix, no recurrent
         # state, no MoE capacity coupling across chunks
+        if prefill_chunk > 0 and cfg.mla is not None:
+            raise ValueError("chunked prefill does not support latent "
+                             "attention (prefill_chunk must be 0)")
         self.prefill_chunk = prefill_chunk
         self._chunkable = (prefill_chunk > 0 and cfg.kind == "decoder"
                            and not self._has_frontend and w == 0
@@ -359,14 +362,21 @@ class Engine:
         """Serving-side counters: jit trace counts per entry point (each
         should freeze after one warmup per shape bucket — the serving
         analogue of ``offload_stats``'s zero-retrace contract), plus
-        preemptions and live page-pool occupancy."""
-        return {
+        preemptions and live page-pool occupancy.  With a MoE,
+        ``moe_routed``: the (token, expert) pairs routed to each held
+        expert by the decode steps' active rows, summed over layers —
+        counted on the device inside the step and read here."""
+        stats = {
             **self.serve_counters,
             "pages_used": self.pool.used_pages,
             "pages_free": self.pool.free_pages,
             "page_size": self.page_size,
             "table_width": self.table_width,
         }
+        if self.cache is not None and "moe_routed" in self.cache:
+            stats["moe_routed"] = np.asarray(
+                self.cache["moe_routed"]).tolist()
+        return stats
 
     def explain_decode(self):
         """Per-segment offload DecisionReport of the paged decode step
@@ -804,7 +814,7 @@ class Engine:
 
 # batch-axis position (from the end) per cache leaf name — mirrors the
 # layouts in repro.models.transformer.init_block_cache
-_BATCH_AXIS_FROM_END = {"k": 4, "v": 4, "ssm": 4, "wkv": 4,
+_BATCH_AXIS_FROM_END = {"k": 4, "v": 4, "ssm": 4, "wkv": 4, "ckv": 3,
                         "conv": 3, "tshift": 3, "cshift": 3}
 
 
@@ -828,11 +838,24 @@ def _fit_len(x: jnp.ndarray, length: int, axis: int) -> jnp.ndarray:
 
 def _scatter_admit(cache, cache1, table_row, slot, *, page: int, n_pr: int):
     """Merge a single-request prefill cache into the paged pools:
-    attention K/V leaves scatter their first ``n_pr`` pages through the
-    slot's block-table row; recurrent leaves write the slot's state row
-    (name-resolved batch axis, as in the fixed-slot engine)."""
+    attention K/V leaves and latent rows (``ckv``) scatter their first
+    ``n_pr`` pages through the slot's block-table row; recurrent leaves
+    write the slot's state row (name-resolved batch axis, as in the
+    fixed-slot engine).  The decode counters pass through."""
+    counters = {k: cache[k] for k in ("moe_routed",) if k in cache}
+    cache = {k: v for k, v in cache.items() if k not in counters}
+
     def leaf(path, pool_leaf, one):
         name = _leaf_name(path)
+        if name == "ckv":
+            # rows [(L,) 1, T, C] -> pages [(L,) P, page, C]
+            ids = table_row[:n_pr]
+            x = _fit_len(one, n_pr * page, axis=one.ndim - 2)
+            x = x.reshape(*x.shape[:-3], n_pr, page, x.shape[-1])
+            x = x.astype(pool_leaf.dtype)
+            if pool_leaf.ndim == 4:        # stacked periods
+                return pool_leaf.at[:, ids].set(x)
+            return pool_leaf.at[ids].set(x)
         if name in ("k", "v") and pool_leaf.ndim in (4, 5) \
                 and one.ndim == pool_leaf.ndim:
             ids = table_row[:n_pr]
@@ -855,7 +878,8 @@ def _scatter_admit(cache, cache1, table_row, slot, *, page: int, n_pr: int):
         return pool_leaf.at[idx].set(
             jnp.squeeze(one, ax).astype(pool_leaf.dtype))
 
-    return jax.tree_util.tree_map_with_path(leaf, cache, cache1)
+    return {**jax.tree_util.tree_map_with_path(leaf, cache, cache1),
+            **counters}
 
 
 class FixedSlotEngine:

@@ -34,6 +34,9 @@ _RULES: dict[str, tuple] = {
     "wq": ("fsdp", "model"), "wk": ("fsdp", "model"), "wv": ("fsdp", "model"),
     "wo": ("model", "fsdp"),
     "bq": ("model",), "bk": ("model",), "bv": ("model",),
+    # latent attention: the shared latent projection stays whole, its
+    # per-head decompression splits over heads
+    "wkv_a": ("fsdp", None), "wkv_b": ("fsdp", "model"),
     # mlp
     "gate": ("fsdp", "model"), "up": ("fsdp", "model"),
     "down": ("model", "fsdp"),
@@ -108,13 +111,14 @@ def param_spec_tree(cfg: ModelConfig, params_shape: Params,
         {a: {"pod": 2, "data": 16, "model": 16}.get(a, 1) for a in mesh_axes}
     model_n = sizes.get("model", 1)
     ep_ok = (cfg.moe is not None
-             and cfg.moe.num_experts % max(model_n, 1) == 0)
+             and cfg.moe.held_range[1] % max(model_n, 1) == 0)
 
     def rule_for(path, leaf) -> P:
         names = [str(getattr(p, "key", getattr(p, "name", "")))
                  for p in path]
         name = names[-1] if names else ""
         in_moe = cfg.moe is not None and "ffn" in names and \
+            "shared" not in names and \
             name in _MOE_3D and len(leaf.shape) >= 3
         if in_moe:
             # expert weights [(stack,) E, d, f] / down [(stack,) E, f, d]:
@@ -173,6 +177,10 @@ def cache_spec_tree(cfg: ModelConfig, cache_shape: Params,
         names = [str(getattr(p, "key", getattr(p, "name", ""))) for p in path]
         name = names[-1] if names else ""
         nd = len(leaf.shape)
+        if name == "ckv":      # latent rows [B, T, C]: sequence-sharded
+            extra = nd - 3
+            return sanitize_spec(P(*((None,) * extra), fsdp, model, None),
+                                 leaf.shape, sizes)
         if name in ("k", "v"):
             extra = nd - 4  # [B, T, NK, H]
             nk = leaf.shape[-2]

@@ -171,7 +171,7 @@ def _layerwise_stack_decode(params, cfg, x, cache, pos, block_tables,
     def block(bp, kind, h, c):
         bp = params["shared_attn"] if kind == "shared_attention" else bp
         return block_decode_paged(bp, cfg, kind, h, c, pos, block_tables,
-                                  active, max_len=max_len)
+                                  active, max_len=max_len)[:2]
 
     def period(h, inp):
         pp, pc = inp

@@ -1,4 +1,5 @@
-"""Ahead-of-time compiles for a described TPU v5e, at Qwen3-1.7B widths.
+"""Ahead-of-time compiles for a described TPU v5e, at Qwen3-1.7B widths
+and Moonlight-16B-A3B's.
 
 No chip is attached: the TPU compiler compiles for a topology that is
 only described, so it refuses here what the chip would refuse (block
@@ -8,6 +9,7 @@ of these compiles live in this one file.
 """
 from __future__ import annotations
 
+import dataclasses
 import os
 import re
 
@@ -275,4 +277,87 @@ def test_offloaded_paged_decode_step_writes_pools_in_place(one_chip,
     copies = [(name, dims) for name, dims, _ in instr.findall(hlo)
               if tuple(int(d) for d in dims.split(",") if d != "1")
               in pool_shapes]
+    assert copies == [], f"whole-pool copies in the step: {copies}"
+
+
+# ------------------------------------------------ Moonlight (MLA + MoE)
+MOON = dataclasses.replace(get_config("moonshot-v1-16b-a3b-ep8"), num_layers=9)
+
+
+def test_paged_mla_decode(one_chip):
+    """Absorbed queries [B, 16, 640] against a latent pool of 640-wide
+    rows (576 padded to whole lanes), 16 pages a row, 8 per grid step."""
+    from repro.models.mla import cache_row
+
+    row = cache_row(MOON)
+    q = _spec(one_chip, (4, 16, row), BF16)
+    pool = _spec(one_chip, (1 + 4 * 16, 64, row), BF16)
+    tables = _spec(one_chip, (4, 16), jnp.int32)
+    lengths = _spec(one_chip, (4,), jnp.int32)
+    compiled = _compile(
+        lambda q, p, t, n: kops.paged_mla_decode(
+            q, p, t, n, rank=MOON.mla.kv_rank, scale=0.07, impl="pallas"),
+        q, pool, tables, lengths)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows,tm", [(512, 16), (26624, 256)])
+def test_moe_experts(one_chip, rows, tm):
+    """The grouped SwiGLU over 8 held experts' float32 matrices at
+    Moonlight's widths: a decode step's tiles and a 4096-token admit's."""
+    f = MOON.moe.d_expert
+    compiled = _compile(
+        lambda x, g, n, wg, wu, wd: kops.moe_experts(
+            x, g, n, wg, wu, wd, tm=tm, impl="pallas"),
+        _spec(one_chip, (rows, D), BF16),
+        _spec(one_chip, (rows // tm,), jnp.int32),
+        _spec(one_chip, (1,), jnp.int32), _spec(one_chip, (8, D, f)),
+        _spec(one_chip, (8, D, f)), _spec(one_chip, (8, f, D)))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_offloaded_moonlight_decode_step(one_chip, on_tpu_paths):
+    """Moonlight's decode step at full width, the benchmark's 9 layers:
+    the greedy offload plan, the ``paged_mla_decode`` and
+    ``moe_experts`` kernels, no kernel demoted, and with the cache
+    donated no copy of a latent pool."""
+    from repro.models import build_model
+
+    model = build_model(MOON)
+    slots, page, max_len = 4, 64, 128
+    pages = 1 + slots * (max_len // page)
+
+    def place(tree):
+        return jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
+                            tree)
+
+    params = place(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(
+        lambda: model.init_paged_cache(slots, pages, page)))
+    vec = _spec(one_chip, (slots,), jnp.int32)
+    args = (params, cache, vec, vec,
+            _spec(one_chip, (slots, max_len // page), jnp.int32),
+            _spec(one_chip, (slots,), jnp.bool_))
+
+    def paged_decode(params, cache, tok, pos, tables, active):
+        return model.decode_step_paged(params, cache, tok, pos, tables,
+                                       active, max_len=max_len)
+
+    before = kernel_guard().stats()
+    wrapped = mpu_offload(paged_decode, policy=OffloadPolicy())
+    assert wrapped.explain(*args).n_fused >= 1
+    hlo = jax.jit(wrapped, donate_argnums=(1,)).lower(*args).compile() \
+        .as_text()
+    for kernel in ("paged_mla_decode", "moe_experts"):
+        assert re.search(rf"%{kernel}[.\d]* = .*tpu_custom_call", hlo), kernel
+    after = kernel_guard().stats()
+    assert after["kernel_failures"] == before["kernel_failures"]
+    assert after["kernel_fallbacks"] == before["kernel_fallbacks"]
+    pool = cache["stack"]["0"]["ckv"].shape
+    pool_shapes = {pool[1:], pool, (pool[0] * pool[1], *pool[2:]),
+                   (pool[0] * pool[1] * pool[2], pool[3])}
+    instr = re.compile(r"%(\S+) = bf16\[([\d,]*)\]\S* "
+                       r"(copy|dynamic-slice|dynamic-update-slice)\(")
+    copies = [(name, dims) for name, dims, _ in instr.findall(hlo)
+              if tuple(int(d) for d in dims.split(",")) in pool_shapes]
     assert copies == [], f"whole-pool copies in the step: {copies}"
