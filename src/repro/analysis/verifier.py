@@ -843,10 +843,12 @@ def verify_plan(plan: OffloadPlan, closed=None) -> list[Finding]:
 def verify_paged_decode(block_tables, lengths, *, num_pages: int,
                         page_size: int) -> list[Finding]:
     """Bounds proof for ``paged_decode_attention``'s scalar-prefetched
-    gathers.  The K/V BlockSpec index map ``(T[b, pi], kh, 0, 0)`` runs
-    for EVERY grid step — including steps the compute mask skips — so
-    every table entry (padding included) must name a real page, and no
-    sequence may claim more KV slots than its table can address."""
+    gathers.  The K/V BlockSpec index maps (``streamed_page``) run for
+    EVERY grid step — including steps the compute mask skips — and read
+    table entries up to each row's length, so every table entry must
+    name a real page (padding included, as a table's tail is stale),
+    and no sequence may claim more KV slots than its table can
+    address."""
     findings: list[Finding] = []
     t = np.asarray(block_tables)
     lens = np.asarray(lengths)

@@ -79,13 +79,30 @@ def test_page_pool_rejects_degenerate():
 
 
 # ------------------------------------------------------- paged decode kernel
-@pytest.mark.parametrize("b,np_,page,nq,nk,h", [
-    (2, 4, 64, 8, 2, 32),
-    (3, 3, 32, 4, 4, 16),
-    (1, 8, 16, 2, 1, 64),
+def _case(b, np_, page, nq, nk, h, lens=None):
+    name = "-".join(map(str, (b, np_, page, nq, nk, h)))
+    if lens is not None:
+        name += "-len" + "_".join(map(str, lens))
+    return pytest.param(b, np_, page, nq, nk, h, lens, id=name)
+
+
+@pytest.mark.parametrize("b,np_,page,nq,nk,h,lens", [
+    _case(2, 4, 64, 8, 2, 32),
+    _case(3, 3, 32, 4, 4, 16),
+    _case(1, 8, 16, 2, 1, 64),
+    # G = 2 and G = 1 over widths 8 does not divide (3, 6 and 12 pages
+    # a step split as 3, 6 and 6): an empty row, lengths on a page
+    # boundary, a full table, a row ending inside a later grid step
+    _case(3, 6, 16, 4, 2, 32, (0, 16, 96)),
+    _case(4, 3, 16, 8, 8, 16, (0, 32, 48, 17)),
+    _case(3, 12, 16, 4, 4, 32, (48, 192, 100)),
+    _case(2, 12, 16, 4, 2, 32, (192, 0)),
+    # 16 pages at 8 a step: the second step partly and wholly past
+    _case(3, 16, 8, 4, 2, 32, (70, 128, 8)),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_decode_attention_matches_ref(b, np_, page, nq, nk, h, dtype):
+def test_paged_decode_attention_matches_ref(b, np_, page, nq, nk, h, lens,
+                                            dtype):
     pool_pages = 1 + b * np_
     q = _rand(0, (b, nq, h), dtype)
     k_pages = _rand(1, (pool_pages, nk, page, h), dtype)
@@ -95,13 +112,19 @@ def test_paged_decode_attention_matches_ref(b, np_, page, nq, nk, h, dtype):
     perm = rng.permutation(np.arange(1, pool_pages))
     tables = jnp.asarray(perm.reshape(b, np_).astype(np.int32))
     lengths = jnp.asarray(
-        rng.integers(1, np_ * page + 1, size=(b,)), jnp.int32)
+        rng.integers(1, np_ * page + 1, size=(b,)) if lens is None
+        else lens, jnp.int32)
     out = ops.paged_decode_attention(q, k_pages, v_pages, tables, lengths,
                                      impl="interpret")
     want = ref.ref_paged_decode_attention(q, k_pages, v_pages, tables,
                                           lengths)
+    # an empty row (a free slot) attends to nothing and reads zeros; the
+    # oracle's softmax over an all-masked row is no answer to compare
+    live = np.asarray(lengths) > 0
     np.testing.assert_allclose(
-        out.astype(np.float32), want.astype(np.float32), **_tol(dtype))
+        np.asarray(out, np.float32)[live],
+        np.asarray(want, np.float32)[live], **_tol(dtype))
+    np.testing.assert_array_equal(np.asarray(out, np.float32)[~live], 0)
     # the same pool as layer 1 of three, read through the flattened
     # stack at page offset 1 * P as the decode layer scan reads it
     k_stack = jnp.stack([_rand(3, k_pages.shape, dtype), k_pages,
@@ -141,6 +164,55 @@ def test_paged_decode_ignores_pages_past_length():
                                      jnp.asarray(scrambled), lengths,
                                      impl="interpret")
     np.testing.assert_array_equal(np.asarray(base), np.asarray(out))
+
+
+def test_paged_decode_streams_no_page_past_length():
+    """The K/V index maps (``streamed_page``) in grid order, as the
+    pipeline walks them: an input fetches a page only when its block
+    index changes, so every fetch of row b is one of its used pages or
+    the pool's page 0 (parked), and each used page is fetched once."""
+    from repro.kernels.decode_attention import streamed_page
+    page, per, width = 16, 4, 12
+    lengths = np.array([0, 16, 17, 100, 192, 64, 1], np.int32)
+    b = len(lengths)
+    tables = np.arange(1, 1 + b * width, dtype=np.int32).reshape(b, width)
+    held = {}
+    for bb in range(b):
+        last = max(lengths[bb] - 1, 0) // page
+        fetched = []
+        for i in range(width // per):
+            for j in range(per):
+                got = int(streamed_page(tables, lengths, bb, i, j,
+                                        page=page, per=per))
+                if i * per + j <= last:
+                    assert got == tables[bb, i * per + j]
+                elif j <= last:       # keeps its last page of the row
+                    assert got == tables[bb, i * per + j - per * (
+                        (i * per + j - last + per - 1) // per)]
+                else:                 # never used by this row: parked
+                    assert got == 0
+                if held.get(j) != got:
+                    fetched.append(got)
+                    held[j] = got
+        used = list(tables[bb, :last + 1])
+        assert sorted(p for p in fetched if p) == sorted(used)
+    # an empty row reads only its first table entry
+    assert int(streamed_page(tables, lengths, 0, 2, 0, page=page,
+                             per=per)) == tables[0, 0]
+
+
+@pytest.mark.parametrize("width,page_bytes,per", [
+    (32, 8 * 64 * 128 * 2, 8),       # Qwen3-1.7B's pages, chat
+    (128, 8 * 64 * 128 * 2, 8),      # the same, rag's wider table
+    (64, 32 * 64 * 128 * 2, 2),      # DeepSeek-7B's MHA pages
+    (12, 4096, 6), (3, 4096, 3), (6, 4096, 6),
+    (7, 4 << 20, 1),                 # not even one pair fits: 1
+])
+def test_paged_decode_pages_per_step(width, page_bytes, per):
+    from repro.kernels.decode_attention import KV_STEP_BYTES, pages_per_step
+    got = pages_per_step(width, page_bytes)
+    assert got == per and width % got == 0
+    assert got == 1 or 2 * got * page_bytes <= KV_STEP_BYTES
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

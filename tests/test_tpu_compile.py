@@ -66,15 +66,21 @@ def _compile(fn, *args):
 
 
 def test_paged_decode_attention(one_chip):
-    pages = 1 + 4 * 2
-    q = _spec(one_chip, (4, NQ, H), BF16)
-    kv = _spec(one_chip, (pages, NK, 64, H), BF16)
-    tables = _spec(one_chip, (4, 2), jnp.int32)
-    lengths = _spec(one_chip, (4,), jnp.int32)
-    compiled = _compile(
-        lambda q, k, v, t, n: kops.paged_decode_attention(
-            q, k, v, t, n, impl="pallas"), q, kv, kv, tables, lengths)
-    assert "tpu_custom_call" in compiled.as_text()
+    """A tiny table, then the benchmark's dense decode shapes, where the
+    kernel's K/V blocks are widest: Qwen3-1.7B's chat (24 rows, 16 / 8
+    heads, 32 pages at 8 a step) and DeepSeek-7B's longdoc (6 rows, 32
+    / 32 heads, 64 pages at 2 a step), so a VMEM overrun shows here."""
+    for b, nq, nk, width in [(4, NQ, NK, 2), (24, 16, 8, 32),
+                             (6, 32, 32, 64)]:
+        pages = 1 + b * width
+        q = _spec(one_chip, (b, nq, H), BF16)
+        kv = _spec(one_chip, (pages, nk, 64, H), BF16)
+        tables = _spec(one_chip, (b, width), jnp.int32)
+        lengths = _spec(one_chip, (b,), jnp.int32)
+        compiled = _compile(
+            lambda q, k, v, t, n: kops.paged_decode_attention(
+                q, k, v, t, n, impl="pallas"), q, kv, kv, tables, lengths)
+        assert "tpu_custom_call" in compiled.as_text()
 
 
 def _mlp(x, ln, w_gate, w_up, w_down):
