@@ -1,23 +1,14 @@
-"""Benchmark harness aggregator — one function per paper table/figure.
+"""The simulator's paper tables and figures, one function each.
 
-Prints ``name,us_per_call,derived`` CSV rows per the harness contract:
-  * simulator figures: us_per_call = simulated MPU end-to-end time per
-    workload; derived = the figure's headline ratio vs the paper value.
-  * offload chains: us_per_call = projected v5e time for the fused chain;
-    derived = HBM-traffic reduction.
-  * roofline cells (if experiments/roofline exists): us_per_call = the
-    dominant roofline term; derived = roofline fraction.
+Prints ``name,us_per_call,derived`` CSV rows: us_per_call = simulated
+MPU end-to-end time per workload; derived = the figure's headline ratio
+vs the paper value.
 
 Run: PYTHONPATH=src python -m benchmarks.run
 """
 from __future__ import annotations
 
-import json
-import pathlib
-
-from benchmarks import figs, offload_bench, table3_area
-
-ROOT = pathlib.Path(__file__).resolve().parents[1]
+from benchmarks import figs, table3_area
 
 
 def emit(name: str, us: float, derived: str):
@@ -25,8 +16,6 @@ def emit(name: str, us: float, derived: str):
 
 
 def main() -> None:
-    from repro.launch.compile_cache import setup_compile_cache
-    setup_compile_cache()
     print("name,us_per_call,derived")
 
     rows, s = figs.fig8_9_speedup_energy()
@@ -70,33 +59,6 @@ def main() -> None:
     emit("table3/total", 0.0,
          f"overhead_pct={s['total_overhead_pct']:.2f};"
          f"paper={s['paper_overhead_pct']}")
-
-    rows, s = offload_bench.run()
-    for r in rows:
-        emit(f"offload/{r['chain']}", r["fused_us_v5e"],
-             f"traffic_reduction={r['traffic_reduction']:.2f}")
-    emit("offload/MEAN", 0.0,
-         f"traffic_reduction={s['mean_traffic_reduction']:.2f}")
-
-    dr_dir = ROOT / "experiments" / "dryrun"
-    if dr_dir.exists():
-        ok = fail = 0
-        for f in sorted(dr_dir.glob("*.json")):
-            d = json.loads(f.read_text())
-            ok += 1 if d.get("ok") else 0
-            fail += 0 if d.get("ok") else 1
-        emit("dryrun/cells", 0.0, f"compiled={ok};failed={fail}")
-
-    rl_dir = ROOT / "experiments" / "roofline"
-    if rl_dir.exists():
-        for f in sorted(rl_dir.glob("*.json")):
-            d = json.loads(f.read_text())
-            dom_s = {"compute": d["compute_s"], "memory": d["memory_s"],
-                     "collective": d["collective_s"]}[d["dominant"]]
-            emit(f"roofline/{d['arch']}/{d['shape']}", dom_s * 1e6,
-                 f"dominant={d['dominant']};"
-                 f"fraction={d['roofline_fraction']:.3f};"
-                 f"useful={d['useful_flops_ratio']:.2f}")
 
 
 if __name__ == "__main__":

@@ -4,7 +4,8 @@
 proves — without executing anything — alias safety, index-map
 coverage/bounds, VMEM legality, and segment well-formedness.  Findings
 are typed; ``python -m repro.analysis.lint`` sweeps every configs model
-and MUST_FUSE bench chain.  See docs/analysis.md for the rule catalog.
+and MUST_FUSE chain (``repro.analysis.chains``).  See docs/analysis.md
+for the rule catalog.
 """
 from repro.analysis.verifier import (
     SEVERITIES,

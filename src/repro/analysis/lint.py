@@ -8,9 +8,8 @@ Two target families:
     small abstract batch: planning and verification never allocate real
     parameters — ``jax.eval_shape`` + ``ShapeDtypeStruct`` inputs all
     the way down).
-  * ``--chains`` — every MUST_FUSE chain from ``benchmarks/
-    offload_bench.py`` (located by walking up from cwd), the committed
-    fusion contract.
+  * ``--chains`` — every MUST_FUSE chain of ``repro.analysis.chains``,
+    the committed fusion contract.
 
 Exit status is non-zero iff any finding of severity >= error survives.
 See docs/analysis.md for the rule catalog.
@@ -19,7 +18,6 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-import os
 import sys
 from typing import Any, Callable, Iterable
 
@@ -64,36 +62,6 @@ def config_targets(archs: Iterable[str] | None = None,
 
         yield f"{arch}:fwd", fwd, (params, batch)
         yield f"{arch}:grad", jax.grad(fwd), (params, batch)
-
-
-def _find_bench(start: str | None = None) -> str | None:
-    d = os.path.abspath(start or os.getcwd())
-    while True:
-        cand = os.path.join(d, "benchmarks", "offload_bench.py")
-        if os.path.exists(cand):
-            return cand
-        parent = os.path.dirname(d)
-        if parent == d:
-            return None
-        d = parent
-
-
-def chain_targets() -> Iterable[tuple[str, Callable, tuple, tuple]]:
-    """Yield (name, fn, args, donate) for every MUST_FUSE bench chain."""
-    import importlib.util
-
-    path = _find_bench()
-    if path is None:
-        raise FileNotFoundError(
-            "benchmarks/offload_bench.py not found above cwd; run from "
-            "the repository (or pass --no-chains)")
-    spec = importlib.util.spec_from_file_location("_offload_bench", path)
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    must = set(bench.MUST_FUSE)
-    for name, fn, args, donate in bench._cases():
-        if name in must:
-            yield name, fn, tuple(args), tuple(donate)
 
 
 def verify_target(fn: Callable, args: tuple,
@@ -146,7 +114,7 @@ def main(argv=None) -> int:
                     help="lint specific arch id(s) (implies config "
                          "sweep for just those)")
     ap.add_argument("--chains", action="store_true",
-                    help="sweep every MUST_FUSE offload-bench chain")
+                    help="sweep every MUST_FUSE chain")
     ap.add_argument("-v", "--verbose", action="store_true",
                     help="print info-severity findings too")
     args = ap.parse_args(argv)
@@ -159,7 +127,8 @@ def main(argv=None) -> int:
             yield from ((n, f, a) for n, f, a in
                         config_targets(args.arch or None))
         if args.chains:
-            yield from chain_targets()
+            from repro.analysis.chains import chains
+            yield from chains()
 
     return run(targets(), verbose=args.verbose)
 

@@ -18,7 +18,6 @@ from repro.core.offload import (
     bwd_plans,
     clear_bwd_plans,
     mpu_offload,
-    mpu_offload_interpreted,
     offload_explain,
     offload_report,
     plan_offload,
@@ -46,7 +45,7 @@ __all__ = [
     "annotate_jaxpr", "MatmulAnchor", "OffloadPlan", "OffloadStats",
     "Segment",
     "bwd_plan_stats", "bwd_plans", "clear_bwd_plans",
-    "mpu_offload", "mpu_offload_interpreted", "offload_explain",
+    "mpu_offload", "offload_explain",
     "offload_report", "plan_offload", "rewrite_offload",
     "DEFAULT_POLICY", "OFFLOAD_MODES", "PLANNER_MODES", "SIMULATOR_MODES",
     "DecisionReport", "OffloadPolicy", "SegmentDecision",

@@ -114,10 +114,6 @@ compile-time artefact in which each near segment appears as a single
 ``offload_report`` returns the plan with the paper's TSV-style traffic
 accounting: naive per-eqn HBM bytes vs post-fusion bytes, plus the bytes
 whose round-trip is eliminated by segment-boundary donation.
-
-The legacy per-call interpreter is kept as ``execute_offloaded`` /
-``mpu_offload_interpreted`` solely as the benchmark baseline; it is not
-used on any production path.
 """
 from __future__ import annotations
 
@@ -2210,17 +2206,6 @@ def _segment_vjp(eqns: Sequence, seg: Segment, *,
     return call
 
 
-def _segment_call(eqns: Sequence, seg: Segment, read, *, impl: str,
-                  donate: bool = True):
-    """Dispatch one planned segment to its fused kernel (the legacy
-    interpreter's non-differentiable entry point; the compile-time
-    runner goes through ``_segment_vjp``).  Returns one
-    [rows, out_cols[j]] array per segment output."""
-    vals = [read(v) for v in _segment_arg_vars(seg)]
-    aliases = tuple(seg.donations) if donate else ()
-    return _segment_dispatch(eqns, seg, vals, impl=impl, donate=aliases)
-
-
 # ---------------------------------------------------------------------------
 # Plan serialization: the persistent plan cache's payload format.
 #
@@ -3093,125 +3078,3 @@ def offload_explain(fn: Callable, *args,
     ``mpu_offload(fn, policy=...).explain(*args)`` would return."""
     return offload_report(fn, *args, policy=policy,
                           donate_argnums=donate_argnums).report()
-
-
-# ---------------------------------------------------------------------------
-# Legacy per-call interpreter — benchmark baseline ONLY.
-#
-# This is what the compiled path replaced: every call re-traces fn,
-# re-plans the jaxpr, and walks it eqn-by-eqn in Python (recursing into
-# scan/jit bodies per call).  benchmarks/offload_bench.py times it
-# against mpu_offload to quantify the win; nothing else should use it.
-# Donation is deliberately NOT applied here (pure baseline semantics).
-# ---------------------------------------------------------------------------
-
-def execute_offloaded(closed: jcore.ClosedJaxpr, plan: OffloadPlan,
-                      consts: Sequence, args: Sequence, *,
-                      policy: OffloadPolicy | None = None,
-                      impl: str | None = None,
-                      bulk_threshold: int | None = None,
-                      min_segment: int | None = None):
-    """Interpret the (flattened) jaxpr, dispatching near segments to
-    fused kernels.  ``policy`` parameterizes the per-call planning of
-    nested scan/call bodies (matching the top-level plan)."""
-    policy = resolve_policy(policy, impl=impl,
-                            bulk_threshold=bulk_threshold,
-                            min_segment=min_segment)
-    impl = policy.impl
-    jaxpr = closed.jaxpr
-    eqns = jaxpr.eqns
-    seg_by_start = {s.span_start: s for s in plan.segments}
-    env: dict[Any, Any] = {}
-
-    def read(v):
-        return v.val if isinstance(v, jcore.Literal) else env[v]
-
-    def bind_eqn(eqn):
-        outs = _bind_eqn(eqn, [read(v) for v in eqn.invars])
-        for var, val in zip(eqn.outvars, outs):
-            env[var] = val
-
-    for var, val in zip(jaxpr.constvars, consts):
-        env[var] = val
-    for var, val in zip(jaxpr.invars, args):
-        env[var] = val
-
-    i = 0
-    while i < len(eqns):
-        if i in seg_by_start:
-            seg = seg_by_start[i]
-            for j in seg.pre_eqns:
-                bind_eqn(eqns[j])
-            outs = _segment_call(eqns, seg, read, impl=impl, donate=False)
-            for var, val in zip(seg.outputs, outs):
-                env[var] = val.reshape(tuple(var.aval.shape))
-            i = seg.span_end + 1
-            continue
-        eqn = eqns[i]
-        name = eqn.primitive.name
-        if name == "scan":
-            outs = _interpreted_scan(eqn, [read(v) for v in eqn.invars],
-                                     policy=policy)
-            for var, val in zip(eqn.outvars, outs):
-                env[var] = val
-        elif name in _CALL_BODY_PARAM:
-            inner = _flatten_calls(eqn.params[_CALL_BODY_PARAM[name]])
-            inner_plan = plan_offload(inner, policy=policy)
-            outs = execute_offloaded(inner, inner_plan, inner.consts,
-                                     [read(v) for v in eqn.invars],
-                                     policy=policy)
-            for var, val in zip(eqn.outvars, outs):
-                env[var] = val
-        else:
-            bind_eqn(eqn)
-        i += 1
-    return tuple(read(v) for v in jaxpr.outvars)
-
-
-def _interpreted_scan(eqn, invals: Sequence, *, policy: OffloadPolicy):
-    """Per-call scan handling of the legacy interpreter: re-plans the body
-    on every outer call (the cost the rewriter eliminates)."""
-    params = eqn.params
-    inner = _flatten_calls(params["jaxpr"])
-    n_consts = params["num_consts"]
-    n_carry = params["num_carry"]
-    consts = list(invals[:n_consts])
-    carry0 = tuple(invals[n_consts:n_consts + n_carry])
-    xs = tuple(invals[n_consts + n_carry:])
-    inner_plan = plan_offload(inner, policy=policy)
-
-    def body(carry, x):
-        vals = [*consts, *carry, *x]
-        outs = execute_offloaded(inner, inner_plan, inner.consts, vals,
-                                 policy=policy)
-        return tuple(outs[:n_carry]), tuple(outs[n_carry:])
-
-    carry, ys = jax.lax.scan(
-        body, carry0, xs, length=params["length"],
-        reverse=params.get("reverse", False),
-        unroll=params.get("unroll", 1))
-    return (*carry, *ys)
-
-
-def mpu_offload_interpreted(fn: Callable, *,
-                            policy: OffloadPolicy | None = None,
-                            bulk_threshold: int | None = None,
-                            min_segment: int | None = None,
-                            impl: str | None = None) -> Callable:
-    """The pre-rewriter behaviour (trace + plan + interpret on EVERY
-    call).  Benchmark baseline for ``benchmarks/offload_bench.py``."""
-    base = policy
-    overrides = dict(bulk_threshold=bulk_threshold,
-                     min_segment=min_segment, impl=impl)
-
-    def wrapped(*args):
-        pol = resolve_policy(base, **overrides)
-        closed = _flatten_calls(jax.make_jaxpr(fn)(*args))
-        plan = plan_offload(closed, policy=pol)
-        flat_args = jax.tree.leaves(args)  # invars are flattened leaves
-        flat = execute_offloaded(closed, plan, closed.consts, flat_args,
-                                 policy=pol)
-        out_tree = jax.tree.structure(jax.eval_shape(fn, *args))
-        return jax.tree.unflatten(out_tree, flat)
-
-    return wrapped

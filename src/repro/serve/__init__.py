@@ -1,4 +1,4 @@
-from repro.serve.engine import Completion, Engine, FixedSlotEngine, Request
+from repro.serve.engine import Completion, Engine, Request
 from repro.serve.faults import (
     FaultConfig,
     FaultInjected,
@@ -8,5 +8,5 @@ from repro.serve.faults import (
 from repro.serve.kv_pool import PagePool, bucket_length, ceil_pow2
 
 __all__ = ["Completion", "Engine", "FaultConfig", "FaultInjected",
-           "FaultInjector", "FixedSlotEngine", "PagePool", "Request",
+           "FaultInjector", "PagePool", "Request",
            "bucket_length", "ceil_pow2", "inject"]

@@ -21,8 +21,7 @@ Design contract — **zero re-traces at steady state**:
   counters freeze after warmup;
 * slot bookkeeping (pos/token/budget/temperature/active) lives on
   device and is updated inside the jitted step — one host sync per
-  decode step, instead of the per-slot Python loop the fixed-slot
-  engine used.
+  decode step.
 
 Long prompts on dense attention-only models can prefill in fixed-size
 chunks interleaved with decode (``prefill_chunk=N``): one chunk per
@@ -30,9 +29,6 @@ engine step scatters straight into the request's pages, bounding
 per-step latency.  On page exhaustion the engine preempts the youngest
 request by recompute (its prompt + emitted tokens re-queue), which is
 exact for greedy decoding.
-
-``FixedSlotEngine`` preserves the previous dense slots*max_len engine
-as the benchmark baseline (``benchmarks/serve_bench.py``).
 
 Knobs: ``page_size`` (tokens per KV page), ``num_pages`` (pool size;
 default fits ``slots`` full-length requests — smaller values
@@ -840,8 +836,8 @@ def _scatter_admit(cache, cache1, table_row, slot, *, page: int, n_pr: int):
     """Merge a single-request prefill cache into the paged pools:
     attention K/V leaves and latent rows (``ckv``) scatter their first
     ``n_pr`` pages through the slot's block-table row; recurrent leaves
-    write the slot's state row (name-resolved batch axis, as in the
-    fixed-slot engine).  The decode counters pass through."""
+    write the slot's state row (name-resolved batch axis).  The decode
+    counters pass through."""
     counters = {k: cache[k] for k in ("moe_routed",) if k in cache}
     cache = {k: v for k, v in cache.items() if k not in counters}
 
@@ -880,146 +876,3 @@ def _scatter_admit(cache, cache1, table_row, slot, *, page: int, n_pr: int):
 
     return {**jax.tree_util.tree_map_with_path(leaf, cache, cache1),
             **counters}
-
-
-class FixedSlotEngine:
-    """The previous engine: a dense ``[slots, max_len]`` KV cache with
-    per-slot host bookkeeping.  Kept as the serving benchmark baseline —
-    ``benchmarks/serve_bench.py`` measures the paged engine against it
-    at equal KV-cache memory."""
-
-    def __init__(self, cfg: ModelConfig, params: Any, *, slots: int = 8,
-                 max_len: int = 512, seed: int = 0, offload: bool = False,
-                 offload_policy: "OffloadPolicy | None" = None,
-                 offload_bulk_threshold: int | None = None,
-                 offload_max_plans: int | None = None):
-        self.cfg = cfg
-        self.model = build_model(cfg)
-        self.params = params
-        self.slots = slots
-        self.max_len = max_len
-        self.cache = self.model.init_cache(slots, max_len)
-        self.pos = np.zeros((slots,), np.int32)
-        self.active = np.zeros((slots,), bool)
-        self.budget = np.zeros((slots,), np.int32)
-        self.rid = np.full((slots,), -1, np.int32)
-        self.last_token = np.zeros((slots,), np.int32)
-        self.rng = jax.random.PRNGKey(seed)
-        self.temps = np.zeros((slots,), np.float32)
-
-        offload = offload or offload_policy is not None
-        if offload_bulk_threshold is not None or \
-                offload_max_plans is not None:
-            from repro.core.policy import fold_legacy_kwargs
-            offload_policy = fold_legacy_kwargs(
-                offload_policy, where="Engine", target="offload_policy",
-                bulk_threshold=offload_bulk_threshold,
-                max_plans=offload_max_plans)
-        decode_fn = self.model.decode_step
-        if offload:
-            from repro.core.offload import mpu_offload
-            decode_fn = mpu_offload(decode_fn, policy=offload_policy)
-        self.offload = offload
-        self.offload_policy = offload_policy
-        self._decode_offload = decode_fn if offload else None
-        self._decode = jax.jit(decode_fn, donate_argnums=(1,))
-        self._prefill1 = jax.jit(
-            lambda p, batch: self.model.prefill(p, batch, max_len))
-
-    @property
-    def offload_stats(self) -> dict | None:
-        if self._decode_offload is None:
-            return None
-        return self._decode_offload.stats.as_dict()
-
-    def explain_decode(self):
-        if self._decode_offload is None:
-            return None
-        return self._decode_offload.explain(
-            self.params, self.cache,
-            jnp.asarray(self.last_token), jnp.asarray(self.pos))
-
-    # -- slot management ----------------------------------------------------
-    def _free_slot(self) -> int | None:
-        idx = np.where(~self.active)[0]
-        return int(idx[0]) if idx.size else None
-
-    def admit(self, req: Request) -> bool:
-        """Prefill a request into a free slot. Returns False if full."""
-        slot = self._free_slot()
-        if slot is None:
-            return False
-        toks = np.asarray(req.prompt, np.int32)[None]  # [1, S]
-        batch = {"tokens": toks}
-        if self.cfg.frontend != "none":
-            from repro.models.frontends import synth_frontend_embeddings
-            batch["frontend"] = synth_frontend_embeddings(
-                jax.random.fold_in(self.rng, req.rid), self.cfg, 1)
-        logits, cache1 = self._prefill1(self.params, batch)
-        # merge slot-cache: write cache1 rows into pool slot
-        self.cache = jax.tree_util.tree_map_with_path(
-            lambda path, pool, one: _merge_slot(path, pool, one, slot),
-            self.cache, cache1)
-        next_tok = int(jnp.argmax(logits[0]))
-        self.pos[slot] = toks.shape[1]
-        self.active[slot] = True
-        self.budget[slot] = req.max_new_tokens - 1
-        self.rid[slot] = req.rid
-        self.last_token[slot] = next_tok
-        self.temps[slot] = req.temperature
-        return True
-
-    # -- decode -------------------------------------------------------------
-    def step(self) -> list[tuple[int, int]]:
-        """One decode step for all active slots.
-        Returns [(rid, token)] emitted this step."""
-        if not self.active.any():
-            return []
-        logits, self.cache = self._decode(
-            self.params, self.cache,
-            jnp.asarray(self.last_token), jnp.asarray(self.pos))
-        self.rng, sub = jax.random.split(self.rng)
-        greedy = jnp.argmax(logits, -1)
-        temps = jnp.asarray(self.temps)[:, None]
-        sampled = jax.random.categorical(
-            sub, logits / jnp.maximum(temps, 1e-3))
-        nxt = np.asarray(jnp.where(jnp.asarray(self.temps) > 0,
-                                   sampled, greedy), np.int32)
-        out = []
-        for s in range(self.slots):
-            if not self.active[s]:
-                continue
-            out.append((int(self.rid[s]), int(self.last_token[s])))
-            self.pos[s] += 1
-            self.last_token[s] = nxt[s]
-            self.budget[s] -= 1
-            if self.budget[s] < 0 or self.pos[s] >= self.max_len - 1:
-                self.active[s] = False
-        return out
-
-    def generate(self, requests: list[Request]) -> dict[int, Completion]:
-        """Run a request list to completion with continuous batching."""
-        pending = list(requests)
-        done: dict[int, Completion] = {
-            r.rid: Completion(r.rid) for r in requests}
-        while pending or self.active.any():
-            while pending and self.admit(pending[0]):
-                pending.pop(0)
-            for rid, tok in self.step():
-                done[rid].tokens.append(tok)
-        return done
-
-
-def _merge_slot(path, pool: jnp.ndarray, one: jnp.ndarray, slot: int):
-    """Write a single-request cache leaf into the pool at ``slot``.
-    The batch axis is resolved by leaf name (robust to slots == 1 and to
-    stacked-layer leading dims)."""
-    name = _leaf_name(path)
-    from_end = _BATCH_AXIS_FROM_END.get(name)
-    if from_end is None or one.ndim != pool.ndim:
-        raise ValueError(
-            f"cannot merge cache leaf {name!r} {one.shape} -> {pool.shape}")
-    ax = pool.ndim - from_end
-    idx = [slice(None)] * pool.ndim
-    idx[ax] = slice(slot, slot + 1)
-    return pool.at[tuple(idx)].set(one.astype(pool.dtype))

@@ -26,7 +26,6 @@ import pytest
 
 from repro.core import (
     mpu_offload,
-    mpu_offload_interpreted,
     offload_report,
     rewrite_offload,
 )
@@ -172,14 +171,12 @@ def test_fused_segment_multi_output():
                                    rtol=1e-5, atol=1e-5)
 
 
-def test_compiled_matches_interpreted_baseline():
+def test_compiled_matches_plain_function():
     x, y = _rand((64, 32)), _rand((64, 32), 1)
     compiled = mpu_offload(_chain, bulk_threshold=64, impl="interpret")
-    interpreted = mpu_offload_interpreted(_chain, bulk_threshold=64,
-                                          impl="interpret")
     np.testing.assert_allclose(np.asarray(compiled(x, y)),
-                               np.asarray(interpreted(x, y)),
-                               rtol=1e-6, atol=1e-6)
+                               np.asarray(_chain(x, y)),
+                               rtol=1e-5, atol=1e-5)
 
 
 def test_offload_report_still_exposes_plan():

@@ -7,8 +7,9 @@ Covers the contracts the paged engine is built on:
 * the paged decode kernel against its gather-then-attend oracle
   (GQA, ragged lengths, stale/zero block-table entries, f32 + bf16);
 * the head-major in-place decode read path;
-* paged ``Engine`` == dense ``FixedSlotEngine`` token-for-token across
-  page boundaries, under churn, with chunked prefill and preemption;
+* paged ``Engine`` == a dense per-request reference (``model.prefill``
+  then ``model.decode_step``, greedy) token-for-token across page
+  boundaries, under churn, with chunked prefill and preemption;
 * the zero-retrace steady state: churning admits/evicts/decodes leave
   ``offload_stats`` at ``plan_misses == traces == 1`` and freeze the
   engine's jit trace counters after one warmup per shape bucket.
@@ -24,7 +25,6 @@ from repro.kernels import ops, ref
 from repro.models import build_model
 from repro.serve import (
     Engine,
-    FixedSlotEngine,
     PagePool,
     Request,
     bucket_length,
@@ -344,44 +344,64 @@ def _prompts(n, lo=5, hi=24, seed=0):
         np.int32), max_new_tokens=6, rid=i) for i in range(n)]
 
 
-def test_paged_engine_matches_fixed_slot_across_page_boundaries():
+def _dense_greedy(cfg, params, reqs, max_len):
+    """Each request alone through the model's dense cache: prefill, then
+    ``decode_step`` with argmax — no slots, pages or engine code."""
+    model = build_model(cfg)
+    prefill = jax.jit(lambda p, toks: model.prefill(p, {"tokens": toks},
+                                                    max_len))
+    decode = jax.jit(model.decode_step)
+    out = {}
+    for r in reqs:
+        prompt = np.asarray(r.prompt, np.int32)
+        logits, cache = prefill(params, prompt[None])
+        toks = [int(jnp.argmax(logits[0]))]
+        pos = len(prompt)
+        while len(toks) < r.max_new_tokens:
+            logits, cache = decode(params, cache,
+                                   jnp.asarray([toks[-1]], jnp.int32),
+                                   jnp.asarray([pos], jnp.int32))
+            toks.append(int(jnp.argmax(logits[0])))
+            pos += 1
+        out[r.rid] = toks
+    return out
+
+
+def test_paged_engine_matches_dense_reference_across_page_boundaries():
     """page_size=8 with generation crossing several page boundaries —
-    tokens must match the dense fixed-slot engine exactly (greedy)."""
+    tokens must match the dense reference exactly (greedy)."""
     cfg, params = _mk()
     reqs = _prompts(6)
     paged = Engine(cfg, params, slots=2, max_len=48, page_size=8)
-    fixed = FixedSlotEngine(cfg, params, slots=2, max_len=48)
     got = paged.generate([dataclasses.replace(r) for r in reqs])
-    want = fixed.generate([dataclasses.replace(r) for r in reqs])
+    want = _dense_greedy(cfg, params, reqs, max_len=48)
     for r in reqs:
-        assert got[r.rid].tokens == want[r.rid].tokens, r.rid
+        assert got[r.rid].tokens == want[r.rid], r.rid
         assert len(got[r.rid].tokens) == r.max_new_tokens
 
 
-def test_paged_engine_swa_matches_fixed_slot():
+def test_paged_engine_swa_matches_dense_reference():
     """SWA rolling pages: window < prompt + generation, exact match."""
     cfg, params = _mk("mixtral-8x7b", sliding_window=8, moe=None)
     reqs = [Request(np.arange(2, 2 + n, dtype=np.int32), max_new_tokens=8,
                     rid=i) for i, n in enumerate((6, 11, 4))]
     paged = Engine(cfg, params, slots=2, max_len=32, page_size=4)
-    fixed = FixedSlotEngine(cfg, params, slots=2, max_len=32)
     got = paged.generate([dataclasses.replace(r) for r in reqs])
-    want = fixed.generate([dataclasses.replace(r) for r in reqs])
+    want = _dense_greedy(cfg, params, reqs, max_len=32)
     for r in reqs:
-        assert got[r.rid].tokens == want[r.rid].tokens, r.rid
+        assert got[r.rid].tokens == want[r.rid], r.rid
 
 
-def test_paged_engine_recurrent_family_matches_fixed_slot():
+def test_paged_engine_recurrent_family_matches_dense_reference():
     """mamba2 blocks carry per-slot state rows, not pages — inactive
     rows must stay frozen batch-wide."""
     cfg, params = _mk("zamba2-1.2b")
     reqs = _prompts(4, lo=4, hi=12, seed=3)
     paged = Engine(cfg, params, slots=2, max_len=32, page_size=8)
-    fixed = FixedSlotEngine(cfg, params, slots=2, max_len=32)
     got = paged.generate([dataclasses.replace(r) for r in reqs])
-    want = fixed.generate([dataclasses.replace(r) for r in reqs])
+    want = _dense_greedy(cfg, params, reqs, max_len=32)
     for r in reqs:
-        assert got[r.rid].tokens == want[r.rid].tokens, r.rid
+        assert got[r.rid].tokens == want[r.rid], r.rid
 
 
 def test_zero_retrace_steady_state_single_bucket():

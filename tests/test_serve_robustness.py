@@ -1,10 +1,12 @@
 """Serving robustness under injected faults.
 
-The contract (MUST_SURVIVE, also enforced by ``serve_bench --chaos``):
-faults may cancel/abort individual requests, but every request that
-completes with status ``ok`` emits tokens identical to a fault-free
-run, cancelled requests release their pages immediately, and the
-engine never wedges or leaks pool pages.
+The contract (MUST_SURVIVE, held as a whole by
+``test_fault_storm_survives``): faults may cancel/abort individual
+requests, but every request that completes with status ``ok`` emits
+tokens identical to a fault-free run, cancelled requests release their
+pages immediately, the engine never wedges or leaks pool pages, and a
+corrupted persistent plan cache reads as a counted ``disk_corrupt``,
+never an exception.
 """
 import dataclasses
 
@@ -12,6 +14,8 @@ import jax
 import numpy as np
 import pytest
 
+from repro.core.artifacts import set_disk_injector
+from repro.core.policy import OffloadPolicy
 from repro.kernels.guard import kernel_guard
 from repro.models import build_model
 from repro.serve import (
@@ -177,3 +181,62 @@ def test_pool_double_alloc_raises():
     pool._free.append(live)
     with pytest.raises(RuntimeError, match="double-alloc"):
         pool.alloc(1, 1)   # LIFO: pops the corrupt entry first
+
+
+# ------------------------------------------------------------ fault storm
+def test_fault_storm_survives(setup, clean_guard, tmp_path, monkeypatch):
+    """Every fault class at once against the offloaded engine, warm-
+    starting from a plan cache whose entries were bit-flipped on disk:
+    every kernel launch fails (guarded fallback to ref, quarantine,
+    all_far re-plan), one request's logits turn NaN, page allocations
+    fail transiently, slow steps push a deadlined request past its
+    budget, and every artifact read/write is truncated."""
+    cfg, params, _ = setup
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(1, 250, size=int(rng.integers(5, 17))).astype(
+        np.int32) for _ in range(6)]
+
+    def reqs(deadline_s):
+        return [Request(p, max_new_tokens=8, rid=i,
+                        deadline_s=deadline_s if i == 0 else 0.0)
+                for i, p in enumerate(prompts)]
+
+    kw = dict(slots=4, max_len=64, page_size=8, offload=True,
+              offload_policy=OffloadPolicy(impl="interpret"))
+    monkeypatch.setenv("MPU_PLAN_CACHE", str(tmp_path))
+    base = Engine(cfg, params, **kw).generate(reqs(0.0))
+    poisoned = list(tmp_path.glob("*.bin"))
+    assert poisoned
+    for b in poisoned:
+        raw = bytearray(b.read_bytes())
+        raw[len(raw) // 2] ^= 0x01
+        b.write_bytes(bytes(raw))
+
+    # quarantine after the first failure: a single-segment plan
+    # dispatches once per trace, so the default threshold never trips
+    threshold = clean_guard.threshold
+    clean_guard.threshold = 1
+    inj = FaultInjector(FaultConfig(
+        kernel_fail_rate=1.0, nan_logit_rate=1.0, nan_logit_limit=1,
+        page_fail_rate=0.3, slow_step_rate=1.0, slow_step_s=0.02,
+        disk_fail_rate=1.0, disk_truncate_share=1.0, seed=7))
+    try:
+        eng = Engine(cfg, params, fault_injector=inj, **kw)
+        done = eng.generate(reqs(0.08))
+    finally:
+        set_disk_injector(None)
+        clean_guard.threshold = threshold
+
+    sv, st = eng.serve_counters, eng.offload_stats
+    ok = [r for r, c in done.items() if c.status == "ok"]
+    assert ok
+    for r in ok:
+        assert done[r].tokens == base[r].tokens, r
+    assert done[0].status == "cancelled"
+    assert eng.pool.used_pages == 0
+    assert st["quarantines"] >= 1
+    assert sv["nan_aborts"] >= 1
+    assert sv["page_faults"] >= 1
+    assert st["plan_misses"] <= 1 + st["plan_invalidations"]
+    assert st["disk_corrupt"] >= 1
+    assert inj.counters["disk_faults_injected"] >= 1
